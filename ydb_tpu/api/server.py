@@ -558,7 +558,8 @@ class RequestProxy:
             schema = schema_from_json(man["schema"])
             desc = TableDescription(
                 path="/" + target, schema=schema,
-                primary_key=(man["pk_column"],),
+                primary_key=tuple(man.get("pk_columns")
+                                  or (man["pk_column"],)),
                 n_shards=request.shards or man["n_shards"],
                 store="column", ttl_column=man.get("ttl_column"),
                 upsert=man["upsert"],

@@ -21,6 +21,26 @@ import sys
 import time
 
 
+def _use_device(args) -> None:
+    """Take the device JAX finds (``--platform`` narrows the choice; it
+    is how tests ask for the CPU), say which, and place the persistent
+    compile cache before the first compile (only when this is the
+    process's entry point: a test that calls ``main`` in-process must
+    keep compiling, see runtime/compile_cache.py)."""
+    import jax
+
+    from ydb_tpu.runtime import compile_cache
+
+    if args.platform:
+        jax.config.update("jax_platforms", args.platform)
+    cache_dir = (compile_cache.configure() if args.persistent_cache
+                 else "off")
+    devs = jax.devices()
+    print(f"device: {devs[0].platform} {devs[0].device_kind} "
+          f"x{len(devs)}; compile cache: {cache_dir}",
+          file=sys.stderr, flush=True)
+
+
 def _connect(args):
     from ydb_tpu.api.client import Driver
 
@@ -28,9 +48,7 @@ def _connect(args):
 
 
 def cmd_serve(args):
-    import jax
-
-    jax.config.update("jax_platforms", args.platform)
+    _use_device(args)
     from ydb_tpu.api.server import make_server
     from ydb_tpu.config import AppConfig
     from ydb_tpu.engine.blobs import DirBlobStore, MemBlobStore
@@ -154,9 +172,7 @@ def cmd_topic(args):
 
 
 def _run_workload(args, run, **kwargs):
-    import jax
-
-    jax.config.update("jax_platforms", args.platform)
+    _use_device(args)
     queries = args.queries.split(",") if args.queries else None
     results = run(queries=queries, iterations=args.iterations, **kwargs)
     for name, seconds, rows in results:
@@ -184,9 +200,7 @@ def cmd_tpcds(args):
 
 
 def cmd_loadtest(args):
-    import jax
-
-    jax.config.update("jax_platforms", args.platform)
+    _use_device(args)
     from ydb_tpu.kqp.session import Cluster
     from ydb_tpu.obs.loadtest import LoadService
 
@@ -196,7 +210,7 @@ def cmd_loadtest(args):
           f"{r['rps']} rps  p50={r['p50_ms']}ms p99={r['p99_ms']}ms")
 
 
-def main(argv=None):
+def main(argv=None, persistent_cache: bool = False):
     ap = argparse.ArgumentParser(prog="ydb_tpu")
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -204,11 +218,16 @@ def main(argv=None):
         p.add_argument("-e", "--endpoint", default="127.0.0.1:2136")
         p.add_argument("--auth-token", default=None)
 
+    def add_platform(p):
+        p.add_argument("--platform", default=None,
+                       help="JAX platform (e.g. cpu); default: the "
+                            "device JAX finds")
+
     p = sub.add_parser("serve")
     p.add_argument("--data-dir", default=None)
     p.add_argument("--port", type=int, default=None)
     p.add_argument("--auth-token", default=None)
-    p.add_argument("--platform", default="cpu")
+    add_platform(p)
     p.add_argument("--background-period", type=float, default=None)
     p.add_argument("--yaml-config", default=None)
     p.add_argument("--pg-port", type=int, default=None,
@@ -259,32 +278,33 @@ def main(argv=None):
     wt.add_argument("--sf", type=float, default=0.01)
     wt.add_argument("--queries", default=None)
     wt.add_argument("--iterations", type=int, default=1)
-    wt.add_argument("--platform", default="cpu")
+    add_platform(wt)
     wt.set_defaults(fn=cmd_workload)
     wc = wsub.add_parser("clickbench")
     wc.add_argument("--rows", type=int, default=100_000)
     wc.add_argument("--queries", default=None)
     wc.add_argument("--iterations", type=int, default=1)
-    wc.add_argument("--platform", default="cpu")
+    add_platform(wc)
     wc.add_argument("--no-verify", action="store_true")
     wc.set_defaults(fn=cmd_clickbench)
     wd = wsub.add_parser("tpcds")
     wd.add_argument("--sf", type=float, default=0.002)
     wd.add_argument("--queries", default=None)
     wd.add_argument("--iterations", type=int, default=1)
-    wd.add_argument("--platform", default="cpu")
+    add_platform(wd)
     wd.add_argument("--no-verify", action="store_true")
     wd.set_defaults(fn=cmd_tpcds)
     wl = wsub.add_parser("load")
     wl.add_argument("--kind", default="kv_upsert",
                     choices=["kv_upsert", "select", "storage_put"])
     wl.add_argument("--requests", type=int, default=100)
-    wl.add_argument("--platform", default="cpu")
+    add_platform(wl)
     wl.set_defaults(fn=cmd_loadtest)
 
     args = ap.parse_args(argv)
+    args.persistent_cache = persistent_cache
     args.fn(args)
 
 
 if __name__ == "__main__":
-    main()
+    main(persistent_cache=True)

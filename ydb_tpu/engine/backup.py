@@ -78,6 +78,7 @@ def export_table(table, dest: BlobStore, name: str,
         "snapshot": snap,
         "schema": schema_to_json(table.schema),
         "pk_column": table.pk_column,
+        "pk_columns": list(table.pk_columns),
         "ttl_column": table.shards[0].ttl_column,
         "upsert": table.upsert,
         "n_shards": len(table.shards),
@@ -115,6 +116,7 @@ def import_table(src: BlobStore, name: str, store: BlobStore,
         table_name or man["name"], schema, store, coordinator,
         n_shards=n_shards or man["n_shards"],
         pk_column=man["pk_column"], upsert=man["upsert"],
+        pk_columns=tuple(man.get("pk_columns") or ()) or None,
         ttl_column=man.get("ttl_column"),
         dicts=dicts, config=config,
     )

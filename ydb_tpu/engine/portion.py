@@ -321,6 +321,19 @@ def read_portion_blob(
     return cols, valid
 
 
+def last_of_equal_keys(keys: list[np.ndarray]) -> np.ndarray:
+    """Mask of the rows that END a run of equal key tuples. ``keys`` are
+    the primary-key columns of rows already ordered so that equal
+    tuples are adjacent, oldest first: the mask keeps the newest."""
+    n = len(keys[0])
+    if n == 0:
+        return np.zeros(0, dtype=bool)
+    same = np.ones(n - 1, dtype=bool)
+    for k in keys:
+        same &= k[1:] == k[:-1]
+    return np.r_[~same, True]
+
+
 def column_stats(
     arr: np.ndarray, validity: np.ndarray | None = None,
 ) -> tuple:

@@ -40,6 +40,7 @@ from ydb_tpu.chaos import deadline as statement_deadline
 from ydb_tpu.engine.portion import (
     PortionChunkReader,
     PortionMeta,
+    last_of_equal_keys,
     project_chunk,
     read_portion_blob,
 )
@@ -358,10 +359,12 @@ class PortionStreamSource:
         batch merge of the <=bound prefixes is final — the incremental
         analog of the reference's interval merge (scanner.h:69).
         """
-        pk = self.shard.pk_column
+        # a composite key merges on its first column and de-duplicates
+        # on the whole tuple (see below)
+        rest = self.shard.pk_columns[1:]
         read_names = tuple(names)
-        if pk not in read_names:
-            read_names = read_names + (pk,)
+        read_names += tuple(k for k in self.shard.pk_columns
+                            if k not in read_names)
         ordered = sorted(cluster, key=lambda m: (m.commit_snap,
                                                  m.portion_id))
         cursors = [_RunCursor(self, m, read_names) for m in ordered]
@@ -390,7 +393,25 @@ class PortionStreamSource:
                         continue
                     parts.append(c.slices(k))
                     runs.append(c.pk_buf[:k])
-                run_idx, row_idx = native.kway_merge(runs, dedup=True)
+                run_idx, row_idx = native.kway_merge(runs,
+                                                     dedup=not rest)
+                if rest:
+                    # the merge is stable (oldest run first) and every
+                    # row with a first-column value <= bound is in this
+                    # batch, so a stable sort of the batch on the whole
+                    # key puts equal tuples together oldest -> newest
+                    keys = []
+                    for k in self.shard.pk_columns:
+                        a = np.empty(len(run_idx),
+                                     dtype=parts[0][0][k].dtype)
+                        for r, p in enumerate(parts):
+                            sel = run_idx == r
+                            a[sel] = p[0][k][row_idx[sel]]
+                        keys.append(a)
+                    order = np.lexsort(keys[::-1])
+                    keep = np.sort(order[last_of_equal_keys(
+                        [a[order] for a in keys])])
+                    run_idx, row_idx = run_idx[keep], row_idx[keep]
                 # gather per-run instead of concatenate-then-gather:
                 # with dedup the merged output is SMALLER than the
                 # buffered input, so materializing a concatenated copy

@@ -50,6 +50,7 @@ from ydb_tpu.engine.oracle import OracleTable
 from ydb_tpu.engine.portion import (
     PortionMeta,
     column_stats,
+    last_of_equal_keys,
     read_portion_blob,
     write_portion_blob,
 )
@@ -110,11 +111,20 @@ class ColumnShard:
         config: ShardConfig | None = None,
         dicts: DictionarySet | None = None,
         upsert: bool = False,
+        pk_columns: tuple[str, ...] | None = None,
     ):
         self.shard_id = shard_id
         self.schema = schema
         self.store = store
         self.pk_column = pk_column
+        # the full primary key: rows sort, route and range-prune on its
+        # FIRST column (pk_column); newest-wins dedup under upsert
+        # compares the whole tuple, so a composite key such as TPC-H
+        # lineitem's (l_orderkey, l_linenumber) keeps every line
+        self.pk_columns = (tuple(pk_columns) if pk_columns
+                           else ((pk_column,) if pk_column else ()))
+        if self.pk_columns and self.pk_columns[0] != pk_column:
+            raise ValueError("pk_columns must start with pk_column")
         self.ttl_column = ttl_column
         # upsert: PK semantics — a re-written key shadows the old row;
         # scans merge portions by PK with newest-wins dedup
@@ -334,12 +344,14 @@ class ColumnShard:
         # under upsert, equal keys within one commit collapse last-wins
         if self.pk_column and self.pk_column in cols and \
                 len(cols[self.pk_column]):
-            pk = cols[self.pk_column]
-            order = np.argsort(pk, kind="stable")
             if self.upsert:
-                sorted_pk = pk[order]
-                keep = np.r_[sorted_pk[1:] != sorted_pk[:-1], True]
-                order = order[keep]
+                # stable sort on the whole key, last of each equal key
+                keys = [np.asarray(cols[k]) for k in self.pk_columns]
+                order = np.lexsort(keys[::-1])
+                order = order[last_of_equal_keys(
+                    [k[order] for k in keys])]
+            else:
+                order = np.argsort(cols[self.pk_column], kind="stable")
             cols = {n: a[order] for n, a in cols.items()}
             validity = {n: a[order] for n, a in (validity or {}).items()}
         with self._meta_lock:

@@ -220,7 +220,7 @@ class BatchDispatcher:
             if key_of is not None:
                 vec.append(("dev", site.table, site.node.program,
                             site.read_cols, site.capacity,
-                            key_of(site.read_cols, 1 << 22)))
+                            key_of(site.read_cols, db.scan_block_rows)))
             else:
                 vec.append(("src", site.table, site.node.program,
                             site.read_cols, site.capacity, id(src)))

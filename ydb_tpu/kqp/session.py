@@ -342,6 +342,8 @@ class Cluster:
         dictionary contents / schema shape into plan-time state."""
         self._plan_cache.clear()
         self._compile_cache.clear()
+        if self._mesh_exec is not None:
+            self._mesh_exec._jit_cache.clear()
 
     def stop(self, timeout: float = 30.0) -> None:
         """Orderly node teardown (the driver_lib shutdown analog): stop
@@ -416,6 +418,7 @@ class Cluster:
             t = ShardedTable(
                 name, desc.schema, self.store, self.coordinator,
                 n_shards=desc.n_shards, pk_column=desc.primary_key[0],
+                pk_columns=tuple(desc.primary_key),
                 ttl_column=desc.ttl_column, dicts=self.dicts, boot=boot,
                 config=shard_config, upsert=desc.upsert,
                 gen=desc.shard_gen,
@@ -1103,7 +1106,11 @@ class Cluster:
         from ydb_tpu.obs.sysview import SYS_SCHEMAS, table_stats
 
         schemas = {n: t.schema for n, t in self.tables.items()}
-        pks = {n: (t.pk_column,) for n, t in self.tables.items()}
+        # the WHOLE key: the planner takes a join side whose key
+        # columns cover its primary key as unique, so naming only the
+        # first column of lineitem's (l_orderkey, l_linenumber) would
+        # make Q3 keep one line per order
+        pks = {n: tuple(t.pk_columns) for n, t in self.tables.items()}
         if self.flags.enable_sys_views:
             for name, schema in SYS_SCHEMAS.items():
                 schemas.setdefault(name, schema)
@@ -1263,7 +1270,8 @@ class Cluster:
                 sources[name] = _merge_shard_sources(t, snap)
         if include_sys:
             sources = _SysLazySources(self, sources)
-        db = Database(sources=sources, dicts=self.dicts)
+        db = Database(sources=sources, dicts=self.dicts,
+                      scan_block_rows=self.config.scan_block_rows)
         db.block_cache = self.scan_block_cache
         # compiled programs persist across statements (the node-scoped
         # pattern cache): the second run of a SELECT is a compile-cache

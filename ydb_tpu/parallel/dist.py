@@ -56,6 +56,39 @@ def stack_blocks(blocks: list[TableBlock]) -> TableBlock:
     return out
 
 
+def place_shards(blocks: list[TableBlock], mesh,
+                 owner: str = "mesh_place") -> TableBlock:
+    """Per-shard blocks -> one stacked block sharded over the mesh's
+    shard axis, each shard put on ITS device.
+
+    ``device_put(stack_blocks(blocks), sharding)`` stages the whole
+    stack on one device first, and cannot even stack blocks that
+    already live on different devices (the resident tier binds each
+    shard's columns to the device that scans it): assemble the global
+    array from the single-device pieces instead."""
+    sharding = NamedSharding(mesh, P(SHARD_AXIS))
+    devices = [row[0] for row in mesh.devices]
+    assert len(blocks) == len(devices) == mesh.devices.size, \
+        (len(blocks), mesh.devices.shape)
+
+    def place(arrays):
+        pieces = [jax.device_put(a[None], d)
+                  for a, d in zip(arrays, devices)]
+        return jax.make_array_from_single_device_arrays(
+            (len(pieces),) + arrays[0].shape, sharding, pieces)
+
+    sch = blocks[0].schema
+    with memsan.seam("staging"):
+        out = TableBlock(
+            {n: Column(place([b.columns[n].data for b in blocks]),
+                       place([b.columns[n].validity for b in blocks]))
+             for n in sch.names},
+            place([jnp.asarray(b.length) for b in blocks]), sch)
+    if memsan.armed():
+        memsan.charge(memsan.nbytes_of(out), "staging", owner=owner)
+    return out
+
+
 def _local(stacked: TableBlock) -> TableBlock:
     """Inside shard_map: strip the (size-1) leading device axis."""
     cols = {
@@ -394,14 +427,7 @@ class MeshScan:
             # compact states vary in size shard-to-shard: pad to common
             cap = max(s.capacity for s in states)
             states = [_pad_state(s, cap) for s in states]
-        with memsan.seam("staging"):
-            placed = jax.device_put(
-                stack_blocks(states),
-                NamedSharding(self.mesh, P(SHARD_AXIS)))
-        if memsan.armed():
-            memsan.charge(memsan.nbytes_of(placed), "staging",
-                          owner="mesh_place")
-        out = self._merge_final_step(placed)
+        out = self._merge_final_step(place_shards(states, self.mesh))
         return OracleTable.from_block(out)
 
     def execute(self, source: ColumnSource) -> OracleTable:

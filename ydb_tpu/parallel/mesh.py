@@ -28,21 +28,8 @@ SHARD_AXIS = "shard"
 PIPE_AXIS = "pipe"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; older
-    releases only have ``jax.experimental.shard_map.shard_map`` whose
-    replication check is spelled ``check_rep``. Every engine call site
-    routes through this wrapper so the mesh runs on either."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+#: every engine call site maps over the mesh through this one name
+shard_map = jax.shard_map
 
 
 def make_mesh(

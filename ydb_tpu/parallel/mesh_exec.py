@@ -37,13 +37,17 @@ from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.blocks.dictionary import DictionarySet
 from ydb_tpu.chaos import deadline as statement_deadline
 from ydb_tpu.engine.oracle import OracleTable
-from ydb_tpu.engine.scan import ColumnSource, ScanExecutor
+from ydb_tpu.engine.scan import (
+    DEFAULT_BLOCK_ROWS,
+    ColumnSource,
+    ScanExecutor,
+)
 from ydb_tpu.parallel.dist import (
     MeshScan,
     _local,
     _pad_state,
     _relocal,
-    stack_blocks,
+    place_shards,
 )
 from ydb_tpu.obs import timeline
 from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
@@ -242,7 +246,6 @@ class MeshPlanExecutor:
         and the per-device blocks stack under NamedSharding(P(shard))."""
         from ydb_tpu.ssa.plan_fuse import fit_blocks
 
-        sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         inputs: dict = {}
         for site in fused.sites:
             subs = self.db.sources[site.table]
@@ -252,7 +255,7 @@ class MeshPlanExecutor:
                     f" {self.n}-device mesh (need exactly one per device)")
             devs = []
             for sub in subs:
-                blocks = tuple(sub.blocks(1 << 22, site.read_cols))
+                blocks = tuple(sub.blocks(DEFAULT_BLOCK_ROWS, site.read_cols))
                 if not blocks:
                     # portion streams yield nothing for an empty shard
                     blocks = (TableBlock.from_numpy(
@@ -260,12 +263,8 @@ class MeshPlanExecutor:
                          for f in site.in_schema.fields},
                         site.in_schema),)
                 devs.append(fit_blocks(blocks, site.capacity))
-            with memsan.seam("staging"):
-                inputs[site.key] = jax.device_put(
-                    stack_blocks(devs), sharding)
-        if memsan.armed():
-            memsan.charge(memsan.nbytes_of(inputs), "staging",
-                          owner="stage_fused")
+            inputs[site.key] = place_shards(devs, self.mesh,
+                                            owner="stage_fused")
         return inputs
 
     def _exec(self, plan, memo: dict, root: bool = False):
@@ -285,15 +284,6 @@ class MeshPlanExecutor:
         memo[id(plan)] = out
         return out
 
-    def _shard_it(self, stacked: TableBlock) -> TableBlock:
-        sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
-        with memsan.seam("staging"):
-            placed = jax.device_put(stacked, sharding)
-        if memsan.armed():
-            memsan.charge(memsan.nbytes_of(placed), "staging",
-                          owner="mesh_place")
-        return placed
-
     def _scan(self, plan: TableScan) -> TableBlock:
         """Per-shard scan: pushdown program runs in each shard's scan
         executor; per-shard results pad-stack onto the mesh."""
@@ -308,16 +298,27 @@ class MeshPlanExecutor:
         for sub in subs:
             if plan.program is None:
                 names = plan.columns or sub.schema.names
-                blks = list(sub.blocks(1 << 20, names))
+                blks = list(sub.blocks(DEFAULT_BLOCK_ROWS, names))
                 blk = blks[0] if len(blks) == 1 else _concat(blks)
             else:
-                ex = ScanExecutor(plan.program, sub, block_rows=1 << 20,
-                                  key_spaces=self.db.key_spaces)
-                blk = ex.run_stream(sub.blocks(1 << 20, ex.read_cols))
+                # one compiled executor per (table, program), shared by
+                # the shards and kept across statements like every
+                # other step here: a fresh ScanExecutor is a fresh jit,
+                # i.e. an XLA compile per shard per statement
+                key = ("scan", plan.table, plan.program)
+                ex = self._jit_cache.get(key)
+                if ex is None:
+                    ex = ScanExecutor(
+                        plan.program, sub, block_rows=DEFAULT_BLOCK_ROWS,
+                        key_spaces=self.db.key_spaces).detach()
+                    self._jit_cache[key] = ex
+                blk = ex.run_stream(
+                    sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols))
             locals_.append(blk)
         cap = _round_up(max(int(b.length) for b in locals_))
-        return self._shard_it(stack_blocks(
-            [_pad_state(self._slice(b, cap), cap) for b in locals_]))
+        return place_shards(
+            [_pad_state(self._slice(b, cap), cap) for b in locals_],
+            self.mesh)
 
     @staticmethod
     def _slice(block: TableBlock, cap: int) -> TableBlock:

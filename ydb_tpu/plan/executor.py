@@ -30,7 +30,11 @@ from ydb_tpu.chaos import deadline as statement_deadline
 from ydb_tpu.blocks.block import TableBlock, concat_blocks, device_aux
 from ydb_tpu.blocks.dictionary import DictionarySet
 from ydb_tpu.engine.oracle import OracleTable
-from ydb_tpu.engine.scan import ColumnSource, ScanExecutor
+from ydb_tpu.engine.scan import (
+    DEFAULT_BLOCK_ROWS,
+    ColumnSource,
+    ScanExecutor,
+)
 from ydb_tpu.obs import tracing
 from ydb_tpu.obs.probes import probe as _probe
 from ydb_tpu.ssa import join as join_kernels
@@ -78,6 +82,11 @@ class Database:
     # aggregator table statistics (stats.cost.TableStats by table name):
     # feeds DQ join sizing estimates; advisory only
     table_stats: dict | None = None
+    # rows per scan block, for every scan this Database's statements
+    # run (the cluster hands down its shards' scan_block_rows): the
+    # pushdown program's device temporaries scale with it — Q1's
+    # partial takes 1.9 GB at 2^20 rows and 8.6 GB at 2^22 on a v5e
+    scan_block_rows: int = DEFAULT_BLOCK_ROWS
 
     def invalidate_compile_cache(self):
         self._compile_cache.clear()
@@ -159,12 +168,18 @@ def _execute_plan_mesh(plan: PlanNode, db: Database):
     # donated-buffer dispatch over the mesh; the per-node walk remains
     # the fallback for shapes that don't mesh-fuse
     try:
-        fused = getattr(mex, "execute_fused", None)
-        if fused is not None:
-            out = fused(plan)
-            if out is not None:
-                return out
-        return mex.execute(plan)
+        # the span is how a profile tells the mesh executors from the
+        # single-chip ones: answered=1 with a "plan.fuse" child is the
+        # mesh-fused dispatch, without one the mesh walk; a plan that
+        # falls back leaves the span without the attr
+        with tracing.span("mesh") as sp:
+            sp.set(devices=mex.n)
+            fused = getattr(mex, "execute_fused", None)
+            out = fused(plan) if fused is not None else None
+            if out is None:
+                out = mex.execute(plan)
+            sp.set(answered=1)
+            return out
     except NotImplementedError:
         return None
     except chaos.DeviceLostError:
@@ -292,7 +307,7 @@ def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
     fresh = ex is None
     if fresh:
         ex = ScanExecutor(
-            plan.program, src, block_rows=1 << 22,
+            plan.program, src, block_rows=db.scan_block_rows,
             key_spaces=db.key_spaces,
         ).detach()  # cache compiled state, not the source arrays
         db._compile_cache[key] = ex
@@ -325,7 +340,7 @@ def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
         chunks0 = {k: int(getattr(src, k, 0))
                    for k in ("chunks_read", "chunks_skipped",
                              "resident_hits", "resident_rows")}
-        raw_stream = src.blocks(1 << 22, ex.read_cols)
+        raw_stream = src.blocks(db.scan_block_rows, ex.read_cols)
         stream = raw_stream
         bc = db.block_cache
         key_of = getattr(src, "device_cache_key", None)
@@ -343,7 +358,8 @@ def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
             # generator — a late-bound `stream` would hand the
             # generator back to itself
             stream = bc.stream(
-                key_of(ex.read_cols, 1 << 22), lambda: raw_stream)
+                key_of(ex.read_cols, db.scan_block_rows),
+                lambda: raw_stream)
         out = ex.run_stream(stream, timer=timer)
     finally:
         if timer is not None and hasattr(base_src, "attach_timer"):
@@ -426,7 +442,7 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
                     arrays, site.in_schema, validity,
                     capacity=site.capacity)
         else:
-            raw_stream = src.blocks(1 << 22, site.read_cols)
+            raw_stream = src.blocks(db.scan_block_rows, site.read_cols)
             stream = raw_stream
             bc = db.block_cache
             key_of = getattr(src, "device_cache_key", None)
@@ -437,7 +453,8 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
             if bc is not None and key_of is not None \
                     and bc.budget() > 0 and not res_on:
                 stream = bc.stream(
-                    key_of(site.read_cols, 1 << 22), lambda: raw_stream)
+                    key_of(site.read_cols, db.scan_block_rows),
+                    lambda: raw_stream)
             blocks = tuple(stream)
             with staging:
                 blk = plan_fuse.fit_blocks(blocks, site.capacity)

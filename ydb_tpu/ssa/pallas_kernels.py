@@ -15,8 +15,10 @@ decimals, float64) fall back to the scatter path, so results never
 silently lose precision. Group counts <= MAX_GROUPS keep the one-hot
 tile in VMEM.
 
-Enable on TPU with YDB_TPU_PALLAS=1 (kernels.scatter_sum consults
-``enabled()``); tests run the same kernel in interpreter mode on CPU.
+On by default on a TPU backend; YDB_TPU_PALLAS=0|1 overrides
+(kernels.scatter_sum consults ``enabled()``). Tests run the same kernel
+in interpreter mode on CPU and compile it for a described v5e
+(tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -26,9 +28,6 @@ import os
 
 import jax
 import jax.numpy as jnp
-# jax.enable_x64 was removed from the top-level namespace; the
-# experimental context manager is the stable spelling across versions
-from jax.experimental import enable_x64 as _enable_x64
 
 ROW_TILE = 1024
 MAX_GROUPS = 2048
@@ -62,8 +61,12 @@ MAX_FUSED_SLOTS = 128
 
 def supported_fused(dtype, num_groups: int, n_slots: int) -> bool:
     """Eligibility of the fused multi-column tile kernel
-    (kernels.fused_group_reduce's >ONEHOT tier)."""
-    return supported(dtype, num_groups) and n_slots <= MAX_FUSED_SLOTS
+    (kernels.fused_group_reduce's >ONEHOT tier). float32 only: its
+    contraction is a ``tpu.matmul``, and Mosaic refuses an int32
+    lhs/rhs on a v5e — int32 banks take the scatter tier, as int64
+    banks do."""
+    return (jnp.dtype(dtype) == jnp.float32 and num_groups <= MAX_GROUPS
+            and n_slots <= MAX_FUSED_SLOTS)
 
 
 def _pad_rows(a: jax.Array, n: int, fill):
@@ -110,7 +113,7 @@ def grouped_sum(values: jax.Array, gid: jax.Array, num_groups: int,
     # the engine runs with jax_enable_x64; Mosaic cannot legalize the
     # implicit i64 index/constant types that mode introduces, and
     # nothing in this kernel needs 64 bits — trace it in 32-bit mode
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid=(tiles,),
@@ -179,7 +182,7 @@ def grouped_sum_multi(values: jax.Array, gid: jax.Array, num_groups: int,
             preferred_element_type=out_ref.dtype)
 
     # 32-bit trace for the same Mosaic i64 reason as grouped_sum
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,
             grid=(tiles,),

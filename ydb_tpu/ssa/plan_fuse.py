@@ -104,7 +104,7 @@ FUSE_FORCE: bool | None = None
 #: probe rows, ~1.6x at ~12k), while past ~10^5 rows the kernels are
 #: compute-bound and the walk's tighter 1024-quantum padding edges out
 #: the shape-class padding. Well under the walk's scan block size
-#: (1 << 22), so any fusible table was a SINGLE block on the
+#: (1 << 20), so any fusible table was a SINGLE block on the
 #: per-fragment path anyway — identical operand shapes, bit-identical
 #: results, no extra memory.
 FUSE_MAX_ROWS = int(os.environ.get("YDB_TPU_FUSE_MAX_ROWS", str(1 << 17)))

@@ -56,12 +56,16 @@ class ShardedTable:
         boot: bool = False,
         upsert: bool = False,
         gen: int = 0,
+        pk_columns: tuple[str, ...] | None = None,
     ):
         self.name = name
         self.schema = schema
         self.store = store
         self.coordinator = coordinator
         self.pk_column = pk_column or schema.names[0]
+        # rows route on the first key column, so all versions of one
+        # key share a shard; upsert dedup compares the whole key
+        self.pk_columns = tuple(pk_columns or (self.pk_column,))
         self.ttl_column = ttl_column
         self.config = config
         # upsert: PK rewrite shadows the old row. Rows route by PK hash,
@@ -86,12 +90,14 @@ class ShardedTable:
             ]
             for s in self.shards:
                 s.upsert = upsert
+                s.pk_columns = self.pk_columns
         else:
             self.shards = [
                 ColumnShard(
                     sid, schema, store,
                     pk_column=self.pk_column, ttl_column=ttl_column,
                     config=config, dicts=self.dicts, upsert=upsert,
+                    pk_columns=self.pk_columns,
                 )
                 for sid in ids
             ]
@@ -135,6 +141,7 @@ class ShardedTable:
                 self._shard_id(new_gen, i), self.schema, self.store,
                 pk_column=self.pk_column, ttl_column=self.ttl_column,
                 config=self.config, dicts=self.dicts, upsert=self.upsert,
+                pk_columns=self.pk_columns,
             )
             for i in range(n_new)
         ]
