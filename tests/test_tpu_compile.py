@@ -1,0 +1,157 @@
+"""The main path's kernels and scan programs, compiled for a described
+v5e at the sizes the SQL path really uses.
+
+The TPU compiler is installed here and compiles for a chip that is
+described and not attached (on-chip-measurement guide, section 2), so
+what interpret mode cannot show — a Mosaic refusal, a program whose
+temporaries do not fit beside a resident table — costs no chip time.
+Nothing runs: a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import): only one process may load the TPU library, and every xdist
+worker imports every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ydb_tpu.engine.scan import DEFAULT_BLOCK_ROWS, ColumnSource, ScanExecutor
+from ydb_tpu.engine.shard import ShardConfig
+from ydb_tpu.ssa import pallas_kernels
+from ydb_tpu.workload import tpch
+
+#: one v5e chip (Google Cloud documentation, "TPU v5e")
+V5E_HBM_BYTES = 16e9
+#: the share of it one program's temporaries may take: engine/hbm.py
+#: leaves 3/8 of the device to temporaries, staging and results
+TEMP_SHARE = 1 / 4
+GROUPS = (513, 1024, 2048)
+DTYPES = ("float32", "int32")
+#: the slot count of a wide fused bank (Q1 stacks 5 + 4 + 6)
+FUSED_SLOTS = 15
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it off round these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _compile_grouped_sum(dtype, groups, one_chip):
+    n = DEFAULT_BLOCK_ROWS
+    return pallas_kernels.grouped_sum.lower(
+        _shape((n,), dtype, one_chip), _shape((n,), "int32", one_chip),
+        num_groups=groups).compile()
+
+
+def _compile_grouped_sum_multi(dtype, groups, one_chip):
+    n = DEFAULT_BLOCK_ROWS
+    return pallas_kernels.grouped_sum_multi.lower(
+        _shape((n, FUSED_SLOTS), dtype, one_chip),
+        _shape((n,), "int32", one_chip), num_groups=groups).compile()
+
+
+def test_block_size_is_the_one_the_path_uses():
+    assert ShardConfig().scan_block_rows == DEFAULT_BLOCK_ROWS == 1 << 20
+
+
+@pytest.mark.parametrize("groups", GROUPS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_grouped_sum_compiles(dtype, groups, one_chip,
+                              no_persistent_cache):
+    assert pallas_kernels.supported(dtype, groups)
+    compiled = _compile_grouped_sum(dtype, groups, one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("groups", GROUPS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_grouped_sum_multi_admitted_iff_it_compiles(
+        dtype, groups, one_chip, no_persistent_cache):
+    """``supported_fused`` admits exactly what the chip's compiler
+    takes: float32 compiles, int32 is refused by Mosaic (its
+    ``tpu.matmul`` takes no int32 operands) and must not be admitted."""
+    admitted = pallas_kernels.supported_fused(dtype, groups, FUSED_SLOTS)
+    try:
+        compiled = _compile_grouped_sum_multi(dtype, groups, one_chip)
+    except Exception as e:  # noqa: BLE001 - MosaicError is not public
+        assert "Mosaic" in f"{type(e).__name__}: {e}", e
+        assert not admitted, \
+            f"supported_fused admits {dtype} x {groups}, Mosaic refuses"
+        return
+    assert "tpu_custom_call" in compiled.as_text()
+    assert admitted, \
+        f"{dtype} x {groups} compiles but supported_fused rejects it"
+
+
+def test_supported_fused_rejects_what_mosaic_refuses():
+    for groups in GROUPS:
+        assert not pallas_kernels.supported_fused(
+            jnp.int32, groups, FUSED_SLOTS)
+        assert pallas_kernels.supported_fused(
+            jnp.float32, groups, FUSED_SLOTS)
+    assert not pallas_kernels.supported_fused(jnp.float32, 2049, 4)
+    assert not pallas_kernels.supported_fused(
+        jnp.float32, 1024, pallas_kernels.MAX_FUSED_SLOTS + 1)
+
+
+@pytest.mark.parametrize("query", ("q1", "q6"))
+def test_scan_partial_fits_beside_a_resident_table(
+        query, one_chip, no_persistent_cache):
+    """The pushdown partial program of Q1/Q6 over one scan block: its
+    temporaries scale with the block (Q1: 1.9 GB at 2^20 rows, 8.6 GB
+    at 2^22), so at ``scan_block_rows`` they stay under TEMP_SHARE of
+    the chip."""
+    cap = 1 << 12
+    data = tpch.TpchData(sf=0.001, seed=5)
+    src = ColumnSource(columns=data.tables["lineitem"],
+                       schema=tpch.LINEITEM_SCHEMA, dicts=data.dicts)
+    program = {"q1": tpch.q1_program, "q6": tpch.q6_program}[query]()
+    ex = ScanExecutor(program, src, block_rows=cap)
+    block = next(iter(src.blocks(cap, ex.read_cols)))
+    rows = ShardConfig().scan_block_rows
+
+    def described(x):
+        x = np.asarray(x) if not hasattr(x, "shape") else x
+        shape = tuple(rows if d == cap else d for d in x.shape)
+        return _shape(shape, x.dtype, one_chip)
+
+    args = jax.tree_util.tree_map(
+        described, (block, dict(ex.partial.aux)))
+    compiled = jax.jit(ex.partial.run).lower(*args).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < TEMP_SHARE * V5E_HBM_BYTES, (query, temp)
