@@ -1,5 +1,5 @@
 """Pallas group-by kernel: interpreter-mode equivalence with the
-scatter path (real-TPU execution is covered by bench on hardware)."""
+scatter path (compiled for a described v5e in test_tpu_compile.py)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -39,3 +39,24 @@ def test_gating():
     assert not pallas_kernels.supported(jnp.int64, 10)   # exactness
     assert not pallas_kernels.supported(jnp.float32, 10**6)  # VMEM
     assert pallas_kernels.supported(jnp.float32, 2048)
+
+
+def test_scatter_sum_over_onehot_limit_with_pallas_off(monkeypatch):
+    """Above ONEHOT_GROUP_LIMIT ``kernels.scatter_sum`` imports this
+    module at trace time to ask ``enabled()``: with the Pallas path off
+    (the CPU default) a 600-group sum must take the XLA scatter — the
+    lazy import itself is what a broken module would fail."""
+    from ydb_tpu.ssa import kernels
+
+    monkeypatch.setattr(pallas_kernels, "FORCE", False)
+    assert not pallas_kernels.enabled()
+    rng = np.random.default_rng(7)
+    n, k = 5000, 600
+    assert k > kernels.ONEHOT_GROUP_LIMIT
+    vals = rng.integers(0, 100, n)
+    gid = rng.integers(0, k, n)
+    valid = rng.random(n) < 0.8
+    got = scatter_sum(jnp.asarray(vals, dtype=jnp.float32),
+                      jnp.asarray(valid), jnp.asarray(gid, jnp.int32), k)
+    want = np.bincount(gid[valid], weights=vals[valid], minlength=k)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)

@@ -419,3 +419,67 @@ def test_bounded_under_sustained_ingest_and_scan(monkeypatch):
     snap = shard.resident.snapshot()
     assert snap["evictions"] + snap["spills"] > 0
     assert _sum_n(shard)[1] == total
+
+
+def test_auto_budget_is_device_wide_not_per_store(monkeypatch):
+    """A table has a store per shard and a cluster many tables, all in
+    one HBM: an auto-budget store may only grow into what the other
+    auto stores on its device leave of the device's share. Explicit
+    budgets stay per store."""
+    import weakref
+
+    resident_mod.RESIDENT_FORCE = True
+    monkeypatch.setattr(resident_mod, "FORCED_BYTES", 1000)
+    # a ledger of its own: stores of earlier tests may still be alive
+    monkeypatch.setattr(resident_mod, "_STORES", weakref.WeakSet())
+    a, b = ResidentStore("dw_a"), ResidentStore("dw_b")
+    fixed = ResidentStore("dw_fixed", budget=600)
+    assert a.budget() == b.budget() == 1000
+    cols = {"v": np.arange(50, dtype=np.int64)}   # 400 B + 50 B validity
+    assert a.promote(1, 50, cols, None)
+    assert a.nbytes == 450
+    assert a.budget() == 1000            # its own bytes do not count
+    assert b.budget() == 550             # what a leaves
+    assert fixed.budget() == 600
+    assert b.promote(1, 50, cols, None) and b.nbytes == 450
+    # b is full at 550: a second portion evicts its first, never a's
+    assert b.promote(2, 50, cols, None)
+    assert b.nbytes == 450 and b.evictions == 1 and a.nbytes == 450
+    a.clear()
+    assert b.budget() == 1000
+
+
+def test_budgets_derive_from_the_device_report(monkeypatch):
+    """The automatic budgets are shares of the HBM the device reports
+    (engine/hbm.py), and nothing where it reports none (CPU)."""
+    import weakref
+
+    from ydb_tpu.engine import blockcache, hbm
+
+    monkeypatch.setattr(resident_mod, "_STORES", weakref.WeakSet())
+    assert hbm.device_bytes() == 0       # the CPU backend of the tests
+    assert resident_mod.default_budget() == 0
+    assert blockcache.default_budget() == 0
+    monkeypatch.setattr(hbm, "device_bytes", lambda: 16 << 30)
+    assert resident_mod.default_budget() == 8 << 30
+    assert blockcache.default_budget() == 2 << 30
+    assert blockcache.DeviceBlockCache().budget() == 2 << 30
+    assert ResidentStore("hbm_auto").budget() == 8 << 30
+
+
+def test_device_slice_binding_moves_resident_columns():
+    """Columns promoted before a mesh binding sit on the default
+    device; binding the store moves them to ITS device, so the mesh
+    scan computes there (and budgets are per device)."""
+    import jax
+
+    devs = jax.devices()
+    assert len(devs) >= 2
+    resident_mod.RESIDENT_FORCE = True
+    st = ResidentStore("slice_move")
+    st.promote(7, 8, {"v": np.arange(8, dtype=np.int64)}, None)
+    ent = st.lookup(7, ("v",))["v"]
+    assert ent.data.devices() == {devs[0]}
+    st.set_device_slice(1, devs[1], 1 << 20)
+    assert ent.data.devices() == ent.validity.devices() == {devs[1]}
+    np.testing.assert_array_equal(np.asarray(ent.data), np.arange(8))

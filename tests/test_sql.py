@@ -692,3 +692,58 @@ def test_or_of_exists_decorrelates():
         "or not exists (select * from w where c.id = cid) "
         "order by id")
     assert np.asarray(r2.cols["id"][0]).tolist() == [2, 4]
+
+
+def test_composite_primary_key_upsert_and_join():
+    """A column table's key may span columns (TPC-H lineitem:
+    (l_orderkey, l_linenumber)): rows route and sort on the first, but
+    upsert dedup compares the WHOLE key — across commits, inside one
+    commit and after compaction — and the planner sees the whole key,
+    so a join on the first column keeps every line."""
+    import numpy as np
+
+    from ydb_tpu.kqp.session import Cluster
+
+    c = Cluster()
+    s = c.session()
+    s.execute("CREATE TABLE li (ok int64, ln int64, v int64, "
+              "PRIMARY KEY (ok, ln)) WITH (shards = 2, upsert = on)")
+    s.execute("CREATE TABLE od (ok int64, w int64, PRIMARY KEY (ok)) "
+              "WITH (shards = 2, upsert = on)")
+    assert c.catalog().primary_keys["li"] == ("ok", "ln")
+    ok = np.repeat(np.arange(1, 501), 4)
+    ln = np.tile(np.arange(1, 5), 500)
+    v = np.arange(2000)
+    t = c.tables["li"]
+    # two batches that split an order between them
+    assert t.insert({"ok": ok[:1002], "ln": ln[:1002],
+                     "v": v[:1002]}).committed
+    assert t.insert({"ok": ok[1002:], "ln": ln[1002:],
+                     "v": v[1002:]}).committed
+    c.tables["od"].insert({"ok": np.arange(1, 501),
+                           "w": np.ones(500, dtype=np.int64)})
+    c._invalidate_plans()
+
+    def totals():
+        r = s.execute("SELECT COUNT(*) AS n, SUM(v) AS sv FROM li")
+        return int(r.cols["n"][0][0]), int(r.cols["sv"][0][0])
+
+    assert totals() == (2000, int(v.sum()))
+    # same key twice in ONE statement: the last wins; other lines stay
+    s.execute("UPSERT INTO li (ok, ln, v) VALUES "
+              "(251, 2, -1), (251, 3, -7), (7, 1, -9), (251, 2, -5)")
+    want = v.copy()
+    want[(ok == 251) & (ln == 2)] = -5
+    want[(ok == 251) & (ln == 3)] = -7
+    want[(ok == 7) & (ln == 1)] = -9
+    assert totals() == (2000, int(want.sum()))
+    r = s.execute("SELECT ln, v FROM li WHERE ok = 251 ORDER BY ln")
+    np.testing.assert_array_equal(r.cols["ln"][0], [1, 2, 3, 4])
+    np.testing.assert_array_equal(r.cols["v"][0], want[ok == 251])
+    j = s.execute("SELECT COUNT(*) AS n, SUM(l.v * o.w) AS sv "
+                  "FROM li l JOIN od o ON l.ok = o.ok")
+    assert (int(j.cols["n"][0][0]), int(j.cols["sv"][0][0])) == \
+        (2000, int(want.sum()))
+    for sh in t.shards:
+        sh.compact()
+    assert totals() == (2000, int(want.sum()))

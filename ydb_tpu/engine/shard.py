@@ -676,8 +676,7 @@ class ColumnShard:
                 lambda: src.blocks(self.config.scan_block_rows,
                                    ex.read_cols)),
             timer=timer, consumed_cb=src.note_block_consumed))
-        # per-scan stage attribution (read/merge/stage/compute seconds);
-        # bench.py surfaces this as metric extras
+        # per-scan stage attribution (read/merge/stage/compute seconds)
         self.last_scan_stages = timer.snapshot()
         # morsel-pipeline attribution (engine.stream_sched): stats are
         # set when the pipelined stream finishes; None on the

@@ -212,7 +212,7 @@ def bench_pruning(rows: int, chunk_rows: int, iters: int,
                   selectivity: float = 0.05, shard=None) -> dict:
     """Selective non-PK filter A/B: stats-on (zone pruning) vs
     stats-off, bit-identical results required. ``shard`` reuses an
-    already-built events shard (bench.py's NDV pass shares one)."""
+    already-built events shard."""
     from ydb_tpu import stats as stats_mod
     from ydb_tpu.ssa import Agg, AggSpec, Call, Col, FilterStep, \
         GroupByStep, Op, Program

@@ -113,8 +113,8 @@ class StageTimer:
     went. Each pipeline site charges its own stage (``read`` / ``merge``
     / ``stage`` / ``compute``); concurrent stages may sum past the
     wall-clock total, which is exactly the overlap being measured.
-    Thread-safe; ``snapshot()`` is what bench.py surfaces as metric
-    extras and what the ``scan.stages`` probe fires.
+    Thread-safe; ``snapshot()`` is what scan spans carry as
+    ``stage_*`` attrs and what the ``scan.stages`` probe fires.
     """
 
     #: canonical scan stages, always present in snapshots (zero if unhit)

@@ -367,7 +367,7 @@ class _Holder:
 def profiled(sql: str = "", kind: str = "select",
              query_class: str = "", tracer=None):
     """Run a block under a fresh root span and hand back its profile
-    (``holder.profile`` after exit) — the bench.py seam for profiling
+    (``holder.profile`` after exit) — the seam for profiling
     engine-tier scans that never pass through a session."""
     from ydb_tpu.obs.tracing import Tracer, activate
 
