@@ -146,7 +146,8 @@ def check_q1(res, ref, nls) -> None:
     assert np.array_equal(col(res, "count_order")[order], ref["count"])
     for name, rname, scale in Q1_SUMS:
         got = scaled_int(res, name, scale)[order]
-        assert np.array_equal(got, ref[rname]), f"Q1 {name} differs"
+        assert np.array_equal(got, ref[rname]), \
+            f"Q1 {name} differs: got {got}, want {ref[rname]}"
     for name, rname, scale in (("avg_qty", "sum_qty", 2),
                                ("avg_price", "sum_base_price", 2),
                                ("avg_disc", "sum_disc", 2)):
@@ -520,19 +521,29 @@ def device_line(cache_dir: str) -> dict:
 
 
 def memory_line(cluster, table_bytes: int) -> None:
-    """The three numbers that have to fit one HBM together."""
+    """What has to fit one HBM together: the tables, the two tiers'
+    budgets (shares of the HBM the device reports, engine/hbm.py), and
+    the rest, which program temporaries, staging and results take."""
     from ydb_tpu.engine import hbm, resident
 
-    # the largest program's temporaries at the path's block size: the
-    # Q1 partial, compiled for a described v5e
-    # (tests/test_tpu_compile.py holds the figure under its bound)
-    q1_temp = 1_930_000_000
     say(f"memory: hbm_bytes={hbm.device_bytes()} "
         f"table_bytes={table_bytes} "
         f"resident_budget={resident.default_budget()} "
         f"block_cache_budget={cluster.scan_block_cache.budget()} "
-        f"scan_block_rows={cluster.config.scan_block_rows} "
-        f"largest_program_temp_bytes~{q1_temp} (Q1 partial, compiled)")
+        f"scan_block_rows={cluster.config.scan_block_rows}")
+
+
+def peak_line(resident_bytes: int) -> None:
+    """Measured: the most the device held at once, and how much of it
+    was not resident table data (temporaries, staging, results)."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    peak = int(stats.get("peak_bytes_in_use", 0))
+    say(f"memory peak: peak_bytes_in_use={peak} "
+        f"resident_bytes={resident_bytes} "
+        f"beside_the_tables={max(peak - resident_bytes, 0)} "
+        f"bytes_limit={stats.get('bytes_limit')}")
 
 
 def phase_one_chip(args, cluster, session, data) -> None:
@@ -582,7 +593,7 @@ def phase_one_chip(args, cluster, session, data) -> None:
     say(f"pgwire Q1: port={pg.port} rows={len(wire_rows)} "
         f"seconds={wire_s:.3f} result=ok (equal to Session.execute)")
     drain_promotions(cluster)
-    tier_report(cluster)
+    peak_line(tier_report(cluster)["bytes"])
 
 
 def device_bytes_report(cluster) -> list[int]:

@@ -303,3 +303,34 @@ def test_fused_flag_env_gating(monkeypatch):
     assert kernels.fused_group_by_enabled()  # default on
     monkeypatch.setattr(kernels, "FUSED_FORCE", False)
     assert not kernels.fused_group_by_enabled()
+
+
+def test_onehot_tier_off_the_gemm_is_bit_exact(monkeypatch):
+    """Where the platform GEMM is not exact (a TPU: f64 dots run as f32
+    MXU passes, s64 dots do not exist) the one-hot tier reduces on the
+    vector unit. Integer banks must equal the limb-GEMM path bit for
+    bit — including sums past 2^32, which is where the chip's f64 dot
+    went wrong — and float banks to rounding."""
+    rng = np.random.default_rng(11)
+    n, k = 4096, 7
+    ints = rng.integers(-(1 << 40), 1 << 50, (n, 5)).astype(np.int64)
+    flts = rng.random((n, 3)) * 1e6
+    gid = jnp.asarray(rng.integers(0, k + 1, n), dtype=jnp.int32)
+    banks = {jnp.dtype("int64"): jnp.asarray(ints),
+             jnp.dtype("float64"): jnp.asarray(flts)}
+    gemm = kernels.fused_group_reduce_banks(banks, gid, k)
+    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: False)
+    vpu = kernels.fused_group_reduce_banks(banks, gid, k)
+    want = np.zeros((k, 5), dtype=np.int64)
+    live = np.asarray(gid) < k
+    np.add.at(want, np.asarray(gid)[live], ints[live])
+    for got in (gemm, vpu):
+        np.testing.assert_array_equal(
+            np.asarray(got[jnp.dtype("int64")]), want)
+    np.testing.assert_allclose(
+        np.asarray(vpu[jnp.dtype("float64")]),
+        np.asarray(gemm[jnp.dtype("float64")]), rtol=1e-12)
+    # the single-bank entry point takes the same turn
+    np.testing.assert_array_equal(
+        np.asarray(kernels.fused_group_reduce(
+            jnp.asarray(ints), gid, k)), want)

@@ -129,13 +129,20 @@ def test_supported_fused_rejects_what_mosaic_refuses():
         jnp.float32, 1024, pallas_kernels.MAX_FUSED_SLOTS + 1)
 
 
+@pytest.mark.parametrize("gemm", (False, True), ids=("vpu", "gemm"))
 @pytest.mark.parametrize("query", ("q1", "q6"))
 def test_scan_partial_fits_beside_a_resident_table(
-        query, one_chip, no_persistent_cache):
-    """The pushdown partial program of Q1/Q6 over one scan block: its
-    temporaries scale with the block (Q1: 1.9 GB at 2^20 rows, 8.6 GB
-    at 2^22), so at ``scan_block_rows`` they stay under TEMP_SHARE of
-    the chip."""
+        query, gemm, one_chip, no_persistent_cache, monkeypatch):
+    """The pushdown partial program of Q1/Q6 over one scan block, in
+    both one-hot tiers: "vpu" is what a TPU traces (kernels.
+    _gemm_is_exact() is false there; steered here because the backend
+    of this process is the CPU), "gemm" what any other backend does.
+    The GEMM tier's temporaries scale with the block (Q1: 1.9 GB at
+    2^20 rows, 8.6 GB at 2^22), so at ``scan_block_rows`` both stay
+    under TEMP_SHARE of the chip."""
+    from ydb_tpu.ssa import kernels
+
+    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: gemm)
     cap = 1 << 12
     data = tpch.TpchData(sf=0.001, seed=5)
     src = ColumnSource(columns=data.tables["lineitem"],
@@ -154,4 +161,6 @@ def test_scan_partial_fits_beside_a_resident_table(
         described, (block, dict(ex.partial.aux)))
     compiled = jax.jit(ex.partial.run).lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert 0 < temp < TEMP_SHARE * V5E_HBM_BYTES, (query, temp)
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, gemm, temp)
+    if gemm and query == "q1":
+        assert temp > 1e9, "the GEMM tier's hit matrix went somewhere?"

@@ -214,9 +214,11 @@ class ResidentStore:
             # reads either copy), or every mesh scan computes there
             import jax
 
-            for e in entries:
-                e.data = jax.device_put(e.data, device)
-                e.validity = jax.device_put(e.validity, device)
+            # the same bytes, already charged at promotion
+            with memsan.seam("resident"):
+                for e in entries:
+                    e.data = jax.device_put(e.data, device)
+                    e.validity = jax.device_put(e.validity, device)
 
     def clear_device_slice(self) -> None:
         with self._lock:

@@ -291,6 +291,8 @@ def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
     dtype = jnp.dtype(dtype or stacked.dtype)
     stacked = stacked.astype(dtype)
     if num_groups <= ONEHOT_GROUP_LIMIT:
+        if not _gemm_is_exact():
+            return _masked_group_sums(stacked, gid, num_groups)
         if stacked.shape[0] < _INT_LIMB_MAX_ROWS:
             # f64 GEMM via the bank encoder (exact for ints through
             # 24-bit limbs — XLA's CPU integer dot is a naive loop)
@@ -307,6 +309,34 @@ def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
         return pallas_kernels.grouped_sum_multi(stacked, gid, num_groups)
     out = jnp.zeros((num_groups, stacked.shape[1]), dtype=dtype)
     return out.at[gid].add(stacked, mode="drop")
+
+
+def _gemm_is_exact() -> bool:
+    """Whether the one-hot tier may ride the platform GEMM. Consulted at
+    TRACE time, like ``pallas_kernels.enabled()``.
+
+    Not on a TPU: it has no f64, so XLA splits an f64 dot into f32 MXU
+    passes whose f32 accumulators round the integer limb sums (measured
+    on a v5e: TPC-H Q1 at SF 1 came back with wrong decimal sums from
+    the final program's [72x12]^T [72x23] dot, while a 2^20-row dot
+    happened to be lowered exactly), and it has no s64 dot at all
+    (``UNIMPLEMENTED ... rewriting computation to not contain X64``).
+    There the tier reduces on the vector unit (_masked_group_sums),
+    which on the same chip is also ~80x faster than the emulated f64
+    GEMM (1.9 ms against 145 ms for 2^20 rows x 11 int64 slots)."""
+    return jax.default_backend() != "tpu"
+
+
+def _masked_group_sums(stacked: jax.Array, gid: jax.Array,
+                       num_groups: int) -> jax.Array:
+    """(rows x slots) -> (groups x slots) sums as one masked where+sum
+    per slot over the shared hit matrix: exact in every dtype (int64
+    adds on the vector unit), no GEMM."""
+    hits = group_hits(gid, num_groups)
+    zero = jnp.zeros((), dtype=stacked.dtype)
+    return jnp.stack(
+        [jnp.sum(jnp.where(hits, stacked[:, j][:, None], zero), axis=0)
+         for j in range(stacked.shape[1])], axis=1)
 
 
 #: 24-bit-limb exactness bound: each limb column sums < 2^24 * rows, so
@@ -334,7 +364,8 @@ def fused_group_reduce_banks(banks: dict, gid: jax.Array,
     reduces each bank via fused_group_reduce (Pallas / 2D scatter).
     """
     rows = next(iter(banks.values())).shape[0] if banks else 0
-    if num_groups > ONEHOT_GROUP_LIMIT or rows >= _INT_LIMB_MAX_ROWS:
+    if num_groups > ONEHOT_GROUP_LIMIT or rows >= _INT_LIMB_MAX_ROWS \
+            or not _gemm_is_exact():
         return {dt: fused_group_reduce(st, gid, num_groups, dtype=dt)
                 for dt, st in banks.items()}
     if rows <= _INT_LIMB2_MAX_ROWS:
