@@ -729,6 +729,15 @@ def test_composite_primary_key_upsert_and_join():
         return int(r.cols["n"][0][0]), int(r.cols["sv"][0][0])
 
     assert totals() == (2000, int(v.sum()))
+    # the two batches touch on order 251 (lines 1-2 | 3-4) without
+    # overlapping on the whole key: no host-merged cluster joins them
+    from ydb_tpu.engine.reader import plan_clusters
+
+    for sh in t.shards:
+        metas = sh.visible_portions()
+        assert len(metas) == 2
+        assert [len(cl) for cl in plan_clusters(metas, dedup=True)] \
+            == [1, 1]
     # same key twice in ONE statement: the last wins; other lines stay
     s.execute("UPSERT INTO li (ok, ln, v) VALUES "
               "(251, 2, -1), (251, 3, -7), (7, 1, -9), (251, 2, -5)")

@@ -72,7 +72,6 @@ class JoinSpec:
     kind: str = "inner"  # inner | left | semi | anti (expand: inner|left)
     suffix: str = ""
     expand: bool = False  # N:M expansion vs N:1 lookup
-    fanout_hint: float = 4.0  # expand: initial output capacity multiple
 
 
 @dataclasses.dataclass(frozen=True)

@@ -52,6 +52,13 @@ class PortionMeta:
     # PK range stats for scan pruning (min/max of the first PK column)
     pk_min: int | None = None
     pk_max: int | None = None
+    # composite keys under upsert: the REST of the key at the portion's
+    # first and last row (rows sort on the whole key). Two portions that
+    # merely touch on the first column — an order's lines split across
+    # two commits — are then told from two that overlap
+    # (reader.plan_clusters). None = single-column key or older metadata.
+    key_min_rest: list | None = None
+    key_max_rest: list | None = None
     # min/max of the TTL column, for eviction planning
     ttl_min: int | None = None
     ttl_max: int | None = None

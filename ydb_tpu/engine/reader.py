@@ -132,26 +132,37 @@ def plan_clusters(
     """Group portions into PK-overlap clusters (granule planning analog).
 
     Without dedup every portion streams independently. With dedup,
-    portions whose [pk_min, pk_max] ranges overlap must merge together;
-    portions with no PK stats (empty or statless) conservatively join
-    one cluster with everything they might overlap.
+    portions whose key ranges overlap must merge together; portions
+    with no PK stats (empty or statless) conservatively join one
+    cluster with everything they might overlap. A range is [pk_min,
+    pk_max] on the first key column, refined by the rest of a composite
+    key where the portion recorded it: without that, bulk-loaded
+    batches that split one first-column value between them (an order's
+    lines) would chain the whole table into one host-merged cluster.
     """
     if not dedup:
         return [[m] for m in metas]
+
+    def lo(m):
+        return (m.pk_min, *(m.key_min_rest or ()))
+
+    def hi(m):
+        return (m.pk_max, *(m.key_max_rest or (float("inf"),)))
+
     statless = [m for m in metas if m.pk_min is None]
     ranged = sorted(
         (m for m in metas if m.pk_min is not None),
-        key=lambda m: (m.pk_min, m.pk_max, m.portion_id),
+        key=lambda m: (lo(m), hi(m), m.portion_id),
     )
     clusters: list[list[PortionMeta]] = []
     cur: list[PortionMeta] = []
-    cur_max: int | None = None
+    cur_max: tuple | None = None
     for m in ranged:
-        if cur and m.pk_min > cur_max:
+        if cur and lo(m) > cur_max:
             clusters.append(cur)
             cur, cur_max = [], None
         cur.append(m)
-        cur_max = m.pk_max if cur_max is None else max(cur_max, m.pk_max)
+        cur_max = hi(m) if cur_max is None else max(cur_max, hi(m))
     if cur:
         clusters.append(cur)
     if statless:

@@ -63,7 +63,7 @@ RESIDENT_FORCE: "bool | None" = None
 #: (CPU tests and kernelbench A/Bs: the bytes are host memory)
 FORCED_BYTES = 4 << 30
 
-#: every live store, for the device-wide ledger (auto_budget_left)
+#: every live store: ResidentStore.budget() reads the others' bytes
 _STORES: "weakref.WeakSet[ResidentStore]" = weakref.WeakSet()
 
 #: host-path reads of one portion before heat promotion triggers

@@ -377,6 +377,11 @@ class ColumnShard:
             meta.zones = column_zones(cols, validity)
         if self.pk_column and self.pk_column in cols:
             meta.pk_min, meta.pk_max = column_stats(cols[self.pk_column])
+            rest = [cols[k] for k in self.pk_columns[1:]]
+            if self.upsert and rest and meta.num_rows and all(
+                    np.issubdtype(a.dtype, np.integer) for a in rest):
+                meta.key_min_rest = [int(a[0]) for a in rest]
+                meta.key_max_rest = [int(a[-1]) for a in rest]
         if self.ttl_column and self.ttl_column in cols:
             meta.ttl_min, meta.ttl_max = column_stats(cols[self.ttl_column])
         with self._meta_lock:

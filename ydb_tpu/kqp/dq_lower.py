@@ -78,12 +78,9 @@ def plan_to_stages(plan, n_tasks: int = 2, estimator=None,
 
     ``estimator(node) -> float | None`` supplies statistics-based row
     estimates (stats.cost.estimate_plan_rows bound to the aggregator's
-    TableStats). Two consumers:
+    TableStats). One consumer (expand-join output capacity needs no
+    estimate: ``run_equi_join`` sizes it from the exact match count):
 
-      * expand-join output capacity — ``fanout_hint`` is sized from the
-        estimated output/probe ratio instead of the fixed 4x guess, so
-        skew neither over-allocates HBM nor walks the overflow-retry
-        ladder (bit-identical: capacity only changes dead padding);
       * build-side selection (``allow_swap=True``) — an inner expand
         join whose "build" side is estimated much larger than its probe
         side swaps the two (a grace join should build on the SMALL
@@ -140,14 +137,6 @@ def plan_to_stages(plan, n_tasks: int = 2, estimator=None,
                              payload=node.payload, kind=node.kind,
                              suffix=node.suffix)
             else:
-                fanout = node.fanout_hint
-                out_rows = est(node)
-                base = b_rows if swapped else p_rows
-                if out_rows is not None and base:
-                    # estimated per-probe-row expansion, padded 2x and
-                    # bounded: capacity sizing only, never semantics
-                    fanout = min(64.0, max(1.0,
-                                           2.0 * out_rows / base))
                 pp = node.probe_payload
                 bp = node.build_payload
                 if swapped:
@@ -155,7 +144,7 @@ def plan_to_stages(plan, n_tasks: int = 2, estimator=None,
                 j = JoinSpec(probe_keys, build_keys,
                              probe_payload=pp, build_payload=bp,
                              kind=node.kind, suffix=node.build_suffix,
-                             expand=True, fanout_hint=fanout)
+                             expand=True)
             return add(program=None,
                        inputs=(UnionAllInput(pi), UnionAllInput(bi)),
                        output=None, tasks=n_tasks, join=j)

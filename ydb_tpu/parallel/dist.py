@@ -20,13 +20,15 @@ collectives, not a message exchange.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ydb_tpu import dtypes
-from ydb_tpu.analysis import budget_ok, memsan
+from ydb_tpu.analysis import memsan
 from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.blocks.dictionary import DictionarySet
 from ydb_tpu.engine.oracle import OracleTable
@@ -56,34 +58,51 @@ def stack_blocks(blocks: list[TableBlock]) -> TableBlock:
     return out
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _fit_with_device_axis(block: TableBlock, capacity: int) -> TableBlock:
+    """Cut or zero-pad every column to ``capacity`` rows (live rows sit
+    in the prefix; padding validity is False) and give every leaf a
+    leading size-1 device axis — ONE program per block shape and
+    device, where the eager spelling is several per column."""
+    def fit(a):
+        n = a.shape[0]
+        if n > capacity:
+            a = a[:capacity]
+        elif n < capacity:
+            a = jnp.concatenate(
+                [a, jnp.zeros((capacity - n,), dtype=a.dtype)])
+        return a[None]
+
+    cols = {n: Column(fit(c.data), fit(c.validity))
+            for n, c in block.columns.items()}
+    return TableBlock(cols, jnp.asarray(block.length)[None], block.schema)
+
+
 def place_shards(blocks: list[TableBlock], mesh,
+                 capacity: int | None = None,
                  owner: str = "mesh_place") -> TableBlock:
-    """Per-shard blocks -> one stacked block sharded over the mesh's
-    shard axis, each shard put on ITS device.
+    """Per-shard blocks -> one stacked block of ``capacity`` rows per
+    shard (default: the blocks' own, which must then agree), sharded
+    over the mesh's shard axis, each shard put on ITS device.
 
     ``device_put(stack_blocks(blocks), sharding)`` stages the whole
     stack on one device first, and cannot even stack blocks that
     already live on different devices (the resident tier binds each
     shard's columns to the device that scans it): assemble the global
-    array from the single-device pieces instead."""
+    arrays from the single-device pieces instead."""
     sharding = NamedSharding(mesh, P(SHARD_AXIS))
     devices = [row[0] for row in mesh.devices]
     assert len(blocks) == len(devices) == mesh.devices.size, \
         (len(blocks), mesh.devices.shape)
-
-    def place(arrays):
-        pieces = [jax.device_put(a[None], d)
-                  for a, d in zip(arrays, devices)]
-        return jax.make_array_from_single_device_arrays(
-            (len(pieces),) + arrays[0].shape, sharding, pieces)
-
-    sch = blocks[0].schema
+    if capacity is None:
+        capacity = blocks[0].capacity
     with memsan.seam("staging"):
-        out = TableBlock(
-            {n: Column(place([b.columns[n].data for b in blocks]),
-                       place([b.columns[n].validity for b in blocks]))
-             for n in sch.names},
-            place([jnp.asarray(b.length) for b in blocks]), sch)
+        pieces = [_fit_with_device_axis(jax.device_put(b, d), capacity)
+                  for b, d in zip(blocks, devices)]
+        out = jax.tree_util.tree_map(
+            lambda *xs: jax.make_array_from_single_device_arrays(
+                (len(xs),) + xs[0].shape[1:], sharding, list(xs)),
+            *pieces)
     if memsan.armed():
         memsan.charge(memsan.nbytes_of(out), "staging", owner=owner)
     return out
@@ -176,23 +195,6 @@ def _concat_states(parts: list) -> TableBlock:
         n: np.concatenate([p[1][n] for p in parts]) for n in sch.names
     }
     return TableBlock.from_numpy(arrays, sch, validity)
-
-
-@budget_ok("transient pad-to-capacity copy: every call site feeds the"
-           " result straight into a charging stack_blocks seam, which"
-           " accounts the stacked footprint")
-def _pad_state(block: TableBlock, capacity: int) -> TableBlock:
-    if block.capacity == capacity:
-        return block
-    cols = {}
-    for n, c in block.columns.items():
-        pad = capacity - c.data.shape[0]
-        cols[n] = Column(
-            jnp.concatenate(
-                [c.data, jnp.zeros((pad,), dtype=c.data.dtype)]),
-            jnp.concatenate([c.validity, jnp.zeros((pad,), dtype=bool)]),
-        )
-    return TableBlock(cols, block.length, block.schema)
 
 
 def _merge_pair(a: TableBlock, b: TableBlock, merge_kinds, rank_tables):
@@ -423,11 +425,11 @@ class MeshScan:
                 else:
                     st = self._pair_jit(st, part)
             states.append(st if foldable else _concat_states(parts))
-        if not foldable:
-            # compact states vary in size shard-to-shard: pad to common
-            cap = max(s.capacity for s in states)
-            states = [_pad_state(s, cap) for s in states]
-        out = self._merge_final_step(place_shards(states, self.mesh))
+        # compact (non-foldable) states vary in size shard-to-shard:
+        # pad to the common capacity
+        out = self._merge_final_step(place_shards(
+            states, self.mesh,
+            capacity=max(s.capacity for s in states)))
         return OracleTable.from_block(out)
 
     def execute(self, source: ColumnSource) -> OracleTable:

@@ -45,7 +45,6 @@ from ydb_tpu.engine.scan import (
 from ydb_tpu.parallel.dist import (
     MeshScan,
     _local,
-    _pad_state,
     _relocal,
     place_shards,
 )
@@ -316,19 +315,7 @@ class MeshPlanExecutor:
                     sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols))
             locals_.append(blk)
         cap = _round_up(max(int(b.length) for b in locals_))
-        return place_shards(
-            [_pad_state(self._slice(b, cap), cap) for b in locals_],
-            self.mesh)
-
-    @staticmethod
-    def _slice(block: TableBlock, cap: int) -> TableBlock:
-        if block.capacity <= cap:
-            return block
-        cols = {
-            n: Column(c.data[:cap], c.validity[:cap])
-            for n, c in block.columns.items()
-        }
-        return TableBlock(cols, block.length, block.schema)
+        return place_shards(locals_, self.mesh, capacity=cap)
 
     def _join(self, plan, memo, expand: bool) -> TableBlock:
         probe = self._exec(plan.probe, memo)

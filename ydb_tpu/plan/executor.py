@@ -617,7 +617,6 @@ def _execute_node(plan: PlanNode, db: Database, _memo: dict) -> TableBlock:
             kind=plan.kind, suffix=plan.build_suffix, expand=True,
             probe_payload=plan.probe_payload,
             build_payload=plan.build_payload,
-            fanout_hint=plan.fanout_hint,
         )
     if isinstance(plan, Transform):
         block = execute_plan(plan.input, db, _memo)

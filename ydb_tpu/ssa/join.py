@@ -143,15 +143,28 @@ def run_equi_join(
     payload=(),
     probe_payload=(),
     build_payload=(),
-    fanout_hint: float = 4.0,
 ) -> TableBlock:
     """One dispatch for every equi-join shape — the single-chip plan
     executor and the DQ grace-bucket join call THIS so their semantics
     cannot drift (test_sql_dq.py asserts bit parity between the paths).
 
     Lookup (N:1) joins support inner/left/semi/anti; expand (N:M) joins
-    support inner/left and retry with exact capacity on overflow.
+    support inner/left.
+
+    Shapes are what a join costs to COMPILE: the build side is sorted,
+    and XLA's TPU sort takes minutes to compile past ~2^14 rows (a
+    (int64, bool) lexsort of 363k rows: 196 s for a v5e), once per
+    distinct shape. So both sides pad to their shape class — buckets of
+    14,912 and 15,101 rows share one program — and an expand join runs
+    in two programs: the sort-bearing match (independent of the output
+    size), then, with the exact match count read back, the emit at the
+    count's shape class. No guessed capacity, no overflow retry that
+    would compile the sort again.
     """
+    from ydb_tpu.ssa.plan_fuse import shape_class
+
+    probe = _pad_block(probe, shape_class(probe.capacity))
+    build = _pad_block(build, shape_class(build.capacity))
     if not expand:
         if kind not in ("inner", "left", "semi", "anti"):
             raise ValueError(kind)
@@ -161,21 +174,37 @@ def run_equi_join(
     if kind not in ("inner", "left"):
         # expand_join silently computes INNER for anything else
         raise ValueError(f"expand join does not support kind {kind!r}")
-    cap = max(int(probe.capacity * fanout_hint), 1024)
-    while True:
-        out, total = _expand_join_jit(
-            probe, build, tuple(probe_keys), tuple(build_keys),
-            tuple(probe_payload), tuple(build_payload), cap, suffix,
-            kind)
-        if int(total) <= cap:
-            return out
-        cap = int(int(total) + 1023) // 1024 * 1024  # exact retry
+    match = _expand_match_jit(probe, build, tuple(probe_keys),
+                              tuple(build_keys), kind)
+    # the one sync of an expand join: the exact output size
+    cap = shape_class(int(match[-1]))
+    return _expand_emit_jit(probe, build, match, tuple(probe_payload),
+                            tuple(build_payload), cap, suffix, kind)[0]
 
 
 # The join kernels are some fifty jnp ops each. Called eagerly every op
 # is its own XLA program per operand shape: one TPC-H Q3 through the DQ
-# graph made 271 compiles (about 1.4 s each on a v5e). Under jit a join
-# is one program per (shapes, keys, payload, kind).
+# graph made 271 compiles. Under jit a join is one or two programs per
+# (shape classes, keys, payload, kind).
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_rows_jit(block: TableBlock, capacity: int) -> TableBlock:
+    def pad(a):
+        return jnp.concatenate(
+            [a, jnp.zeros((capacity - a.shape[0],), dtype=a.dtype)])
+
+    cols = {n: Column(pad(c.data), pad(c.validity))
+            for n, c in block.columns.items()}
+    return TableBlock(cols, block.length, block.schema)
+
+
+def _pad_block(block: TableBlock, capacity: int) -> TableBlock:
+    """Zero-pad to ``capacity`` rows (validity False; the live prefix
+    and ``length`` are untouched)."""
+    if block.capacity == capacity:
+        return block
+    return _pad_rows_jit(block, capacity)
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _lookup_join_of_kind(probe, build, probe_keys, build_keys, payload,
                          suffix, kind):
@@ -193,34 +222,11 @@ def _lookup_join_of_kind(probe, build, probe_keys, build_keys, payload,
     return kernels.compact(probe, ~found & probe.row_mask())  # anti
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7, 8))
-def _expand_join_jit(probe, build, probe_keys, build_keys, probe_payload,
-                     build_payload, out_capacity, build_suffix, kind):
-    return expand_join(
-        probe, build, list(probe_keys), list(build_keys),
-        list(probe_payload), list(build_payload),
-        out_capacity=out_capacity, build_suffix=build_suffix, kind=kind)
-
-
-def expand_join(
-    probe: TableBlock,
-    build: TableBlock,
-    probe_keys: list[str],
-    build_keys: list[str],
-    probe_payload: list[str],
-    build_payload: list[str],
-    out_capacity: int,
-    build_suffix: str = "",
-    kind: str = "inner",
-) -> tuple[TableBlock, jax.Array]:
-    """N:M equi-join with static output capacity.
-
-    ``kind``: "inner" emits matches only; "left" additionally emits every
-    unmatched live probe row once with NULL build payload (LEFT OUTER).
-    Returns (joined block, total rows). Rows beyond ``out_capacity``
-    are truncated — callers check ``total <= out_capacity`` (host
-    side) and retry bigger or pre-partition (grace) if exceeded.
-    """
+def _expand_match(probe, build, probe_keys, build_keys, kind):
+    """The sort-bearing half of an expand join: per probe row, where its
+    matches start in the sorted build side and how many output rows it
+    emits. Returns (order, lo, matches, offsets, total); independent of
+    the output capacity."""
     pk, plive = _join_keys_live(probe, probe_keys)
     bk, blive = _join_keys_live(build, build_keys)
     # LEFT JOIN keeps probe rows whose key is NULL too (they just match
@@ -235,7 +241,7 @@ def expand_join(
     lo = jnp.minimum(lo, n_live)
     hi = jnp.minimum(hi, n_live)
     # int64 accounting: skewed keys can exceed 2^31 matches, and a wrapped
-    # total would defeat the overflow-retry protocol
+    # total would defeat the capacity protocol
     matches = jnp.where(plive, (hi - lo).astype(jnp.int64), jnp.int64(0))
     if kind == "left":
         counts = jnp.where(row_live, jnp.maximum(matches, 1), 0)
@@ -243,7 +249,18 @@ def expand_join(
         counts = matches
     offsets = jnp.cumsum(counts)  # inclusive
     total = offsets[-1] if counts.shape[0] else jnp.int64(0)
-    starts = offsets - counts
+    return order, lo, matches, offsets, total
+
+
+def _expand_emit(probe, build, match, probe_payload, build_payload,
+                 out_capacity, build_suffix, kind):
+    """The other half: map each of ``out_capacity`` output slots back
+    to (probe row, k-th match) and gather the payload."""
+    order, lo, matches, offsets, total = match
+    # counts = offsets - starts; a left join's pad slot for an unmatched
+    # probe row has count 1 and matches 0
+    starts = jnp.concatenate(
+        [jnp.zeros((1,), offsets.dtype), offsets[:-1]])
 
     # map each output slot j to (probe row i, k-th match)
     j = jnp.arange(out_capacity, dtype=offsets.dtype)
@@ -276,3 +293,32 @@ def expand_join(
         TableBlock(cols, length, dtypes.Schema(tuple(fields))),
         total,
     )
+
+
+_expand_match_jit = jax.jit(_expand_match, static_argnums=(2, 3, 4))
+_expand_emit_jit = jax.jit(_expand_emit, static_argnums=(3, 4, 5, 6, 7))
+
+
+def expand_join(
+    probe: TableBlock,
+    build: TableBlock,
+    probe_keys: list[str],
+    build_keys: list[str],
+    probe_payload: list[str],
+    build_payload: list[str],
+    out_capacity: int,
+    build_suffix: str = "",
+    kind: str = "inner",
+) -> tuple[TableBlock, jax.Array]:
+    """N:M equi-join with static output capacity, for callers that trace
+    it into a program of their own (fused plans, the mesh).
+
+    ``kind``: "inner" emits matches only; "left" additionally emits every
+    unmatched live probe row once with NULL build payload (LEFT OUTER).
+    Returns (joined block, total rows). Rows beyond ``out_capacity``
+    are truncated — callers check ``total <= out_capacity`` (host
+    side) and retry bigger or pre-partition (grace) if exceeded.
+    """
+    match = _expand_match(probe, build, probe_keys, build_keys, kind)
+    return _expand_emit(probe, build, match, probe_payload,
+                        build_payload, out_capacity, build_suffix, kind)
