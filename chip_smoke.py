@@ -546,7 +546,7 @@ def peak_line(resident_bytes: int) -> None:
         f"bytes_limit={stats.get('bytes_limit')}")
 
 
-def phase_one_chip(args, cluster, session, data) -> None:
+def phase_one_chip(cluster, session, data) -> None:
     from ydb_tpu.api.pgwire import PgWireServer
     from ydb_tpu.ssa import plan_fuse
     from ydb_tpu.workload.queries import TPCH
@@ -616,7 +616,7 @@ def device_bytes_report(cluster) -> list[int]:
     return [held[d.id] for d in jax.devices()]
 
 
-def phase_four_chips(args, cluster, session, data) -> None:
+def phase_four_chips(cluster, session, data) -> None:
     import jax
 
     from ydb_tpu.workload.queries import TPCH
@@ -699,9 +699,9 @@ def main() -> int:
         cluster = Cluster(store=DirBlobStore(root))
         session = cluster.session()
         if args.chips == 4:
-            phase_four_chips(args, cluster, session, data)
+            phase_four_chips(cluster, session, data)
         else:
-            phase_one_chip(args, cluster, session, data)
+            phase_one_chip(cluster, session, data)
     finally:
         if cluster is not None:
             cluster.stop()

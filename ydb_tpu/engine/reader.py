@@ -411,12 +411,12 @@ class PortionStreamSource:
                     # row with a first-column value <= bound is in this
                     # batch, so a stable sort of the batch on the whole
                     # key puts equal tuples together oldest -> newest
+                    of_run = [run_idx == r for r in range(len(parts))]
                     keys = []
                     for k in self.shard.pk_columns:
                         a = np.empty(len(run_idx),
                                      dtype=parts[0][0][k].dtype)
-                        for r, p in enumerate(parts):
-                            sel = run_idx == r
+                        for p, sel in zip(parts, of_run):
                             a[sel] = p[0][k][row_idx[sel]]
                         keys.append(a)
                     order = np.lexsort(keys[::-1])

@@ -161,10 +161,14 @@ def run_equi_join(
     count's shape class. No guessed capacity, no overflow retry that
     would compile the sort again.
     """
-    from ydb_tpu.ssa.plan_fuse import shape_class
+    from ydb_tpu.ssa.plan_fuse import fit_blocks, shape_class
 
-    probe = _pad_block(probe, shape_class(probe.capacity))
-    build = _pad_block(build, shape_class(build.capacity))
+    def fit(block):  # zero-pad to the shape class; live prefix untouched
+        cap = shape_class(block.capacity)
+        return block if cap == block.capacity else fit_blocks(
+            (block,), cap)
+
+    probe, build = fit(probe), fit(build)
     if not expand:
         if kind not in ("inner", "left", "semi", "anti"):
             raise ValueError(kind)
@@ -186,25 +190,6 @@ def run_equi_join(
 # is its own XLA program per operand shape: one TPC-H Q3 through the DQ
 # graph made 271 compiles. Under jit a join is one or two programs per
 # (shape classes, keys, payload, kind).
-@functools.partial(jax.jit, static_argnums=1)
-def _pad_rows_jit(block: TableBlock, capacity: int) -> TableBlock:
-    def pad(a):
-        return jnp.concatenate(
-            [a, jnp.zeros((capacity - a.shape[0],), dtype=a.dtype)])
-
-    cols = {n: Column(pad(c.data), pad(c.validity))
-            for n, c in block.columns.items()}
-    return TableBlock(cols, block.length, block.schema)
-
-
-def _pad_block(block: TableBlock, capacity: int) -> TableBlock:
-    """Zero-pad to ``capacity`` rows (validity False; the live prefix
-    and ``length`` are untouched)."""
-    if block.capacity == capacity:
-        return block
-    return _pad_rows_jit(block, capacity)
-
-
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _lookup_join_of_kind(probe, build, probe_keys, build_keys, payload,
                          suffix, kind):
