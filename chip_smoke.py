@@ -77,12 +77,12 @@ def cpu_q1(li, cutoff):
     disc_price = price * (100 - disc)                 # scale 4
     charge = disc_price * (100 + li["l_tax"][m])      # scale 6
     out = {"count": np.bincount(gid, minlength=ng)}
+    of_group = [gid == g for g in range(ng)]
     for name, col in (("sum_qty", qty), ("sum_base_price", price),
                       ("sum_disc_price", disc_price),
                       ("sum_charge", charge), ("sum_disc", disc)):
-        acc = np.zeros(ng, dtype=np.int64)
-        np.add.at(acc, gid, col)
-        out[name] = acc
+        out[name] = np.array([int(col[m_g].sum()) for m_g in of_group],
+                             dtype=np.int64)
     keep = out["count"] > 0
     out = {k: v[keep] for k, v in out.items()}
     out["gid"] = np.flatnonzero(keep)
