@@ -667,6 +667,10 @@ def main() -> int:
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4: only the mesh path and its comparison")
     args = ap.parse_args()
+    if not __debug__:
+        print("chip_smoke: its checks are assert statements; run it "
+              "without -O", file=sys.stderr)
+        return 1
 
     import jax
 
