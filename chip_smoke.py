@@ -203,8 +203,8 @@ def executor_of(profile) -> str:
     return "walk"
 
 
-#: XLA backend compiles so far (JAX fires the event once per compile,
-#: never on a jit-cache or persistent-cache hit)
+#: programs XLA built so far, or fetched from the persistent cache (JAX
+#: fires the event around both; never on a jit-cache hit)
 COMPILES = [0]
 
 
