@@ -1,0 +1,9 @@
+"""Device: 1 - the union of device-operation intervals over the traced
+window, in %."""
+
+
+def read(run):
+    trace = run.get("trace")
+    if not trace or not trace["busy_s"]:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
