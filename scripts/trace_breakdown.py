@@ -1,0 +1,219 @@
+#!/usr/bin/env python3
+"""One traced run of a benchmark cell, read below the benchmark's own
+reduction: device-idle seconds by the innermost ``ydb.*`` host span
+that covers each gap, and device time by ``ydb.*`` named scope.
+
+    python scripts/trace_breakdown.py --workload tpch-sf1.join --seed 7
+
+It is ``bench/run.py --trace 1`` with one more reader on the same
+``.xplane.pb`` (the benchmark deletes the trace once it has reduced
+it). PERF.md section 5 is written from its output; a ``benchmark`` PR
+can lift the two functions into ``bench/trace_reduce.py``. The result
+goes to stdout and, as JSON, under ``chiprun_out/``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+sys.path[:0] = [str(ROOT), str(ROOT / "bench")]
+
+PREFIX = "ydb."
+#: spans that cover everything beneath them: a gap put down to one of
+#: these is not explained
+COVERING = {"ydb.query", "ydb.execute", "ydb.dq", "ydb.scan",
+            "ydb.transform", "ydb.analyze"}
+
+
+def host_lines(profile) -> list:
+    """Per host thread line, its ``ydb.*`` events as (start, end, name)."""
+    out = []
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            evs = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                         for e in ln.events if e.name.startswith(PREFIX))
+            if evs:
+                out.append(evs)
+    return out
+
+
+def idle_by_span(busy, lines, lo, hi) -> dict:
+    """Idle nanoseconds of [lo, hi) outside ``busy`` (sorted disjoint
+    intervals), by the innermost event of the statement threads' lines
+    (those that hold a ``ydb.query``) covering each instant; ``(none)``
+    where no ``ydb.*`` event covers it."""
+    gaps, at = [], lo
+    for s, e in busy:
+        if s > at:
+            gaps.append((at, min(s, hi)))
+        at = max(at, e)
+    if hi > at:
+        gaps.append((at, hi))
+    events = [ev for evs in lines
+              if any(n == "ydb.query" for _, _, n in evs) for ev in evs]
+    out = {}
+    for a, b in gaps:
+        cuts = sorted({a, b} | {t for s, e, _ in events for t in (s, e)
+                                if a < t < b})
+        for x, y in zip(cuts, cuts[1:]):
+            covering = [(e - s, n) for s, e, n in events
+                        if s <= x and y <= e]
+            name = min(covering)[1] if covering else "(none)"
+            out[name] = out.get(name, 0.0) + (y - x)
+    return out
+
+
+def self_by_span(lines, lo, hi) -> dict:
+    """Self nanoseconds by event name on the statement threads' lines
+    over [lo, hi): each instant goes to the innermost event covering
+    it, so the names sum to the time some statement was in flight."""
+    out = idle_by_span([], lines, lo, hi)
+    out.pop("(none)", None)
+    return out
+
+
+def device_time_by_scope(path: str, lo: float, hi: float) -> dict:
+    """Device seconds of [lo, hi) by ``ydb.<kernel>`` scope and the
+    heaviest operations with scope and source line. The scope is not
+    among an event's own stats (all that ``ProfileData`` hands out): on
+    a v5e it is in the ``tf_op`` stat of the event's *metadata*, the
+    HLO ``op_name`` (``jit(run)/ydb.compact/gather:``), beside
+    ``source`` (file:line). Reading those takes the xplane proto, which
+    TensorFlow ships; without it there is nothing to read."""
+    try:
+        from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    except ImportError:
+        return {}
+    import trace_reduce
+
+    space = xplane_pb2.XSpace()
+    space.ParseFromString(pathlib.Path(path).read_bytes())
+    by_scope, by_op, carrier = {}, {}, {}
+    for plane in space.planes:
+        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+            continue
+        stat_name = {k: v.name for k, v in plane.stat_metadata.items()}
+        described = {}
+        for mid, md in plane.event_metadata.items():
+            strings = {stat_name.get(st.metadata_id): st.str_value
+                       for st in md.stats
+                       if st.WhichOneof("value") == "str_value"}
+            scope = "(none)"
+            for key, value in strings.items():
+                parts = [x for x in value.split("/")
+                         if x.startswith(PREFIX)]
+                if parts:
+                    scope = parts[-1].rstrip(":")   # the innermost
+                    carrier[key] = carrier.get(key, 0) + 1
+                    break
+            described[mid] = (scope, md.display_name or md.name[:40],
+                              strings.get("source", "").replace(
+                                  str(ROOT) + "/", ""))
+        for line in plane.lines:
+            if line.name != trace_reduce.OPS_LINE:
+                continue
+            for ev in line.events:
+                start = line.timestamp_ns + ev.offset_ps / 1e3
+                dur = ev.duration_ps / 1e3
+                if start + dur <= lo or start >= hi:
+                    continue
+                scope, name, source = described[ev.metadata_id]
+                by_scope[scope] = by_scope.get(scope, 0.0) + dur
+                key = f"{scope} | {name} | {source}"
+                by_op[key] = by_op.get(key, 0.0) + dur
+    return {"by_scope": by_scope, "by_op": by_op, "scope_stat": carrier}
+
+
+def breakdown(path: str) -> dict:
+    from jax.profiler import ProfileData
+
+    import trace_reduce
+
+    profile = ProfileData.from_file(path)
+    loaded = trace_reduce.load(path)
+    lo, hi = trace_reduce.window_of(loaded["spans"])
+    lines = host_lines(profile)
+    idle = {}
+    for plane in profile.planes:
+        if not trace_reduce.DEVICE_PLANE.match(plane.name):
+            continue
+        for ln in plane.lines:
+            if ln.name != trace_reduce.OPS_LINE:
+                continue
+            busy = [(float(e.start_ns), float(e.start_ns + e.duration_ns))
+                    for e in ln.events
+                    if e.start_ns + e.duration_ns > lo and e.start_ns < hi]
+            for k, v in idle_by_span(trace_reduce.union(busy), lines,
+                                     lo, hi).items():
+                idle[k] = idle.get(k, 0.0) + v
+    scopes = device_time_by_scope(path, lo, hi)
+
+    def top(d, n=12):
+        return [[k, round(v / 1e9, 6)] for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])[:n]]
+
+    total_idle = sum(idle.values())
+    named = sum(v for k, v in idle.items()
+                if k != "(none)" and k not in COVERING)
+    per_statement = {}
+    for evs in lines:
+        for _, _, n in evs:
+            per_statement[n] = per_statement.get(n, 0) + 1
+    statements = max(per_statement.get("ydb.query", 0), 1)
+    return {
+        "window_s": (hi - lo) / 1e9,
+        "idle_s": total_idle / 1e9,
+        "idle_named_leaf_share": named / total_idle if total_idle else None,
+        "idle_by_span": top(idle, 20),
+        "device_s_by_scope": top(scopes.get("by_scope", {}), 20),
+        "device_s_top_ops": top(scopes.get("by_op", {}), 10),
+        "scope_stat": scopes.get("scope_stat", {}),
+        "host_self_s_by_span": top(self_by_span(lines, lo, hi), 20),
+        "host_events_per_statement": round(
+            sum(per_statement.values()) / statements, 1),
+        "spans_per_statement": round(
+            sum(v for k, v in per_statement.items()
+                if not k.startswith(PREFIX + "stage.")) / statements, 1),
+        "statements_traced": statements,
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, default=40.0)
+    args = ap.parse_args(argv)
+
+    import run
+    import trace_reduce
+
+    found = {}
+    newest = trace_reduce.newest_trace
+
+    def newest_and_read(trace_dir):
+        path = newest(trace_dir)
+        found.update(breakdown(path))
+        return path
+
+    trace_reduce.newest_trace = newest_and_read
+    cell = run.load_cell(args.workload)
+    run.check_device(cell["chips"])
+    result = run.run_cell(cell, args.seed, args.seconds, True)
+    found["result"] = {k: result[k] for k in ("correct", "metrics")}
+    out = ROOT / "chiprun_out"
+    out.mkdir(exist_ok=True)
+    (out / f"breakdown_{args.workload}_{args.seed}.json").write_text(
+        json.dumps(found, indent=1))
+    print(json.dumps(found, indent=1))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -38,7 +38,7 @@ def test_span_nesting_and_export():
     assert {s.name for s in spans} == {"query", "plan", "execute"}
     by_name = {s.name: s for s in spans}
     assert by_name["plan"].parent_id == by_name["query"].span_id
-    assert "resourceSpans" in tr.export_otlp_json()
+    assert all(s.thread == by_name["query"].thread for s in spans)
 
 
 def test_session_emits_spans_and_counters():

@@ -13,7 +13,8 @@ from ydb_tpu.kqp.session import Cluster
 from ydb_tpu.obs import tracing
 from ydb_tpu.obs.counters import Histogram
 from ydb_tpu.obs.probes import TraceSession
-from ydb_tpu.obs.profile import ProfileRing, build_profile
+from ydb_tpu.obs.profile import (STAGE_KEYS, STATEMENT_KEYS, ProfileRing,
+                                 build_profile)
 from ydb_tpu.obs.tracing import Tracer
 
 
@@ -34,36 +35,44 @@ def cluster():
     return c
 
 
-def lineitem_cluster(sf=0.002):
-    """A Cluster holding TPC-H lineitem (several portions per shard)."""
+def tpch_cluster(sf, tables=("lineitem",)):
+    """A Cluster holding the named TPC-H tables (several portions per
+    shard each), and the generated data."""
     from ydb_tpu.scheme.model import type_to_str
     from ydb_tpu.workload import tpch
 
     data = tpch.TpchData(sf=sf, seed=7)
     c = Cluster()
     s = c.session()
-    cols = ", ".join(
-        f"{f.name} {type_to_str(f.type)}"
-        for f in tpch.LINEITEM_SCHEMA.fields)
-    s.execute(f"CREATE TABLE lineitem ({cols}, "
-              "PRIMARY KEY (l_orderkey)) WITH (shards = 1)")
-    li = data.tables["lineitem"]
-    t = c.tables["lineitem"]
-    n = len(li["l_orderkey"])
-    step = max(1, n // 3)
-    for off in range(0, n, step):  # 3 commits -> 3 portions
-        arrays = {}
-        for f in tpch.LINEITEM_SCHEMA.fields:
-            v = li[f.name][off:off + step]
-            if f.type.is_string:
-                arrays[f.name] = [
-                    bytes(x) for x in data.dicts[f.name].decode(
-                        np.asarray(v, dtype=np.int32))]
-            else:
-                arrays[f.name] = v
-        t.insert(arrays)
+    for name in tables:
+        schema = getattr(tpch, name.upper() + "_SCHEMA")
+        cols = ", ".join(f"{f.name} {type_to_str(f.type)}"
+                         for f in schema.fields)
+        s.execute(f"CREATE TABLE {name} ({cols}, "
+                  f"PRIMARY KEY ({schema.fields[0].name})) "
+                  "WITH (shards = 1)")
+        rows = data.tables[name]
+        n = len(rows[schema.fields[0].name])
+        step = max(1, n // 3)
+        for off in range(0, n, step):  # 3 commits -> 3 portions
+            arrays = {}
+            for f in schema.fields:
+                v = rows[f.name][off:off + step]
+                if f.type.is_string:
+                    arrays[f.name] = [
+                        bytes(x) for x in data.dicts[f.name].decode(
+                            np.asarray(v, dtype=np.int32))]
+                else:
+                    arrays[f.name] = v
+            c.tables[name].insert(arrays)
     c._invalidate_plans()
-    return c, li
+    return c, data
+
+
+def lineitem_cluster(sf=0.002):
+    """A Cluster holding TPC-H lineitem (several portions per shard)."""
+    c, data = tpch_cluster(sf)
+    return c, data.tables["lineitem"]
 
 
 # ---------- span-threaded execution ----------
@@ -118,7 +127,9 @@ def test_span_tree_shape_multi_stage_dq(cluster):
     task_compute = sum(sp["attrs"]["compute_seconds"] for sp in tasks)
     assert task_compute > 0
     assert p.stages["compute"] == pytest.approx(task_compute, abs=1e-6)
-    assert p.device_seconds == p.stages["compute"]
+    # the tasks' dispatches and their waits on the device are spans of
+    # the statement's thread, folded into its own stages
+    assert p.stages["dispatch"] > 0 and p.stages["device_wait"] > 0
 
 
 def test_trace_id_propagates_to_conveyor_producer(cluster):
@@ -166,11 +177,11 @@ def test_scan_stage_seconds_and_pruning_attrs(cluster):
     assert p.pruning["portions_total"] > 0
     assert p.pruning["portions_skipped"] > 0   # zone maps pruned
     assert p.pruning["chunks_read"] > 0
-    assert set(p.stages) == {"read", "merge", "stage", "compute"}
+    assert set(p.stages) == set(STAGE_KEYS) | set(STATEMENT_KEYS)
     assert p.stages["read"] > 0
     assert p.stages["compute"] > 0
-    assert p.device_seconds == p.stages["compute"]
-    assert p.host_seconds >= p.stages["read"]
+    assert p.stages["dispatch"] > 0
+    assert p.stages["device_wait"] > 0
 
 
 # ---------- EXPLAIN ANALYZE ----------
@@ -456,8 +467,250 @@ def test_build_profile_aggregates_scan_spans():
     assert p.pruning == {"portions_total": 6, "portions_skipped": 1,
                          "chunks_read": 4, "chunks_skipped": 2,
                          "resident_portions": 0, "resident_rows": 0}
-    assert p.device_seconds == pytest.approx(0.3)
+    # spans with no dispatch or wait beneath them leave the statement's
+    # own stages at zero and everything unattributed
+    assert p.stages["device_wait"] == p.stages["dispatch"] == 0.0
+    assert p.stages["unattributed"] == pytest.approx(2.0)
     tree = p.span_tree()
     assert tree[0]["name"] == "query"
     assert {c["name"] for c in tree[0]["children"]} == \
         {"scan", "shard.scan"}
+
+
+# ---------- one span tree over the whole statement ----------
+
+@pytest.fixture(scope="module")
+def tpch():
+    return tpch_cluster(0.01, ("lineitem", "orders", "customer"))[0]
+
+
+@pytest.fixture
+def walk(monkeypatch):
+    """At a test's size the fused executor would answer Q1 and Q6; the
+    chip's cells answer them by the streaming walk."""
+    from ydb_tpu.ssa import plan_fuse
+
+    monkeypatch.setattr(plan_fuse, "FUSE_FORCE", False)
+
+
+def statement_spans(cluster, profile):
+    """The finished Span objects of one profiled statement."""
+    return cluster.tracer.spans_for(profile.trace_id)
+
+
+@pytest.mark.parametrize("qid,executor", [("q1", "scan"), ("q6", "scan"),
+                                          ("q3", "dq")])
+def test_both_executors_charge_every_layer(tpch, walk, qid, executor):
+    from ydb_tpu.obs.profile import SPAN_STAGE, self_seconds
+    from ydb_tpu.workload.queries import TPCH
+
+    s = tpch.session()
+    s.execute(TPCH[qid])           # compiles
+    s.execute(TPCH[qid])
+    p = s.last_profile
+    spans = statement_spans(tpch, p)
+    names = {sp.name for sp in spans}
+    assert executor in names and "plan.fuse" not in names
+    assert {"scan.pull", "dispatch", "device.wait", "fetch",
+            "snapshot"} <= names
+    assert set(STATEMENT_KEYS) <= set(p.stages)
+    for k in ("plan", "pull", "dispatch", "device_wait", "fetch"):
+        assert p.stages[k] > 0, k
+    # self times on the statement's thread sum to its seconds: nothing
+    # is counted twice and no interval is lost between parent and child
+    root = next(sp for sp in spans if sp.parent_id is None)
+    selfs = self_seconds(spans)
+    on_thread = [sp for sp in spans
+                 if sp.thread == root.thread and sp.annotated]
+    total = sum(selfs[sp.span_id] for sp in on_thread)
+    slack = max(0.01 * p.seconds, 2e-4)   # a 2 ms statement: 0.2 ms
+    assert total == pytest.approx(p.seconds, abs=slack)
+    assert all(selfs[sp.span_id] >= -1e-6 for sp in on_thread)
+    assert sum(p.stages[k] for k in STATEMENT_KEYS) == pytest.approx(
+        p.seconds, abs=slack)
+    named = sum(selfs[sp.span_id] for sp in on_thread
+                if sp.name in SPAN_STAGE)
+    assert p.stages["unattributed"] == pytest.approx(
+        p.seconds - named, abs=1e-5)
+    # a statement of 2 ms (Q6 here) keeps 0.3-0.7 ms of bookkeeping
+    # outside any named leaf; at the chip's sizes that is nothing
+    assert p.stages["unattributed"] < max(0.10 * p.seconds, 1.5e-3)
+    # spans are per block, dispatch and message batch: never per row
+    assert len(spans) <= 500
+    if executor == "dq":
+        # the DQ source scans charge the walk's stages and counters
+        assert sum(p.stages[k] for k in ("read", "merge", "stage")) > 0
+        assert p.pruning["portions_total"] > 0
+        dispatched = {sp.attrs.get("program") for sp in spans
+                      if sp.name == "dispatch"}
+        assert {"dq_stage", "join_lookup", "join_expand"} <= dispatched
+        # a task opens in one message and finishes in another: it is
+        # in the profile, not in the self-time arithmetic
+        assert not any(sp.annotated for sp in spans
+                       if sp.name == "dq.task")
+    else:
+        assert {sp.attrs.get("program") for sp in spans
+                if sp.name == "dispatch"} >= {"scan_partial"}
+
+
+def test_explain_analyze_prints_the_statement_line(tpch, walk):
+    from ydb_tpu.workload.queries import TPCH
+
+    txt = tpch.session().execute("EXPLAIN ANALYZE " + TPCH["q6"])
+    lines = txt.splitlines()
+    at = next(i for i, ln in enumerate(lines)
+              if ln.startswith("stages: "))
+    assert lines[at].split()[1:] == [
+        ln for ln in lines[at].split()[1:]
+        if ln.split("=")[0] in STAGE_KEYS]
+    assert lines[at + 1].startswith("statement: ")
+    got = dict(kv.split("=") for kv in lines[at + 1].split()[1:])
+    assert list(got) == list(STATEMENT_KEYS)
+    assert float(got["device_wait"]) > 0 and float(got["pull"]) > 0
+
+
+def test_profile_off_opens_no_child_span_and_no_annotation(monkeypatch):
+    opened = []
+
+    class Counting(tracing.TraceAnnotation):
+        def __init__(self, name, **kw):
+            opened.append(name)
+            super().__init__(name, **kw)
+
+    from ydb_tpu.obs import probes
+
+    monkeypatch.setattr(tracing, "TraceAnnotation", Counting)
+    monkeypatch.setattr(probes, "TraceAnnotation", Counting)
+    monkeypatch.setattr(tracing, "PROFILE_FORCE", False)
+    c = Cluster()
+    s = c.session()
+    s.execute("CREATE TABLE ev (id int64, v int64, PRIMARY KEY (id))")
+    s.execute("INSERT INTO ev VALUES (1, 2), (2, 4)")
+    opened.clear()
+    s.execute("SELECT sum(v) AS sv FROM ev")
+    q = [sp for sp in c.tracer.finished if sp.name == "query"][-1]
+    spans = c.tracer.spans_for(q.trace_id)
+    assert {sp.name for sp in spans} == {"query", "plan", "execute"}
+    assert not any(sp.annotated for sp in spans)
+    assert opened == []
+    # the same statement with profiling on annotates every span
+    monkeypatch.setattr(tracing, "PROFILE_FORCE", True)
+    s.execute("SELECT sum(v) AS sv FROM ev")
+    spans = statement_spans(c, s.last_profile)
+    assert sorted(n for n in opened if ".stage." not in n) == sorted(
+        "ydb." + sp.name for sp in spans)
+    assert not any(n.startswith("bench.") for n in opened)
+
+
+def test_the_span_that_dispatched_carries_the_compile(cluster):
+    sql = "SELECT ts, max(v) AS mv FROM ev WHERE v > 7 GROUP BY ts"
+    before = tracing.compile_counts()
+    s = cluster.session()
+    s.execute(sql)
+    spans = statement_spans(cluster, s.last_profile)
+    built = {sp.name: sp.attrs["compile_built"] for sp in spans
+             if sp.attrs.get("compile_built")}
+    assert built.get("dispatch", 0) >= 1, built
+    first = next(sp for sp in spans if sp.name == "dispatch"
+                 and sp.attrs.get("compile_built"))
+    assert first.attrs["compile_seconds"] > 0
+    assert "program" in first.attrs
+    after = tracing.compile_counts()
+    assert after["built"] - before["built"] == sum(built.values())
+    assert after["fetched"] == before["fetched"]   # no persistent cache
+    s.execute(sql)
+    again = statement_spans(cluster, s.last_profile)
+    assert not any(sp.attrs.get("compile_built")
+                   or sp.attrs.get("compile_fetched") for sp in again)
+    assert tracing.compile_counts()["built"] == after["built"]
+
+
+def test_spans_land_on_the_profiler_trace(tpch, walk, tmp_path):
+    """Every finished span of a statement run under the JAX profiler is
+    a ``ydb.<name>`` host event of the same thread's line, as long as
+    the span within 1 ms and nested as the span tree is."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from ydb_tpu.workload.queries import TPCH
+
+    s = tpch.session()
+    s.execute(TPCH["q3"])
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        s.execute(TPCH["q3"])
+        p = s.last_profile
+    finally:
+        jax.profiler.stop_trace()
+    spans = [sp for sp in statement_spans(tpch, p) if sp.annotated]
+    assert len(spans) > 20
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            evs = sorted((e.start_ns, e.duration_ns, e.name)
+                         for e in ln.events
+                         if e.name.startswith("ydb.")
+                         and not e.name.startswith("ydb.stage."))
+            if evs:
+                lines.append(evs)
+    assert not any(e.name.startswith("bench.")
+                   for plane in ProfileData.from_file(path).planes
+                   for ln in plane.lines for e in ln.events)
+    # a thread's spans in start order are one line's events in order
+    event_of = {}
+    for thread in {sp.thread for sp in spans}:
+        mine = sorted((sp for sp in spans if sp.thread == thread),
+                      key=lambda sp: sp.start)
+        want = ["ydb." + sp.name for sp in mine]
+        line = next((evs for evs in lines
+                     if [n for _, _, n in evs] == want), None)
+        assert line is not None, (want, [len(evs) for evs in lines])
+        for sp, ev in zip(mine, line):
+            assert abs(ev[1] / 1e9 - sp.seconds) < 1e-3, sp.name
+            event_of[sp.span_id] = ev
+    by_id = {sp.span_id: sp for sp in spans}
+    nested = 0
+    for sp in spans:
+        parent = by_id.get(sp.parent_id)
+        if parent is None or parent.thread != sp.thread:
+            continue
+        (c0, cd, _), (p0, pd, _) = event_of[sp.span_id], \
+            event_of[parent.span_id]
+        assert p0 <= c0 and c0 + cd <= p0 + pd, (sp.name, parent.name)
+        nested += 1
+    assert nested > 20
+
+
+def test_a_leaf_span_opens_no_span_beneath_it():
+    from ydb_tpu.blocks.block import TableBlock, concat_blocks
+    from ydb_tpu import dtypes
+
+    sch = dtypes.schema(("a", dtypes.INT64), ("b", dtypes.INT64))
+    blocks = [TableBlock.from_numpy(
+        {"a": np.arange(4) + i, "b": np.arange(4) * i}, sch)
+        for i in range(3)]
+    tr = Tracer()
+    with tr.trace("query") as root, tracing.activate(root):
+        out = concat_blocks(blocks)
+        with tracing.span("after") as sp:
+            assert tracing.current_span() is sp
+    assert list(out.to_numpy()["a"]) == [0, 1, 2, 3, 1, 2, 3, 4,
+                                         2, 3, 4, 5]
+    spans = tr.spans_for(root.trace_id)
+    concat = next(s for s in spans if s.name == "host.concat")
+    gets = [s for s in spans if s.name == "device.get"]
+    # two per column (data, validity), whatever the number of blocks,
+    # and none beneath
+    assert len(gets) == 4
+    assert all(s.parent_id == concat.span_id for s in gets)
+    assert not any(s.parent_id in {g.span_id for g in gets}
+                   for s in spans)
+    assert next(s for s in spans if s.name == "after").parent_id == \
+        root.span_id

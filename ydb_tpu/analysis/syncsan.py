@@ -17,10 +17,12 @@ Seams patched while armed (restored on disarm):
   ``jnp.asarray``             H2D transfer when staging host data
   ``np.asarray``              D2H sync when materializing a jax.Array
 
-Compilations are counted through ``jax.monitoring``: the
+Compilations come from the process's one ``jax.monitoring`` listener
+(``obs.tracing``), which this module subscribes to: the
 ``/jax/core/compile/backend_compile_duration`` event fires exactly
-once per XLA backend compile (never on a warm cache hit), so the
-listener is the ground truth the compile caches are judged against.
+once per XLA backend compile, built or fetched from the persistent
+cache (never on a hit of the in-process cache), so it is the ground
+truth the compile caches are judged against.
 ``.item()`` lives on the C++ ArrayImpl and cannot be patched — the
 static analyzer (H001) owns that seam.
 
@@ -43,8 +45,6 @@ import os
 import threading
 
 from ydb_tpu.obs import tracing
-
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 #: tri-state pin: None -> follow the env var; True/False -> forced
 _FORCE: "bool | None" = None
@@ -134,11 +134,15 @@ def _note(**counts) -> None:
         st.note(**counts)
 
 
+# every backend compile of the process, on the compiling thread (the
+# body gates on _ON: subscribed once, for good)
+tracing.on_compile(lambda _fetched, _seconds: _note(compiles=1))
+
+
 # ---------------- seam patches ----------------
 
 _patched = False
 _orig: dict = {}
-_listener_registered = False
 
 
 def _is_device_value(x) -> bool:
@@ -151,7 +155,7 @@ def _is_device_value(x) -> bool:
 
 
 def _install() -> None:
-    global _patched, _listener_registered
+    global _patched
     try:
         import jax
         import jax.numpy as jnp
@@ -177,12 +181,6 @@ def _install() -> None:
             _note(d2h=1, syncs=1)
         return _orig["np_asarray"](a, *args, **kwargs)
 
-    # jax.monitoring offers no per-listener removal, so register once
-    # for the process and gate the body on _ON instead.
-    def _on_event(event, duration, **kw):
-        if _ON and event == _COMPILE_EVENT:
-            _note(compiles=1)
-
     with _meta_lock:
         if _patched:
             return
@@ -195,10 +193,6 @@ def _install() -> None:
         jnp.asarray = jnp_asarray
         np.asarray = np_asarray
         _patched = True
-        if not _listener_registered:
-            jax.monitoring.register_event_duration_secs_listener(
-                _on_event)
-            _listener_registered = True
 
 
 def _uninstall() -> None:

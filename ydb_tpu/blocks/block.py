@@ -232,17 +232,29 @@ class TableBlock:
         high-latency link that — not bandwidth — dominates small
         results, so every column (and its validity) rides one
         ``jax.device_get``."""
-        n = int(self.length)
+        from ydb_tpu.obs import tracing  # deferred: import graph
+
+        n = self.live_rows()
         pack = {
             k: ((self._clip(c.data, n), self._clip(c.validity, n))
                 if validity else (self._clip(c.data, n),))
             for k, c in self.columns.items()
         }
-        got = jax.device_get(pack)
+        with tracing.span("device.get"):
+            got = jax.device_get(pack)
         data = {k: v[0][:n] for k, v in got.items()}
         valid = ({k: v[1][:n] for k, v in got.items()} if validity
                  else {})
         return data, valid
+
+    @host_ok("deliberate result fetch: the sync of host_columns")
+    def live_rows(self) -> int:
+        """``length`` on the host: the sync that waits for whatever
+        program computes this block (a ``device.wait`` span)."""
+        from ydb_tpu.obs import tracing  # deferred: import graph
+
+        with tracing.span("device.wait"):
+            return int(self.length)
 
     @host_ok("deliberate result fetch (delegates to host_columns)")
     def to_numpy(self) -> dict[str, np.ndarray]:
@@ -251,10 +263,13 @@ class TableBlock:
 
     @host_ok("deliberate result fetch: one batched validity device_get")
     def validity_numpy(self) -> dict[str, np.ndarray]:
-        n = int(self.length)
-        got = jax.device_get(
-            {k: self._clip(c.validity, n)
-             for k, c in self.columns.items()})
+        from ydb_tpu.obs import tracing  # deferred: import graph
+
+        n = self.live_rows()
+        with tracing.span("device.get"):
+            got = jax.device_get(
+                {k: self._clip(c.validity, n)
+                 for k, c in self.columns.items()})
         return {k: v[:n] for k, v in got.items()}
 
 
@@ -295,13 +310,24 @@ def concat_blocks(blocks: list[TableBlock], capacity: int | None = None) -> Tabl
                 f.name, f.type,
                 any(b.schema.field(f.name).nullable for b in blocks))
             for f in schema.fields))
+    from ydb_tpu.obs import tracing  # deferred: import graph
+
+    # the walk ends a scan with no final program here, and the host
+    # does the work: every block out of the device once per column,
+    # numpy's concatenate, and the result staged back
+    def fetched(get, name):
+        # one column of every block out of the device: one leaf span,
+        # not one per block
+        with tracing.leaf("device.get"):
+            return [get(b)[name] for b in blocks]
+
     arrays: dict[str, np.ndarray] = {}
     validity: dict[str, np.ndarray] = {}
-    for name in schema.names:
-        arrays[name] = np.concatenate(
-            [b.to_numpy()[name] for b in blocks]
-        )
-        validity[name] = np.concatenate(
-            [b.validity_numpy()[name] for b in blocks]
-        )
-    return TableBlock.from_numpy(arrays, schema, validity, capacity=capacity)
+    with tracing.span("host.concat", blocks=len(blocks)):
+        for name in schema.names:
+            arrays[name] = np.concatenate(
+                fetched(TableBlock.to_numpy, name))
+            validity[name] = np.concatenate(
+                fetched(TableBlock.validity_numpy, name))
+        return TableBlock.from_numpy(arrays, schema, validity,
+                                     capacity=capacity)

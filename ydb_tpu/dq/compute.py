@@ -39,6 +39,7 @@ from ydb_tpu.dq.graph import (
 from ydb_tpu.dq.spilling import Spiller
 from ydb_tpu.engine.oracle import OracleTable
 from ydb_tpu.engine.scan import ColumnSource, merge_blocks_device
+from ydb_tpu.obs import tracing
 from ydb_tpu.runtime.actors import Actor, ActorId
 from ydb_tpu.ssa.compiler import compile_program
 
@@ -106,6 +107,14 @@ class ResultData:
 # ---- payload <-> block ----
 
 
+# The channels carry host payloads: a stage's output leaves the device
+# (block_to_payload), is hashed, split and concatenated on the host, and
+# is staged back for the next stage (payload_to_block, _assemble). Each
+# of those steps runs under a ``dq.exchange`` span, with the waits on
+# the chip as ``device.wait`` / ``device.get`` children.
+
+
+@tracing.span("dq.exchange")
 def block_to_payload(block: TableBlock) -> dict:
     data = block.to_numpy()
     valid = block.validity_numpy()
@@ -116,6 +125,7 @@ def block_to_payload(block: TableBlock) -> dict:
     return out
 
 
+@tracing.span("dq.exchange")
 def payload_to_block(payload: dict, schema: dtypes.Schema) -> TableBlock:
     cols = {f.name: payload[f.name] for f in schema.fields}
     validity = {f.name: payload[f"__v_{f.name}"] for f in schema.fields}
@@ -325,11 +335,13 @@ class ComputeActor(Actor):
         from ydb_tpu.runtime.interconnect import Undelivered
 
         if isinstance(message, StartTask):
-            from ydb_tpu.obs import tracing
-
             parent = tracing.current_span()
             if parent is not None and self._span is None:
-                self._span = parent.child("dq.task").set(
+                # opened here, finished in _finish_output, other
+                # tasks' messages in between: not lexical, so not
+                # annotated (no profiler event, no part in self time)
+                self._span = parent.child(
+                    "dq.task", annotated=False).set(
                     stage=self.task.stage, task=self.task.task_id,
                     thread=threading.get_ident())
             self._start_source()
@@ -504,7 +516,8 @@ class ComputeActor(Actor):
                     f"task {self.task.task_id}: statement deadline "
                     "exceeded"))
             return
-        blk = next(self._source_iter, None)
+        with tracing.span("scan.pull"):
+            blk = next(self._source_iter, None)
         if blk is None:
             if not self.task.input_channels:
                 self._finish_input()
@@ -519,7 +532,8 @@ class ComputeActor(Actor):
         if self._span is None:
             return fn(*args)
         t0 = time.perf_counter()
-        out = fn(*args)
+        with tracing.span("dispatch", program="dq_stage"):
+            out = fn(*args)
         self._compute_s += time.perf_counter() - t0
         return out
 
@@ -571,6 +585,10 @@ class ComputeActor(Actor):
         if isinstance(out, ResultOutput):
             self.send(self.result_target, ResultData(payload, False))
             return
+        self._route(payload, out)
+
+    @tracing.span("dq.exchange")
+    def _route(self, payload: dict, out):
         # each consumer edge gets the full routed stream independently;
         # the row hash is only needed when some edge actually fans out
         h = None
@@ -632,6 +650,7 @@ class ComputeActor(Actor):
             self._dispatch(ch, None, finished=True)
 
 
+@tracing.span("dq.exchange")
 def _assemble(payloads: list[dict], schema: dtypes.Schema) -> TableBlock:
     """Concat channel payloads into one block (capacity >= 1 so the join
     kernels' searchsorted shapes stay valid on empty sides)."""
@@ -742,7 +761,6 @@ def compile_stages(
     whole compiled chain from the shipped stage specs (the task-start
     path, kqp_node_service.cpp:121)."""
     from ydb_tpu.engine.scan import required_columns
-    from ydb_tpu.obs import tracing
 
     compiled: list[_CompiledStage] = []
     cache_hits = cache_misses = 0

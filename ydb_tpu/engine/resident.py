@@ -602,6 +602,7 @@ def _device_blocks(run, names, sch, cap, timer):
     hand the executor mostly-padding blocks and multiply compute by
     the portion count. The aligned case (one portion exactly filling a
     block) reuses the resident arrays as-is — zero device work."""
+    import jax
     import jax.numpy as jnp
 
     stage = (timer.stage if timer is not None else None)
@@ -621,7 +622,7 @@ def _device_blocks(run, names, sch, cap, timer):
                 parts.append((entries, lo, hi, rows))
         ctx = stage("stage") if stage is not None \
             else contextlib.nullcontext()
-        with ctx:
+        with ctx, jax.named_scope("ydb.device_blocks"):
             whole = (len(parts) == 1 and parts[0][1] == 0
                      and parts[0][2] == parts[0][3] == cap)
             cols = {}

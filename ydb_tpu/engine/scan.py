@@ -34,6 +34,7 @@ from ydb_tpu.blocks.block import (
 )
 from ydb_tpu.blocks.dictionary import DictionarySet
 from ydb_tpu.engine.oracle import OracleTable
+from ydb_tpu.obs import tracing
 from ydb_tpu.ssa import kernels, twophase
 from ydb_tpu.ssa.compiler import compile_program
 from ydb_tpu.ssa.program import Program
@@ -83,6 +84,7 @@ class ColumnSource:
             yield TableBlock.from_numpy(arrays, sch, validity, capacity=cap)
 
 
+@jax.named_scope("ydb.merge_blocks_device")
 def merge_blocks_device(blocks: list[TableBlock]) -> TableBlock:
     """Trace-time concat of blocks (live rows compacted to the front).
 
@@ -156,6 +158,22 @@ def required_columns(program: Program, schema: dtypes.Schema) -> tuple[str, ...]
         )
         return (cheapest.name,)
     return tuple(n for n in schema.names if n in used)
+
+
+_END = object()
+
+
+def _pulled(blocks):
+    """``blocks``, each ``next`` under a ``scan.pull`` span: the time
+    the dispatching thread waits for the staging pipeline's next block
+    (with no free conveyor worker the staging itself runs here)."""
+    it = iter(blocks)
+    while True:
+        with tracing.span("scan.pull"):
+            b = next(it, _END)
+        if b is _END:
+            return
+        yield b
 
 
 class ScanExecutor:
@@ -272,18 +290,21 @@ class ScanExecutor:
         self.source = None
         return self
 
-    def _timed_first(self, flag: str, fn, *args):
-        """A program's first dispatch runs jit trace + XLA compile:
-        time it synchronously, once (one-off sync; warm stays async),
-        accumulating into ``first_trace_seconds``."""
-        if getattr(self, flag):
-            return fn(*args)
+    def _timed_first(self, flag: str, program: str, fn, *args):
+        """Enqueue one device program under a ``dispatch`` span named
+        by its role. A program's first dispatch runs jit trace + XLA
+        compile: time it synchronously, once (one-off sync; warm stays
+        async), accumulating into ``first_trace_seconds``."""
         t0 = time.perf_counter()
-        out = fn(*args)
+        with tracing.span("dispatch", program=program):
+            out = fn(*args)
+        if getattr(self, flag):
+            return out
         # one-off sync: times the first dispatch's trace+compile (the
-        # warm arm above stays async)
-        # ydb-lint: disable=H001
-        jax.block_until_ready(out)
+        # warm return above stays async)
+        with tracing.span("device.wait"):
+            # ydb-lint: disable=H001
+            jax.block_until_ready(out)
         setattr(self, flag, True)
         self.first_trace_seconds = (
             (self.first_trace_seconds or 0.0)
@@ -291,16 +312,18 @@ class ScanExecutor:
         return out
 
     def run_block(self, block: TableBlock) -> TableBlock:
-        return self._timed_first("_partial_traced", self._partial_jit,
-                                 block, self._partial_aux)
+        return self._timed_first("_partial_traced", "scan_partial",
+                                 self._partial_jit, block,
+                                 self._partial_aux)
 
     def finalize(self, partials: list[TableBlock]) -> TableBlock:
         """Merge per-block partial results and run the final program —
         one jitted device computation end to end."""
         if self.final is None and len(partials) == 1:
             return partials[0]
-        return self._timed_first("_finalize_traced", self._finalize_jit,
-                                 tuple(partials), self._final_aux)
+        return self._timed_first("_finalize_traced", "scan_finalize",
+                                 self._finalize_jit, tuple(partials),
+                                 self._final_aux)
 
     def run_stream(self, blocks, timer=None,
                    consumed_cb=None) -> TableBlock:
@@ -336,14 +359,15 @@ class ScanExecutor:
                 # deliberate backpressure: sync ONLY the oldest
                 # in-flight block once the window fills — bounded by
                 # inflight_blocks, not rows
-                # ydb-lint: disable=H001
-                jax.block_until_ready(window.popleft())
+                with tracing.span("device.wait"):
+                    # ydb-lint: disable=H001
+                    jax.block_until_ready(window.popleft())
 
         # the morsel driver loop: iterations are bounded by block
         # count (capacity-quantized morsels), never by rows; each
         # iteration is one async device dispatch
         # ydb-lint: disable=H006
-        for b in blocks:
+        for b in _pulled(blocks):
             # block-boundary cancellation point (no-op when the
             # statement carries no deadline)
             statement_deadline.check_current("scan")
@@ -354,8 +378,9 @@ class ScanExecutor:
                     and len(partials) >= self.combine_every
                 ):
                     merged = self._timed_first(
-                        "_combine_traced", self._combine_jit,
-                        tuple(partials), self._combine_aux)
+                        "_combine_traced", "scan_combine",
+                        self._combine_jit, tuple(partials),
+                        self._combine_aux)
                     partials = []
                     admit(merged)
             if consumed_cb is not None:
@@ -374,8 +399,9 @@ class ScanExecutor:
                 # on whichever caller first touches the arrays —
                 # occupancy attribution stays exact. Default path
                 # stays lazy (cross-query dispatch pipelining).
-                # ydb-lint: disable=H001
-                jax.block_until_ready(out.columns)
+                with tracing.span("device.wait"):
+                    # ydb-lint: disable=H001
+                    jax.block_until_ready(out.columns)
             return self._retype(out)
 
     def _stamp_nullability(self, sch: dtypes.Schema) -> dtypes.Schema:

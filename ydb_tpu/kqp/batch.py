@@ -323,7 +323,7 @@ class BatchDispatcher:
                                 site, mdb, None, donate=False)
                             if sp.recording:
                                 sp.set(table=site.table,
-                                       rows=int(blk.length))
+                                       rows=blk.live_rows())
                         return blk
 
                     staged[ident] = self.share.get_or_stage(share_key,

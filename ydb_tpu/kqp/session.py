@@ -1692,8 +1692,9 @@ class Session:
         # layer below — planner, executor, scans, DQ tasks, conveyor
         # prefetch producers — threads children under this trace id.
         # YDB_TPU_PROFILE=0 keeps the root/plan/execute spans (the
-        # pre-profile surface) but skips activation: no child spans, no
-        # attribute computation anywhere below, no profile assembly.
+        # pre-profile surface) but skips activation and annotation: no
+        # child spans, no attribute computation anywhere below, no
+        # event in a profiler trace, no profile assembly.
         prof = tracing.profiling_enabled()
 
         def act(sp):
@@ -1709,7 +1710,8 @@ class Session:
         # statement's registry row; sessions run one statement at a time
         self._active_tok = active_tok
         try:
-            with c.tracer.trace("query", trace_id) as span:
+            with c.tracer.trace("query", trace_id,
+                                annotated=prof) as span:
                 # syncsan window covers plan+execute+fetch: transfers,
                 # blocking syncs and XLA compiles attribute to THIS
                 # statement (conveyor workers resolve via the trace id)
@@ -1983,9 +1985,10 @@ class Session:
         p, alias_map, plan_db = planned
         self._check_access(
             "read", *("/" + t for t in self._plan_tables(p)))
-        db = self._statement_db(plan_db)
         from ydb_tpu.obs import tracing
 
+        with tracing.span("snapshot"):
+            db = self._statement_db(plan_db)
         blk = self._execute_select(p, db)
         with tracing.span("fetch"):
             # device -> host result transfer is its own phase: the one
@@ -2055,7 +2058,9 @@ class Session:
                 # budget, the outer statement window does
                 _ss = _syncsan.begin_statement("<analyze>")
                 _ms = _memsan.begin_statement("<analyze>")
-                out = to_host(self._execute_select(p, db))
+                blk = self._execute_select(p, db)
+                with tracing.span("fetch"):
+                    out = to_host(blk)
                 snap = _syncsan.end_statement(_ss, enforce=False)
                 _ss = None
                 msnap = _memsan.end_statement(_ms, enforce=False)
@@ -2068,7 +2073,7 @@ class Session:
         seconds = _time.monotonic() - t0
         spans = []
         if asp.recording:
-            spans = subtree(
+            spans = [asp] + subtree(
                 self.cluster.tracer.spans_for(asp.trace_id),
                 asp.span_id)
         profile = build_profile(

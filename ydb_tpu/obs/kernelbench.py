@@ -456,9 +456,9 @@ def bench_profile_overhead(sf: float, iters: int, block_rows: int,
     path), plus a third side with the data-movement timeline ring
     enabled (YDB_TPU_TIMELINE=1 state). ``assert_within`` fails the
     bench when the ON side exceeds OFF by more than that fraction (the
-    default-on budget); it also asserts the timeline's contract: ZERO
-    ring events on the disabled path, and the enabled ring within 3%
-    of the profiled run."""
+    default-on budget) or the enabled ring exceeds the profiled run by
+    3%. The timeline's count contract, ZERO ring events on the disabled
+    path, is asserted always."""
     from ydb_tpu.engine.blobs import MemBlobStore
     from ydb_tpu.engine.shard import ColumnShard, ShardConfig
     from ydb_tpu.obs import profile as profile_mod
@@ -542,6 +542,11 @@ def bench_profile_overhead(sf: float, iters: int, block_rows: int,
             100 * (best["tl"] / best["on"] - 1), 2),
         "timeline_disabled_events": disabled_events,
     }
+    # the count contract holds at every size, whatever the clock says
+    if disabled_events:
+        raise AssertionError(
+            f"timeline ring recorded {disabled_events} events "
+            f"while disabled (gate leak)")
     if assert_within is not None:
         # only claim a budget verdict when one was actually checked
         if best["on"] > best["off"] * (1 + assert_within):
@@ -549,10 +554,6 @@ def bench_profile_overhead(sf: float, iters: int, block_rows: int,
                 f"profiling overhead {out['overhead_pct']}% exceeds "
                 f"the {assert_within * 100:g}% budget")
         out["within_budget"] = True
-        if disabled_events:
-            raise AssertionError(
-                f"timeline ring recorded {disabled_events} events "
-                f"while disabled (gate leak)")
         # 2ms absolute slack: at micro scale the 3% band is inside
         # timer jitter; at real scale the relative bound dominates.
         # The hard <3% acceptance bound is the DISABLED path, held by
@@ -1310,35 +1311,42 @@ def main(argv=None) -> int:
         report["streaming"] = bench_streaming(
             args.rows, args.chunk_rows, args.iters)
     if args.profile_overhead or args.smoke:
-        # smoke: tiny run, lax bound (machinery + no-catastrophe
-        # guard); real sizes measure the 2% default-on budget
+        # the 2% default-on budget is measured, at real sizes too;
+        # the zero-events-while-disabled contract is asserted always
         report["profile_overhead"] = bench_profile_overhead(
-            args.sf, max(3, args.iters), args.block_rows,
-            assert_within=(0.5 if args.smoke else None))
+            args.sf, max(3, args.iters), args.block_rows)
     if args.chaos_overhead or args.smoke:
-        # smoke: tiny run, lax bound (machinery + no-catastrophe
-        # guard); real sizes hold the 1% disabled-path budget
+        # smoke: a tiny run under the tier-1 workers, where a
+        # wall-clock ratio is a tripwire: the count contracts are
+        # asserted and the ratio reported; real sizes hold the
+        # 1% disabled-path budget
         report["chaos_overhead"] = bench_chaos_overhead(
             args.sf, max(3, args.iters), args.block_rows,
-            assert_within=(0.5 if args.smoke else 0.01))
+            assert_within=(None if args.smoke else 0.01))
     if args.leaksan_overhead or args.smoke:
-        # smoke: tiny run, lax bound (machinery + no-catastrophe
-        # guard); real sizes hold the 1% disabled-path budget
+        # smoke: a tiny run under the tier-1 workers, where a
+        # wall-clock ratio is a tripwire: the count contracts are
+        # asserted and the ratio reported; real sizes hold the
+        # 1% disabled-path budget
         report["leaksan_overhead"] = bench_leaksan_overhead(
             args.sf, max(3, args.iters), args.block_rows,
-            assert_within=(0.5 if args.smoke else 0.01))
+            assert_within=(None if args.smoke else 0.01))
     if args.admission_overhead or args.smoke:
-        # smoke: tiny run, lax bound (machinery + no-catastrophe
-        # guard); real sizes hold the 3% front-door budget
+        # smoke: a tiny run under the tier-1 workers, where a
+        # wall-clock ratio is a tripwire: the count contracts are
+        # asserted and the ratio reported; real sizes hold the
+        # 3% front-door budget
         report["admission_overhead"] = bench_admission_overhead(
             args.sf, max(3, args.iters),
-            assert_within=(0.5 if args.smoke else 0.03))
+            assert_within=(None if args.smoke else 0.03))
     if args.memsan_overhead or args.smoke:
-        # smoke: tiny run, lax bound (machinery + no-catastrophe
-        # guard); real sizes hold the 3% warm-Q1 tripwire
+        # smoke: a tiny run under the tier-1 workers, where a
+        # wall-clock ratio is a tripwire: the count contracts are
+        # asserted and the ratio reported; real sizes hold the
+        # 3% warm-Q1 tripwire
         report["memsan_overhead"] = bench_memsan_overhead(
             args.sf, max(3, args.iters), args.block_rows,
-            assert_within=(0.5 if args.smoke else 0.03))
+            assert_within=(None if args.smoke else 0.03))
     if args.fusion or args.smoke:
         report["fusion"] = bench_fusion(args.sf, max(3, args.iters))
     if args.batching or args.smoke:

@@ -18,8 +18,11 @@ import fnmatch
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ydb_tpu.analysis import sanitizer
 from ydb_tpu.obs import timeline
+from ydb_tpu.obs.tracing import ANNOTATION_PREFIX
 
 # module-level registry: built at import, before any test could set
 # YDB_TPU_TSAN — so the proxy/lock are always-on variants whose
@@ -142,11 +145,15 @@ class StageTimer:
 
     @contextlib.contextmanager
     def stage(self, name: str, **args):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0, **args)
+        # the charge is also a host event of the profiler trace
+        # (``ydb.stage.<name>``, inert outside a profiler session), so
+        # a device-idle gap can be put down to the stage that covered it
+        with TraceAnnotation(f"{ANNOTATION_PREFIX}stage.{name}"):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0, **args)
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -9,7 +9,8 @@ per-pool latency histograms on the counters page (SURVEY.md §2.14,
 every statement under a traced root span (obs.tracing), the executor /
 scan / DQ / conveyor layers attach children, and ``build_profile``
 folds the finished tree into one ``QueryProfile`` — per-stage seconds,
-device vs host time, rows, cache hits, compile-vs-execute split — that
+the statement thread's self time by layer, rows, cache hits,
+compile-vs-execute split — that
 feeds ``session.last_profile``, the ``sys_top_queries`` /
 ``sys_query_log`` views, the ``/viewer/json/query_profile`` endpoint
 and ``EXPLAIN ANALYZE`` rendering.
@@ -23,14 +24,33 @@ import threading
 import time
 
 
-#: span attrs summed into the per-query stage breakdown; "compute" is
-#: device time, the rest is host-side pipeline work
+#: span attrs summed into the per-query stage breakdown: what the
+#: ``StageTimer`` of each scan charged, over all the threads of the
+#: staging pipeline (so they may sum past the statement's seconds)
 STAGE_KEYS = ("read", "merge", "stage", "compute")
+#: span name -> statement stage: the self time of every span on the
+#: statement's own thread is summed under its stage, and what no named
+#: span covers is ``unattributed``. Together they sum to ``seconds``.
+SPAN_STAGE = {
+    "plan": "plan", "parse": "plan", "ssa.compile": "plan",
+    "plan.signature": "plan", "dq.lower": "plan", "dq.build": "plan",
+    "snapshot": "plan", "scan.prune": "plan",
+    "scan.pull": "pull",
+    "dispatch": "dispatch", "dq.pump": "dispatch",
+    "dq.exchange": "dispatch", "host.concat": "dispatch",
+    "device.wait": "device_wait", "device.get": "device_wait",
+    "fetch": "fetch",
+}
+STATEMENT_KEYS = ("plan", "pull", "dispatch", "device_wait", "fetch",
+                  "unattributed")
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
 #: span names that carry scan-level stage/pruning/compile attrs
 SCAN_SPANS = ("scan", "shard.scan")
+#: span names that carry stage/pruning attrs (the DQ executor charges
+#: all its source scans to its one span)
+STAGE_SPANS = SCAN_SPANS + ("dq",)
 
 
 @dataclasses.dataclass
@@ -71,8 +91,6 @@ class QueryProfile:
     #: count and unbudgeted allocations this statement made; {} when
     #: the sanitizer is off
     memsan: dict = dataclasses.field(default_factory=dict)
-    device_seconds: float = 0.0
-    host_seconds: float = 0.0
     #: per-stage busy fractions + overlap coefficients from the
     #: data-movement timeline (obs.timeline); {} when the ring is off
     stage_occupancy: dict = dataclasses.field(default_factory=dict)
@@ -118,9 +136,43 @@ class QueryProfile:
 def _span_dict(s) -> dict:
     return {
         "name": s.name, "span_id": s.span_id,
-        "parent_id": s.parent_id,
+        "parent_id": s.parent_id, "thread": s.thread,
         "seconds": round(s.seconds, 6), "attrs": dict(s.attrs),
     }
+
+
+def self_seconds(spans) -> dict:
+    """span id -> self time: a span's duration minus its children's on
+    its own thread. Spans that are not lexical (``annotated`` false:
+    ``dq.task``) overlap their siblings and count for nothing here."""
+    by_id = {s.span_id: s for s in spans}
+    out = {s.span_id: s.seconds for s in spans if s.annotated}
+    for s in spans:
+        parent = by_id.get(s.parent_id)
+        if (s.annotated and parent is not None and parent.annotated
+                and parent.thread == s.thread):
+            out[parent.span_id] -= s.seconds
+    return out
+
+
+def statement_stages(spans, seconds: float) -> dict:
+    """``SPAN_STAGE`` applied to the self times of the spans on the
+    statement's thread (that of the span whose parent is not among
+    them: the root), with what is left of ``seconds`` as
+    ``unattributed``."""
+    out = {k: 0.0 for k in STATEMENT_KEYS}
+    if not spans:
+        return out
+    ids = {s.span_id for s in spans}
+    thread = next((s for s in spans if s.parent_id not in ids),
+                  spans[0]).thread
+    selfs = self_seconds(spans)
+    for s in spans:
+        stage = SPAN_STAGE.get(s.name)
+        if stage is not None and s.thread == thread and s.annotated:
+            out[stage] += selfs[s.span_id]
+    out["unattributed"] = max(0.0, seconds - sum(out.values()))
+    return out
 
 
 def subtree(spans, root_span_id: int) -> list:
@@ -201,7 +253,7 @@ def build_profile(spans, sql: str = "", kind: str = "",
             # accumulated compute seconds ARE the device time
             p.stages["compute"] += float(a.get("compute_seconds", 0.0))
             continue
-        if s.name not in SCAN_SPANS and s.name != "transform":
+        if s.name not in STAGE_SPANS and s.name != "transform":
             continue
         if a.get("compile_cache") == "miss":
             p.compile_cache = "miss"
@@ -210,16 +262,15 @@ def build_profile(spans, sql: str = "", kind: str = "",
         p.compile_seconds += float(a.get("first_trace_seconds", 0.0))
         if s.name in SCAN_SPANS:
             rows_out += int(a.get("rows", 0))
+        if s.name in STAGE_SPANS:
             for k in STAGE_KEYS:
                 p.stages[k] += float(a.get(f"stage_{k}", 0.0))
             for k in PRUNING_KEYS:
                 p.pruning[k] += int(a.get(k, 0))
+    p.stages.update(statement_stages(spans, p.seconds))
     p.stages = {k: round(v, 6) for k, v in p.stages.items()}
     p.rows = rows if rows is not None else rows_out
     p.execute_seconds = max(0.0, p.seconds - p.compile_seconds)
-    p.device_seconds = p.stages.get("compute", 0.0)
-    p.host_seconds = round(sum(
-        v for k, v in p.stages.items() if k != "compute"), 6)
     p.spans = [_span_dict(s) for s in spans]
     from ydb_tpu.obs import timeline
 
@@ -336,6 +387,8 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
     st = profile.stages
     lines.append("stages: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in STAGE_KEYS))
+    lines.append("statement: " + " ".join(
+        f"{k}={st.get(k, 0.0):.6f}" for k in STATEMENT_KEYS))
     pr = profile.pruning
     lines.append("rows: " + " ".join(
         f"{k}={pr.get(k, 0)}" for k in PRUNING_KEYS))

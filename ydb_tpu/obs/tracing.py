@@ -18,21 +18,40 @@ work across threads; the Tracer is therefore thread-safe (spans
 finish from prefetch producers while the session thread records its
 own) with a per-trace-id index replacing the old linear scan.
 
+One clock, and the profiler's: spans run on ``time.perf_counter`` (the
+clock ``StageTimer``, the timeline ring and the benchmark use), and
+every annotated span also opens a ``jax.profiler.TraceAnnotation``
+named ``ydb.<span name>``. Outside a profiler session that is one inert
+TraceMe; inside one the span lands on its thread's host line of the same
+``.xplane.pb`` as the device's "XLA Ops", on that trace's clock. A span
+opened in one call and finished in another (``dq.task``) is not
+lexical: it is opened ``annotated=False``, stays out of the profiler
+trace and out of the self-time arithmetic of ``profile.build_profile``.
+
+Compiles: one process-wide ``jax.monitoring`` listener splits every
+``backend_compile_duration`` event into *built* and *fetched* (from the
+persistent cache), counts both for the process (``compile_counts``)
+and charges them to the thread's active span (``compile_built`` /
+``compile_fetched`` / ``compile_seconds`` attrs). ``on_compile``
+subscribes further readers (the sync sanitizer).
+
 Gating: profiling is ON by default; ``YDB_TPU_PROFILE=0`` keeps the
-per-query root span but skips activation, so no child spans (and none
-of their attribute computation) happen anywhere below the session.
-``PROFILE_FORCE`` is the in-process test override (same contract as
-stats.STATS_FORCE).
+per-query root span but skips activation and annotation, so no child
+spans (and none of their attribute computation) and no TraceAnnotation
+happen anywhere below the session. ``PROFILE_FORCE`` is the in-process
+test override (same contract as stats.STATS_FORCE).
 """
 
 from __future__ import annotations
 
 import contextlib
 import itertools
-import json
 import os
 import threading
 import time
+
+import jax
+from jax.profiler import TraceAnnotation
 
 from ydb_tpu.analysis import sanitizer
 from ydb_tpu.obs import timeline
@@ -53,25 +72,40 @@ def profiling_enabled() -> bool:
     return os.environ.get("YDB_TPU_PROFILE", "1") not in ("0", "", "off")
 
 
+#: prefix of every host event the program writes into a profiler trace
+#: (never ``bench.``: the benchmark's reduction takes those for its own)
+ANNOTATION_PREFIX = "ydb."
+
+
 class Span:
     def __init__(self, tracer: "Tracer", name: str, trace_id: int,
-                 parent_id: int | None = None, clock=time.monotonic):
+                 parent_id: int | None = None, annotated: bool = True):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = next(_ids)
         self.parent_id = parent_id
         self.attrs: dict = {}
-        self._clock = clock
-        self.start = clock()
+        #: the thread the span opened on: self time is reckoned among
+        #: the spans of one thread
+        self.thread = threading.get_ident()
+        self.annotated = annotated
+        self._annotation = None
+        if annotated:
+            self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
+            self._annotation.__enter__()
+        self.start = time.perf_counter()
         self.end: float | None = None
 
     #: real spans record; the shared null span (disabled path) does not
     recording = True
 
-    def child(self, name: str) -> "Span":
+    def child(self, name: str, annotated: bool | None = None) -> "Span":
+        """A child span; ``annotated`` defaults to the parent's, so a
+        root opened unannotated (profiling off) keeps its whole tree
+        out of the profiler trace."""
         return Span(self.tracer, name, self.trace_id, self.span_id,
-                    self._clock)
+                    self.annotated if annotated is None else annotated)
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -81,21 +115,18 @@ class Span:
     def seconds(self) -> float:
         """Wall duration (to now while unfinished)."""
         return (self.end if self.end is not None
-                else self._clock()) - self.start
+                else time.perf_counter()) - self.start
 
     def finish(self) -> None:
         if self.end is None:
-            self.end = self._clock()
+            self.end = time.perf_counter()
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+                self._annotation = None
             self.tracer._record(self)
             if timeline.timeline_enabled():
-                # anchor on the duration, not the span's own clock:
-                # spans run on ``clock`` (monotonic by default) while
-                # the timeline axis is perf_counter — re-basing the
-                # interval to end-now keeps one consistent axis
-                now = time.perf_counter()
-                timeline.RING.record(
-                    self.name, "span", now - (self.end - self.start),
-                    now, self.trace_id)
+                timeline.RING.record(self.name, "span", self.start,
+                                     self.end, self.trace_id)
 
     def __enter__(self):
         return self
@@ -117,7 +148,7 @@ class _NullSpan:
     attrs: dict = {}
     seconds = 0.0
 
-    def child(self, name: str) -> "_NullSpan":
+    def child(self, name: str, annotated=None) -> "_NullSpan":
         return self
 
     def set(self, **attrs) -> "_NullSpan":
@@ -179,6 +210,19 @@ def span(name: str, **attrs):
         s.finish()
 
 
+@contextlib.contextmanager
+def leaf(name: str, **attrs):
+    """``span`` whose block opens no span beneath it: for a loop that
+    would otherwise open one per block per column (the span budget is
+    per block, per dispatch, per message batch)."""
+    with span(name, **attrs) as s:
+        _tls.span = None
+        try:
+            yield s
+        finally:
+            _tls.span = s if s.recording else None
+
+
 def annotate(**attrs) -> None:
     """Attach attributes to the active span, if any."""
     sp = current_span()
@@ -209,15 +253,15 @@ class Tracer:
     lock, and the index makes per-query lookups O(spans in trace)
     instead of a scan over the whole ring."""
 
-    def __init__(self, max_spans: int = 10000, clock=time.monotonic):
+    def __init__(self, max_spans: int = 10000):
         self.max_spans = max_spans
         self.finished: list[Span] = []
         self._by_trace: dict[int, list[Span]] = {}
         self._lock = sanitizer.make_lock(f"tracer.{id(self):x}.lock")
-        self._clock = clock
         self._next_tid = 1
 
-    def trace(self, name: str, trace_id: int | None = None) -> Span:
+    def trace(self, name: str, trace_id: int | None = None,
+              annotated: bool = True) -> Span:
         """Open a root span (new trace id unless one is propagated).
         The local allocator always skips past propagated ids so two
         unrelated traces never share an id."""
@@ -228,7 +272,7 @@ class Tracer:
             else:
                 tid = self._next_tid
                 self._next_tid += 1
-        return Span(self, name, tid, None, self._clock)
+        return Span(self, name, tid, None, annotated)
 
     def _record(self, span: Span) -> None:
         with self._lock:
@@ -249,26 +293,59 @@ class Tracer:
         with self._lock:
             return list(self._by_trace.get(trace_id, ()))
 
-    def export_otlp_json(self) -> str:
-        """OTLP/JSON-shaped export (the uploader's wire format)."""
-        with self._lock:
-            spans = list(self.finished)
-        return json.dumps({
-            "resourceSpans": [{
-                "scopeSpans": [{
-                    "spans": [{
-                        "traceId": f"{s.trace_id:032x}",
-                        "spanId": f"{s.span_id:016x}",
-                        "parentSpanId": (f"{s.parent_id:016x}"
-                                         if s.parent_id else ""),
-                        "name": s.name,
-                        "startTimeUnixNano": int(s.start * 1e9),
-                        "endTimeUnixNano": int((s.end or s.start) * 1e9),
-                        "attributes": [
-                            {"key": k, "value": {"stringValue": str(v)}}
-                            for k, v in s.attrs.items()
-                        ],
-                    } for s in spans],
-                }],
-            }],
-        })
+
+# ---------------- which step compiled ----------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: fired inside the compile event's interval, on its thread, when the
+#: executable came out of the persistent cache instead of the compiler
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_compile_lock = threading.Lock()
+_compile_counts = {"built": 0, "fetched": 0, "seconds": 0.0}
+_compile_subscribers: list = []
+
+
+def compile_counts() -> dict:
+    """Programs this process built with XLA / fetched from the
+    persistent cache, and the seconds both took."""
+    with _compile_lock:
+        return dict(_compile_counts)
+
+
+def on_compile(fn) -> None:
+    """Subscribe ``fn(fetched: bool, seconds: float)`` to every backend
+    compile of the process (called on the compiling thread)."""
+    with _compile_lock:
+        _compile_subscribers.append(fn)
+
+
+def _on_cache_hit(event, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _tls.fetched = True
+
+
+def _on_compile(event, seconds, **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    fetched = getattr(_tls, "fetched", False)
+    _tls.fetched = False
+    kind = "fetched" if fetched else "built"
+    with _compile_lock:
+        _compile_counts[kind] += 1
+        _compile_counts["seconds"] += seconds
+        subscribers = list(_compile_subscribers)
+    sp = current_span()
+    if sp is not None:
+        a = sp.attrs
+        a["compile_" + kind] = a.get("compile_" + kind, 0) + 1
+        a["compile_seconds"] = round(
+            a.get("compile_seconds", 0.0) + seconds, 6)
+    for fn in subscribers:
+        fn(fetched, seconds)
+
+
+# jax.monitoring offers no per-listener removal worth relying on: one
+# pair for the process, registered with the module
+jax.monitoring.register_event_listener(_on_cache_hit)
+jax.monitoring.register_event_duration_secs_listener(_on_compile)

@@ -17,6 +17,7 @@ CTE-shared subtree feeding two consumers).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 
@@ -192,10 +193,32 @@ def _execute_plan_mesh(plan: PlanNode, db: Database):
         return None
 
 
-def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
-    """Lower to DQ stages and run on an in-process actor system. Returns
-    None when the plan does not lower (the caller falls back to the
-    recursive walk)."""
+def _source_counters(src) -> dict:
+    """The cumulative chunk and resident counters of a scan source:
+    shared unpruned sources accumulate them across statements, so a
+    span reports a run's DELTA (pruned views are fresh per run)."""
+    return {k: int(getattr(src, k, 0))
+            for k in ("chunks_read", "chunks_skipped", "resident_hits",
+                      "resident_rows")}
+
+
+def _pruning_since(src, before: dict) -> dict:
+    """The pruning attrs of a scan span: the run's counter deltas,
+    portions skipped by zone maps and portions in all."""
+    pruning = {k: v - before[k] for k, v in _source_counters(src).items()}
+    # resident-hit attribution: EXPLAIN ANALYZE shows how much of the
+    # scan the HBM tier served without touching host bytes
+    pruning["resident_portions"] = pruning.pop("resident_hits")
+    pruning["portions_skipped"] = int(getattr(src, "portions_skipped", 0))
+    pruning["portions_total"] = pruning["portions_skipped"] + sum(
+        len(s.metas) for s in getattr(src, "subs", ()))
+    return pruning
+
+
+def _build_dq(plan: PlanNode, db: Database):
+    """Partition the sources, lower to DQ stages and build the actor
+    graph: ``(stages, parts, runtime, handle)``, or None when the plan
+    does not lower."""
     from ydb_tpu.dq.compute import build_stage_graph
     from ydb_tpu.kqp.dq_lower import plan_to_stages
     from ydb_tpu.runtime.actors import ActorSystem
@@ -242,11 +265,42 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
         # plan shapes that do not lower (e.g. a join-rooted plan with no
         # result Transform) keep working through the recursive walk
         return None
+    return stages, parts, rt, handle
+
+
+def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
+    """Lower to DQ stages and run on an in-process actor system. Returns
+    None when the plan does not lower (the caller falls back to the
+    recursive walk)."""
+    from ydb_tpu.obs.probes import StageTimer
+
+    with tracing.span("dq.build") as bsp:
+        built = _build_dq(plan, db)
+    if built is None:
+        return None
+    stages, parts, rt, handle = built
+    # the source scans charge the same stages and pruning counters as
+    # the walk's (_scan_node), all of them to the one "dq" span; the
+    # timer rides the shared base sources for this run only
+    timed = [db.sources[t] for t in parts
+             if bsp.recording and hasattr(db.sources[t], "attach_timer")]
+    timer = StageTimer() if timed else None
+    before = [_source_counters(src) for src in timed]
+    for src in timed:
+        src.attach_timer(timer)
     try:
         with tracing.span("dq") as sp:
             sp.set(stages=len(stages), tasks=_DQ_TASKS)
             handle.start()
-            rt.run()
+            with tracing.span("dq.pump"):
+                rt.run()
+            if timer is not None:
+                pruning = collections.Counter()
+                for src, b in zip(timed, before):
+                    pruning.update(_pruning_since(src, b))
+                sp.set(**{f"stage_{k}": v
+                          for k, v in timer.snapshot().items()},
+                       **pruning)
         err = handle.collector.error
         if err is not None and "deadline" in err:
             # the graph aborted on statement-deadline expiry: surface
@@ -256,6 +310,8 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
             raise RuntimeError("DQ stage graph did not complete")
         return handle.collector.result_block()
     finally:
+        for src in timed:
+            src.attach_timer(None)
         # a cancelled/aborted graph still holds spilled blobs for any
         # parked or accumulated block ids; drop them with the graph
         handle.close()
@@ -334,13 +390,9 @@ def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
         # read. The pruned view carries its predicate fingerprint into
         # the device cache key, so pruned streams never alias unpruned
         # ones.
-        src = _pruned_source(src, plan.program, db)
-        # chunk counters are cumulative on the source object; shared
-        # unpruned sources accumulate across statements, so the span
-        # reports this run's DELTA (pruned views are fresh per run)
-        chunks0 = {k: int(getattr(src, k, 0))
-                   for k in ("chunks_read", "chunks_skipped",
-                             "resident_hits", "resident_rows")}
+        with tracing.span("scan.prune"):
+            src = _pruned_source(src, plan.program, db)
+        chunks0 = _source_counters(src)
         raw_stream = src.blocks(db.scan_block_rows, ex.read_cols)
         stream = raw_stream
         bc = db.block_cache
@@ -367,17 +419,9 @@ def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
             base_src.attach_timer(None)
     if want_stats:
         stages = timer.snapshot()
-        pruning = {k: int(getattr(src, k, 0)) - v0
-                   for k, v0 in chunks0.items()}
-        # resident-hit attribution: EXPLAIN ANALYZE shows how much of
-        # the scan the HBM tier served without touching host bytes
-        pruning["resident_portions"] = pruning.pop("resident_hits")
-        pruning["portions_skipped"] = int(
-            getattr(src, "portions_skipped", 0))
-        pruning["portions_total"] = pruning["portions_skipped"] + sum(
-            len(s.metas) for s in getattr(src, "subs", ()))
+        pruning = _pruning_since(src, chunks0)
         if sp.recording:
-            sp.set(table=plan.table, rows=int(out.length),
+            sp.set(table=plan.table, rows=out.live_rows(),
                    compile_cache=("miss" if fresh else "hit"),
                    **{f"stage_{k}": v for k, v in stages.items()},
                    **pruning)
@@ -415,9 +459,7 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
     try:
         if site.node.program is not None:
             src = _pruned_source(src, site.node.program, db)
-        chunks0 = {k: int(getattr(src, k, 0))
-                   for k in ("chunks_read", "chunks_skipped",
-                             "resident_hits", "resident_rows")}
+        chunks0 = _source_counters(src)
         staging = (timer.stage("stage") if timer is not None
                    else contextlib.nullcontext())
         if isinstance(src, ColumnSource):
@@ -456,19 +498,14 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
                 stream = bc.stream(
                     key_of(site.read_cols, db.scan_block_rows),
                     lambda: raw_stream)
-            blocks = tuple(stream)
+            with tracing.span("scan.pull"):
+                blocks = tuple(stream)
             with staging:
                 blk = plan_fuse.fit_blocks(blocks, site.capacity)
     finally:
         if timer is not None and hasattr(base_src, "attach_timer"):
             base_src.attach_timer(None)
-    pruning = {k: int(getattr(src, k, 0)) - v0
-               for k, v0 in chunks0.items()}
-    pruning["resident_portions"] = pruning.pop("resident_hits")
-    pruning["portions_skipped"] = int(
-        getattr(src, "portions_skipped", 0))
-    pruning["portions_total"] = pruning["portions_skipped"] + sum(
-        len(s.metas) for s in getattr(src, "subs", ()))
+    pruning = _pruning_since(src, chunks0)
     return blk, pruning
 
 
@@ -511,7 +548,7 @@ def _run_fused(fused, db: Database, fsp) -> TableBlock:
                                              fused.donate)
             inputs[other.key] = blk
             if want_stats:
-                emit_obs(sp, other, timer, int(blk.length), pruning)
+                emit_obs(sp, other, timer, blk.live_rows(), pruning)
 
     site = sites[primary]
     with tracing.span("scan") as sp:
@@ -519,14 +556,15 @@ def _run_fused(fused, db: Database, fsp) -> TableBlock:
         blk, pruning = _stage_fused_site(site, db, timer, fused.donate)
         inputs[site.key] = blk
         # rows read before the dispatch: donated inputs are dead after
-        rows = int(blk.length) if want_stats else 0
+        rows = blk.live_rows() if want_stats else 0
         while True:
             # cooperative cancellation between (uninterruptible) fused
             # dispatches: a statement past its deadline stops here
             statement_deadline.check_current("fused dispatch")
             computing = (timer.stage("compute") if timer is not None
                          else contextlib.nullcontext())
-            with computing:
+            with computing, tracing.span("dispatch",
+                                         program="plan_fused"):
                 out, totals = fused.run(inputs)
             over = fused.overflowed(totals)
             if not over:
@@ -552,7 +590,8 @@ def _execute_plan_fused(plan: PlanNode, db: Database) -> TableBlock | None:
     (the caller falls back to the per-node walk)."""
     from ydb_tpu.ssa import plan_fuse
 
-    sig = plan_fuse.plan_signature_cached(plan, db)
+    with tracing.span("plan.signature"):
+        sig = plan_fuse.plan_signature_cached(plan, db)
     if sig is None or not sig.sites:
         return None
     if chaos.hit("fuse.trace") is not None:
@@ -637,7 +676,8 @@ def _execute_node(plan: PlanNode, db: Database, _memo: dict) -> TableBlock:
             else:
                 sp.set(compile_cache="hit")
             run, aux = hit
-            return run(block, aux)
+            with tracing.span("dispatch", program="transform"):
+                return run(block, aux)
     if isinstance(plan, Concat):
         # branches execute independently (planner guarantees identical
         # column names/types); live rows append in branch order

@@ -53,6 +53,7 @@ def _key_i64(cols: list[Column]) -> jax.Array:
     )
 
 
+@jax.named_scope("ydb.sorted_build")
 def _sorted_build(bk: jax.Array, blive: jax.Array):
     """Sort build keys with dead rows last, WITHOUT a value sentinel
     (sentinels collide with legitimate INT64_MAX keys).
@@ -81,6 +82,7 @@ def _join_keys_live(block: TableBlock, keys: list[str]) -> tuple:
     return _key_i64(cols), live
 
 
+@jax.named_scope("ydb.lookup_join")
 def lookup_join(
     probe: TableBlock,
     build: TableBlock,
@@ -161,6 +163,7 @@ def run_equi_join(
     count's shape class. No guessed capacity, no overflow retry that
     would compile the sort again.
     """
+    from ydb_tpu.obs import tracing
     from ydb_tpu.ssa.plan_fuse import fit_blocks, shape_class
 
     def fit(block):  # zero-pad to the shape class; live prefix untouched
@@ -172,18 +175,23 @@ def run_equi_join(
     if not expand:
         if kind not in ("inner", "left", "semi", "anti"):
             raise ValueError(kind)
-        return _lookup_join_of_kind(
-            probe, build, tuple(probe_keys), tuple(build_keys),
-            tuple(payload), suffix, kind)
+        with tracing.span("dispatch", program="join_lookup"):
+            return _lookup_join_of_kind(
+                probe, build, tuple(probe_keys), tuple(build_keys),
+                tuple(payload), suffix, kind)
     if kind not in ("inner", "left"):
         # expand_join silently computes INNER for anything else
         raise ValueError(f"expand join does not support kind {kind!r}")
-    match = _expand_match_jit(probe, build, tuple(probe_keys),
-                              tuple(build_keys), kind)
+    with tracing.span("dispatch", program="join_expand"):
+        match = _expand_match_jit(probe, build, tuple(probe_keys),
+                                  tuple(build_keys), kind)
     # the one sync of an expand join: the exact output size
-    cap = shape_class(int(match[-1]))
-    return _expand_emit_jit(probe, build, match, tuple(probe_payload),
-                            tuple(build_payload), cap, suffix, kind)[0]
+    with tracing.span("device.wait"):
+        total = int(match[-1])
+    with tracing.span("dispatch", program="join_expand"):
+        return _expand_emit_jit(
+            probe, build, match, tuple(probe_payload),
+            tuple(build_payload), shape_class(total), suffix, kind)[0]
 
 
 # The join kernels are some fifty jnp ops each. Called eagerly every op
@@ -207,6 +215,7 @@ def _lookup_join_of_kind(probe, build, probe_keys, build_keys, payload,
     return kernels.compact(probe, ~found & probe.row_mask())  # anti
 
 
+@jax.named_scope("ydb.expand_match")
 def _expand_match(probe, build, probe_keys, build_keys, kind):
     """The sort-bearing half of an expand join: per probe row, where its
     matches start in the sorted build side and how many output rows it
@@ -237,6 +246,7 @@ def _expand_match(probe, build, probe_keys, build_keys, kind):
     return order, lo, matches, offsets, total
 
 
+@jax.named_scope("ydb.expand_emit")
 def _expand_emit(probe, build, match, probe_payload, build_payload,
                  out_capacity, build_suffix, kind):
     """The other half: map each of ``out_capacity`` output slots back

@@ -122,6 +122,7 @@ def days_from_civil(y, m, d):
 # ---------------- filter / compact ----------------
 
 
+@jax.named_scope("ydb.apply_filter")
 def apply_filter(block: TableBlock, mask: jax.Array) -> TableBlock:
     """Late-materialization filter: fold mask into live length accounting by
     compacting. Cheap alternative when no compaction is needed: callers keep
@@ -129,6 +130,7 @@ def apply_filter(block: TableBlock, mask: jax.Array) -> TableBlock:
     return compact(block, mask)
 
 
+@jax.named_scope("ydb.compact")
 def compact(block: TableBlock, selected: jax.Array) -> TableBlock:
     """Move selected live rows to the front (stable), update length.
 
@@ -149,6 +151,7 @@ def compact(block: TableBlock, selected: jax.Array) -> TableBlock:
 # ---------------- grouped aggregation ----------------
 
 
+@jax.named_scope("ydb.group_ids_dense")
 def group_ids_dense(
     keys: list[Column],
     bounds: list[int],
@@ -170,6 +173,7 @@ def group_ids_dense(
     return gid, num_groups
 
 
+@jax.named_scope("ydb.group_ids_sorted")
 def group_ids_sorted(
     keys: list[Column], live: jax.Array, max_groups: int
 ) -> tuple[jax.Array, jax.Array]:
@@ -267,6 +271,7 @@ def first_live_index(hits: jax.Array) -> tuple[jax.Array, jax.Array]:
     return jnp.minimum(first, max(n - 1, 0)), found
 
 
+@jax.named_scope("ydb.fused_group_reduce")
 def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
                        num_groups: int, dtype=None) -> jax.Array:
     """All linear aggregates in one contraction: (rows x slots) stacked
@@ -348,6 +353,7 @@ _INT_LIMB_MAX_ROWS = 1 << 29
 _INT_LIMB2_MAX_ROWS = 1 << 21
 
 
+@jax.named_scope("ydb.fused_group_reduce_banks")
 def fused_group_reduce_banks(banks: dict, gid: jax.Array,
                              num_groups: int) -> dict:
     """All of a GroupByStep's linear banks in ONE contraction.
@@ -420,6 +426,7 @@ def _onehot_reduce(values, valid_row, gid, num_groups: int, fill,
     return reduce_fn(vals, axis=0)
 
 
+@jax.named_scope("ydb.scatter_first")
 def scatter_first(values: jax.Array, valid_row, gid, num_groups: int):
     """Per-group 'some' value: any valid row's value wins (scatter, drop OOB)."""
     if num_groups <= ONEHOT_GROUP_LIMIT and values.ndim == 1:
@@ -434,6 +441,7 @@ def scatter_first(values: jax.Array, valid_row, gid, num_groups: int):
     return out.at[idx].set(values, mode="drop")
 
 
+@jax.named_scope("ydb.scatter_sum")
 def scatter_sum(values, valid_row, gid, num_groups: int, dtype=None):
     dtype = dtype or values.dtype
     if num_groups <= ONEHOT_GROUP_LIMIT:
@@ -452,6 +460,7 @@ def scatter_sum(values, valid_row, gid, num_groups: int, dtype=None):
     return out.at[idx].add(values.astype(dtype), mode="drop")
 
 
+@jax.named_scope("ydb.scatter_min")
 def scatter_min(values, valid_row, gid, num_groups: int):
     init = _extreme(values.dtype, maximum=True)
     if num_groups <= ONEHOT_GROUP_LIMIT:
@@ -462,6 +471,7 @@ def scatter_min(values, valid_row, gid, num_groups: int):
     return out.at[idx].min(values, mode="drop")
 
 
+@jax.named_scope("ydb.scatter_max")
 def scatter_max(values, valid_row, gid, num_groups: int):
     init = _extreme(values.dtype, maximum=False)
     if num_groups <= ONEHOT_GROUP_LIMIT:
@@ -484,6 +494,7 @@ def _extreme(dtype, maximum: bool):
 # ---------------- sort / top-k ----------------
 
 
+@jax.named_scope("ydb.sort_perm")
 def sort_perm(
     keys: list[Column],
     descending: list[bool],
@@ -512,6 +523,7 @@ def sort_perm(
     return jnp.lexsort(tuple(sort_keys))
 
 
+@jax.named_scope("ydb.sort_block")
 def sort_block(
     block: TableBlock,
     keys: list[str],
