@@ -13,6 +13,8 @@ worker imports every test file.
 """
 
 import os
+import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -129,14 +131,34 @@ def test_supported_fused_rejects_what_mosaic_refuses():
         jnp.float32, 1024, pallas_kernels.MAX_FUSED_SLOTS + 1)
 
 
+def _pushed_down(sql_file: str, data):
+    """The program the SQL path hands the scan: the statement as the
+    benchmark sends it, its aggregating Transform pushed into the scan
+    by the walk (plan/executor.py), with the Transform's aliases."""
+    from ydb_tpu.plan import executor as plan_executor
+    from ydb_tpu.sql.parser import parse
+    from ydb_tpu.sql.planner import Catalog, plan_select_full
+
+    sql = (pathlib.Path(__file__).resolve().parents[1] / "bench"
+           / "statements" / sql_file).read_text()
+    catalog = Catalog(schemas={t: data.schema(t) for t in data.tables},
+                      primary_keys=dict(tpch.PRIMARY_KEYS),
+                      dicts=data.dicts)
+    plan = plan_select_full(parse(sql), catalog, None).plan
+    pushed = plan_executor._pushdown_scan(plan, set())
+    return pushed.program, dict(plan.dict_aliases)
+
+
 @pytest.mark.parametrize("gemm", (False, True), ids=("vpu", "gemm"))
-@pytest.mark.parametrize("query", ("q1", "q6"))
+@pytest.mark.parametrize("query", ("q1", "q6", "q1.sql", "q6.sql"))
 def test_scan_partial_fits_beside_a_resident_table(
         query, gemm, one_chip, no_persistent_cache, monkeypatch):
-    """The pushdown partial program of Q1/Q6 over one scan block, in
-    both one-hot tiers: "vpu" is what a TPU traces (kernels.
-    _gemm_is_exact() is false there; steered here because the backend
-    of this process is the CPU), "gemm" what any other backend does.
+    """The pushdown partial program of Q1/Q6 over one scan block (the
+    engine tier's hand-made programs, and what the walk composes from
+    the benchmark's SQL text), in both one-hot tiers: "vpu" is what a
+    TPU traces (kernels._gemm_is_exact() is false there; steered here
+    because the backend of this process is the CPU), "gemm" what any
+    other backend does.
     The GEMM tier's temporaries scale with the block (Q1: 1.9 GB at
     2^20 rows, 8.6 GB at 2^22), so at ``scan_block_rows`` both stay
     under TEMP_SHARE of the chip."""
@@ -147,8 +169,13 @@ def test_scan_partial_fits_beside_a_resident_table(
     data = tpch.TpchData(sf=0.001, seed=5)
     src = ColumnSource(columns=data.tables["lineitem"],
                        schema=tpch.LINEITEM_SCHEMA, dicts=data.dicts)
-    program = {"q1": tpch.q1_program, "q6": tpch.q6_program}[query]()
-    ex = ScanExecutor(program, src, block_rows=cap)
+    if query.endswith(".sql"):
+        program, aliases = _pushed_down(query, data)
+    else:
+        program = {"q1": tpch.q1_program, "q6": tpch.q6_program}[query]()
+        aliases = None
+    ex = ScanExecutor(program, src, block_rows=cap, dict_aliases=aliases)
+    assert ex.folds_partials
     block = next(iter(src.blocks(cap, ex.read_cols)))
     rows = ShardConfig().scan_block_rows
 
@@ -162,5 +189,11 @@ def test_scan_partial_fits_beside_a_resident_table(
     compiled = jax.jit(ex.partial.run).lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, gemm, temp)
-    if gemm and query == "q1":
+    # the block is aggregated under its filter mask: nothing sorts,
+    # gathers or scatters over its rows (kernels.compact would)
+    moved = [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+             if re.search(r"\b(sort|gather|scatter)\(", ln)
+             and f"[{rows}]" in ln]
+    assert not moved, (query, moved[:3])
+    if gemm and query.startswith("q1"):
         assert temp > 1e9, "the GEMM tier's hit matrix went somewhere?"

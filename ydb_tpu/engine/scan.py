@@ -200,6 +200,7 @@ class ScanExecutor:
         inflight_blocks: int = 4,
         combine_every: int = 8,
         group_est: float | None = None,
+        dict_aliases: dict[str, str] | None = None,
     ):
         self.source = source
         self.block_rows = block_rows
@@ -233,9 +234,19 @@ class ScanExecutor:
 
         self._out_nullable = check_program(program, in_schema).out_nullable
         self.partial_prog, self.final_prog = twophase.split(program)
+        # renamed string columns (a Transform's dict_aliases, when its
+        # program runs here: plan/executor.py's aggregate pushdown)
+        # resolve their dictionaries in all three programs; a string
+        # aggregate's output carries its input's, itself maybe renamed
+        aliases = dict(dict_aliases or {})
+        merged_aliases = {
+            **{out: aliases.get(col, col) for out, col
+               in twophase.dict_aliases(self.partial_prog).items()},
+            **aliases,
+        }
         self.partial = compile_program(
             self.partial_prog, in_schema, source.dicts, key_spaces,
-            group_est=group_est,
+            group_est=group_est, dict_aliases=aliases,
         )
         self._partial_jit = jax.jit(self.partial.run)
         self._partial_aux = device_aux(self.partial.aux)
@@ -248,7 +259,7 @@ class ScanExecutor:
             comb = compile_program(
                 combine_prog, self.partial.out_schema, source.dicts,
                 key_spaces,
-                dict_aliases=twophase.dict_aliases(self.partial_prog),
+                dict_aliases=merged_aliases,
             )
             comb_run = comb.run
 
@@ -262,7 +273,7 @@ class ScanExecutor:
             self.final = compile_program(
                 self.final_prog, self.partial.out_schema, source.dicts,
                 key_spaces,
-                dict_aliases=twophase.dict_aliases(self.partial_prog),
+                dict_aliases=merged_aliases,
             )
             self._final_jit = jax.jit(self.final.run)
             self._final_aux = device_aux(self.final.aux)
@@ -282,6 +293,14 @@ class ScanExecutor:
             self._final_aux = {}
             self._finalize_jit = jax.jit(
                 lambda parts, aux: merge_blocks_device(list(parts)))
+
+    @property
+    def folds_partials(self) -> bool:
+        """The program aggregates, and its partial states are
+        shape-stable from block to block (a keyless, dense or
+        dense-slots group layout), so they fold through the combine
+        program and the scan ends on the device in a handful of rows."""
+        return self._combine_jit is not None
 
     def detach(self) -> "ScanExecutor":
         """Drop the source reference: compiled state only. Callers that

@@ -70,6 +70,11 @@ class QueryProfile:
     execute_seconds: float = 0.0   # seconds - compile_seconds
     fused_stages: int = 0     # plan nodes folded into one traced dispatch
     fragments_elided: int = 0  # dispatch boundaries removed by fusion
+    #: scans that ran their consumer's aggregation themselves (the
+    #: walk's aggregate pushdown, plan/executor.py): each block is
+    #: aggregated under its filter mask and the scan ends on the
+    #: device in a handful of rows
+    agg_pushdown: int = 0
     #: cross-query batching (kqp/batch.py): group id + member count of
     #: the micro-batch that served this statement (0 = unbatched), how
     #: many of its scan sites were served by a staging shared with
@@ -262,6 +267,7 @@ def build_profile(spans, sql: str = "", kind: str = "",
         p.compile_seconds += float(a.get("first_trace_seconds", 0.0))
         if s.name in SCAN_SPANS:
             rows_out += int(a.get("rows", 0))
+            p.agg_pushdown += int(a.get("agg_pushdown", 0))
         if s.name in STAGE_SPANS:
             for k in STAGE_KEYS:
                 p.stages[k] += float(a.get(f"stage_{k}", 0.0))
@@ -405,7 +411,8 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
             continue
         a = s["attrs"]
         bits = [f"seconds={s['seconds']:.6f}"]
-        for k in ("table", "shard", "rows", "compile_cache"):
+        for k in ("table", "shard", "rows", "compile_cache",
+                  "agg_pushdown"):
             if k in a:
                 bits.append(f"{k}={a[k]}")
         lines.append(f"  {s['name']}: " + " ".join(bits))

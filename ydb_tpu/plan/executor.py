@@ -39,8 +39,15 @@ from ydb_tpu.engine.scan import (
 from ydb_tpu.obs import tracing
 from ydb_tpu.obs.probes import probe as _probe
 from ydb_tpu.ssa import join as join_kernels
-from ydb_tpu.ssa import kernels
+from ydb_tpu.ssa import kernels, twophase
 from ydb_tpu.ssa.compiler import compile_program
+from ydb_tpu.ssa.program import (
+    AssignStep,
+    FilterStep,
+    GroupByStep,
+    Program,
+    ProjectStep,
+)
 from ydb_tpu.plan.nodes import (
     Concat,
     ExpandJoin,
@@ -126,17 +133,44 @@ _DQ_BLOCK_ROWS = int(os.environ.get("YDB_TPU_DQ_BLOCK_ROWS",
                                     str(1 << 20)))
 
 
+def _inputs(n: PlanNode) -> list:
+    if isinstance(n, (LookupJoin, ExpandJoin)):
+        return [n.probe, n.build]
+    if isinstance(n, Transform):
+        return [n.input]
+    if isinstance(n, Concat):
+        return list(n.inputs)
+    return []
+
+
 def _plan_nodes(plan: PlanNode):
     stack = [plan]
     while stack:
         n = stack.pop()
         yield n
-        if isinstance(n, (LookupJoin, ExpandJoin)):
-            stack += [n.probe, n.build]
-        elif isinstance(n, Transform):
-            stack.append(n.input)
-        elif isinstance(n, Concat):
-            stack += list(n.inputs)
+        stack += _inputs(n)
+
+
+class _Memo(dict):
+    """One statement's walk: id(node) -> its result block, so a shared
+    subtree (a CTE referenced from several places) executes once.
+    ``shared`` holds the ids of the nodes that more than one consumer
+    reads: their results must stay whole for the next reader."""
+
+    def __init__(self, plan: PlanNode):
+        super().__init__()
+        consumers: collections.Counter = collections.Counter()
+        seen: set[int] = set()
+        stack = [plan]
+        while stack:
+            n = stack.pop()
+            if id(n) in seen:
+                continue
+            seen.add(id(n))
+            for child in _inputs(n):
+                consumers[id(child)] += 1
+                stack.append(child)
+        self.shared = {i for i, c in consumers.items() if c > 1}
 
 
 def _partition_for_dq(src) -> list:
@@ -318,7 +352,7 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
 
 
 def execute_plan(plan: PlanNode, db: Database,
-                 _memo: dict | None = None,
+                 _memo: _Memo | None = None,
                  use_dq: bool | None = None) -> TableBlock:
     """Execute a logical plan: join-bearing plans route through the DQ
     stage graph (the production executer path); single-stage plans and
@@ -346,7 +380,7 @@ def execute_plan(plan: PlanNode, db: Database,
             out = _execute_plan_fused(plan, db)
             if out is not None:
                 return out
-        _memo = {}
+        _memo = _Memo(plan)
     hit = _memo.get(id(plan))
     if hit is not None:
         return hit
@@ -355,19 +389,81 @@ def execute_plan(plan: PlanNode, db: Database,
     return out
 
 
-def _scan_node(plan: TableScan, db: Database, sp) -> TableBlock:
-    from ydb_tpu.obs.probes import StageTimer
-
-    src = db.sources[plan.table]
-    key = (plan.table, plan.program)
+def _scan_executor(plan: TableScan, db: Database,
+                   dict_aliases: tuple = ()) -> tuple[ScanExecutor, bool]:
+    """The scan's compiled executor out of ``db._compile_cache``, and
+    whether this call built it. ``dict_aliases`` are those of the
+    Transform whose program the scan's ends in (``_pushdown_scan``)."""
+    key = (plan.table, plan.program, dict_aliases)
     ex = db._compile_cache.get(key)
     fresh = ex is None
     if fresh:
         ex = ScanExecutor(
-            plan.program, src, block_rows=db.scan_block_rows,
-            key_spaces=db.key_spaces,
+            plan.program, db.sources[plan.table],
+            block_rows=db.scan_block_rows, key_spaces=db.key_spaces,
+            dict_aliases=dict(dict_aliases),
         ).detach()  # cache compiled state, not the source arrays
         db._compile_cache[key] = ex
+    return ex, fresh
+
+
+def _pushdown_scan(plan: Transform, shared: set) -> TableScan | None:
+    """Aggregate pushdown into the scan (the reference's
+    PushOlapAggregate, kqp_opt_phy_olap_agg.cpp): the ONE TableScan
+    that answers an aggregating Transform over a scan, its program the
+    scan's with the Transform's appended, or None where the plan keeps
+    the two apart: the scan feeds another consumer too, something
+    other than assign / filter / project comes before the group-by, or
+    the two-phase split does not take one of the aggregates."""
+    scan = plan.input
+    if not isinstance(scan, TableScan) or scan.program is None \
+            or id(scan) in shared:
+        return None
+    head = scan.program.steps
+    steps = head + plan.program.steps
+    for i, step in enumerate(steps):
+        if isinstance(step, GroupByStep):
+            if i < len(head):
+                return None
+            program = Program(steps)
+            try:
+                twophase.split(program)
+            except NotImplementedError:
+                return None
+            return TableScan(scan.table, program)
+        if not isinstance(step, (AssignStep, FilterStep, ProjectStep)):
+            return None
+    return None
+
+
+def _scan_aggregated(plan: Transform, db: Database,
+                     shared: set) -> TableBlock | None:
+    """Run ``Transform(TableScan)`` as one scan that aggregates each
+    block under its filter mask, folds the partial states on the device
+    and finalizes in one dispatch: no block is compacted, nothing is
+    fetched before the result, no program is shaped by the selected
+    row count. None where the shape does not allow it (the caller runs
+    the Transform over the scan's output): ``_pushdown_scan``'s
+    conditions, or a group layout whose partials are not shape-stable
+    (sort-derived: per-block sorts and a merge of N-group partials are
+    another trade). The executor that says so stays cached, so the
+    next run of the statement asks a dict."""
+    pushed = _pushdown_scan(plan, shared)
+    if pushed is None:
+        return None
+    ex, fresh = _scan_executor(pushed, db, plan.dict_aliases)
+    if not ex.folds_partials:
+        return None
+    with tracing.span("scan") as sp:
+        sp.set(agg_pushdown=1)
+        return _scan_node(pushed, db, sp, ex, fresh)
+
+
+def _scan_node(plan: TableScan, db: Database, sp, ex: ScanExecutor,
+               fresh: bool) -> TableBlock:
+    from ydb_tpu.obs.probes import StageTimer
+
+    src = db.sources[plan.table]
     # stage accounting while a query trace records OR a probe session
     # listens (probe observability must not degrade when profiling is
     # off — the shard-level probes fire unconditionally too). The timer
@@ -635,13 +731,38 @@ def _compiled_transform(plan: Transform, schema, db: Database):
     return jax.jit(cp.run), device_aux(cp.aux)
 
 
-def _execute_node(plan: PlanNode, db: Database, _memo: dict) -> TableBlock:
+def _transform_node(plan: Transform, block: TableBlock,
+                    db: Database) -> TableBlock:
+    """Run a Transform's program over its input's result block, in a
+    program compiled at that block's capacity."""
+    key = (plan.program, plan.dict_aliases, block.schema)
+    hit = db._compile_cache.get(key)
+    with tracing.span("transform") as sp:
+        if hit is None:
+            sp.set(compile_cache="miss")
+            # mandatory precondition (ydb_tpu.analysis): surface
+            # step-indexed diagnostics for malformed programs
+            # before any trace work; compile_program re-checks, but
+            # this keeps the executor the choke point even if
+            # lowering changes
+            check_program(plan.program, block.schema)
+            hit = _compiled_transform(plan, block.schema, db)
+            db._compile_cache[key] = hit
+        else:
+            sp.set(compile_cache="hit")
+        run, aux = hit
+        with tracing.span("dispatch", program="transform"):
+            return run(block, aux)
+
+
+def _execute_node(plan: PlanNode, db: Database,
+                  _memo: _Memo) -> TableBlock:
     if isinstance(plan, TableScan):
         src = db.sources[plan.table]
         if plan.program is None:
             return _materialize(src, plan.columns)
         with tracing.span("scan") as sp:
-            return _scan_node(plan, db, sp)
+            return _scan_node(plan, db, sp, *_scan_executor(plan, db))
     if isinstance(plan, LookupJoin):
         probe = execute_plan(plan.probe, db, _memo)
         build = execute_plan(plan.build, db, _memo)
@@ -659,25 +780,11 @@ def _execute_node(plan: PlanNode, db: Database, _memo: dict) -> TableBlock:
             build_payload=plan.build_payload,
         )
     if isinstance(plan, Transform):
-        block = execute_plan(plan.input, db, _memo)
-        key = (plan.program, plan.dict_aliases, block.schema)
-        hit = db._compile_cache.get(key)
-        with tracing.span("transform") as sp:
-            if hit is None:
-                sp.set(compile_cache="miss")
-                # mandatory precondition (ydb_tpu.analysis): surface
-                # step-indexed diagnostics for malformed programs
-                # before any trace work; compile_program re-checks, but
-                # this keeps the executor the choke point even if
-                # lowering changes
-                check_program(plan.program, block.schema)
-                hit = _compiled_transform(plan, block.schema, db)
-                db._compile_cache[key] = hit
-            else:
-                sp.set(compile_cache="hit")
-            run, aux = hit
-            with tracing.span("dispatch", program="transform"):
-                return run(block, aux)
+        out = _scan_aggregated(plan, db, _memo.shared)
+        if out is not None:
+            return out
+        return _transform_node(
+            plan, execute_plan(plan.input, db, _memo), db)
     if isinstance(plan, Concat):
         # branches execute independently (planner guarantees identical
         # column names/types); live rows append in branch order
