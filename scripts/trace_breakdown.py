@@ -18,6 +18,7 @@ import argparse
 import heapq
 import json
 import pathlib
+import re
 import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -27,7 +28,10 @@ PREFIX = "ydb."
 #: spans that cover everything beneath them: a gap put down to one of
 #: these is not explained
 COVERING = {"ydb.query", "ydb.execute", "ydb.dq", "ydb.scan",
-            "ydb.transform", "ydb.analyze"}
+            "ydb.transform", "ydb.analyze", "ydb.mesh"}
+#: a device operation that crosses devices, by its HLO name
+COLLECTIVE = re.compile(
+    r"all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter")
 
 
 def host_lines(profile) -> list:
@@ -112,9 +116,12 @@ def device_time_by_scope(path: str, lo: float, hi: float) -> dict:
     space = xplane_pb2.XSpace()
     space.ParseFromString(pathlib.Path(path).read_bytes())
     by_scope, by_op, carrier = {}, {}, {}
+    collective = {"events": 0, "seconds": 0.0, "exposed_seconds": 0.0,
+                  "by_scope": {}}
     for plane in space.planes:
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
             continue
+        crossing, local = [], []
         stat_name = {k: v.name for k, v in plane.stat_metadata.items()}
         described = {}
         for mid, md in plane.event_metadata.items():
@@ -144,7 +151,33 @@ def device_time_by_scope(path: str, lo: float, hi: float) -> dict:
                 by_scope[scope] = by_scope.get(scope, 0.0) + dur
                 key = f"{scope} | {name} | {source}"
                 by_op[key] = by_op.get(key, 0.0) + dur
-    return {"by_scope": by_scope, "by_op": by_op, "scope_stat": carrier}
+                if COLLECTIVE.search(name):
+                    crossing.append((start, start + dur))
+                    collective["events"] += 1
+                    collective["by_scope"][scope] = (
+                        collective["by_scope"].get(scope, 0.0) + dur / 1e9)
+                else:
+                    local.append((start, start + dur))
+        # a collective is exposed while nothing else runs on its device
+        crossing = trace_reduce.union(crossing)
+        hidden = overlap_ns(crossing, trace_reduce.union(local))
+        total = sum(e - s for s, e in crossing)
+        collective["seconds"] += total / 1e9
+        collective["exposed_seconds"] += (total - hidden) / 1e9
+    return {"by_scope": by_scope, "by_op": by_op, "scope_stat": carrier,
+            "collective": collective}
+
+
+def overlap_ns(a, b) -> float:
+    """Nanoseconds covered by both of two sorted disjoint interval lists."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
 
 
 def breakdown(path: str) -> dict:
@@ -191,6 +224,8 @@ def breakdown(path: str) -> dict:
         "device_s_by_scope": top(scopes.get("by_scope", {}), 20),
         "device_s_top_ops": top(scopes.get("by_op", {}), 10),
         "scope_stat": scopes.get("scope_stat", {}),
+        # summed over the devices: a collective runs on each of them
+        "collective": scopes.get("collective", {}),
         "host_self_s_by_span": top(self_by_span(lines, lo, hi), 20),
         "host_events_per_statement": round(
             sum(per_statement.values()) / statements, 1),
@@ -220,6 +255,31 @@ def main(argv=None) -> int:
         return path
 
     trace_reduce.newest_trace = newest_and_read
+    peak = run.memory_peak_bytes
+
+    def peak_of_each_device():
+        """Every device's peak and live bytes as the window closes (the
+        benchmark reports the fullest device's peak alone)."""
+        import jax
+
+        found["device_memory"] = [
+            {k: int((d.memory_stats() or {}).get(k, 0))
+             for k in ("peak_bytes_in_use", "bytes_in_use")}
+            for d in jax.local_devices()]
+        return peak()
+
+    run.memory_peak_bytes = peak_of_each_device
+    totals = run.deploy.resident_totals
+
+    def totals_and_mesh_report(cluster):
+        """What each mesh device holds resident, where the program has
+        the report (``Cluster.mesh_report``, since PR 31)."""
+        report = getattr(cluster, "mesh_report", lambda: [])()
+        if report:
+            found["mesh_report"] = report
+        return totals(cluster)
+
+    run.deploy.resident_totals = totals_and_mesh_report
     cell = run.load_cell(args.workload)
     run.check_device(cell["chips"])
     result = run.run_cell(cell, args.seed, args.seconds, True)

@@ -495,6 +495,7 @@ class ResidentStore:
         with self._lock:
             return {
                 "portions": len(self._info),
+                "rows": sum(i["rows"] for i in self._info.values()),
                 "columns": len(self._cols),
                 "bytes": self._nbytes,
                 "budget": self.budget(),

@@ -1196,6 +1196,31 @@ class Cluster:
                 resident_mod.assign_device_slices(stores, mex.n,
                                                   devices=devices)
 
+    def mesh_report(self) -> list[dict]:
+        """What each mesh device holds resident: one entry a device
+        with its index, the shard stores bound to it and their resident
+        bytes, rows and portions (the stores' own ``snapshot()``s),
+        over all tables and for each table. ``[]`` with the mesh off."""
+        if self._mesh_exec is None:
+            return []
+        report = [{"device": d, "stores": 0, "bytes": 0, "rows": 0,
+                   "portions": 0, "tables": {}}
+                  for d in range(self._mesh_exec.n)]
+        for name, t in self.tables.items():
+            for sh in getattr(t, "shards", ()):
+                store = getattr(sh, "resident", None)
+                snap = store.snapshot() if store is not None else {}
+                if snap.get("device_slot") is None:
+                    continue
+                dev = report[snap["device_slot"]]
+                dev["stores"] += 1
+                held = dev["tables"].setdefault(
+                    name, {"bytes": 0, "rows": 0, "portions": 0})
+                for k in held:
+                    held[k] += snap[k]
+                    dev[k] += snap[k]
+        return report
+
     def _mesh_snapshot(self, snap: int):
         """A PER-SNAPSHOT MeshPlanExecutor: fresh source bindings (so
         concurrent statements never read each other's snapshot) sharing

@@ -43,6 +43,15 @@ SPAN_STAGE = {
 }
 STATEMENT_KEYS = ("plan", "pull", "dispatch", "device_wait", "fetch",
                   "unattributed")
+#: a seventh statement key, named after the ``mesh`` span and only of
+#: a statement that has one (a mesh executor ran): the self time of the
+#: mesh's own ``dispatch`` / ``device.wait`` / ``device.get`` spans,
+#: those beneath the ``mesh`` span and outside its per-shard ``scan``
+#: spans (placing the shards' blocks on the mesh, the collective step,
+#: the wait for it and the answer's copy out). It is taken out of
+#: ``dispatch`` and ``device_wait``, which keep the shard scans' time,
+#: so the seven keys sum to ``seconds`` as the six do.
+MESH_KEY = "mesh"
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
@@ -168,16 +177,34 @@ def statement_stages(spans, seconds: float) -> dict:
     out = {k: 0.0 for k in STATEMENT_KEYS}
     if not spans:
         return out
-    ids = {s.span_id for s in spans}
-    thread = next((s for s in spans if s.parent_id not in ids),
+    by_id = {s.span_id: s for s in spans}
+    thread = next((s for s in spans if s.parent_id not in by_id),
                   spans[0]).thread
+    if any(s.name == MESH_KEY for s in spans):
+        out[MESH_KEY] = 0.0
     selfs = self_seconds(spans)
     for s in spans:
         stage = SPAN_STAGE.get(s.name)
         if stage is not None and s.thread == thread and s.annotated:
+            if MESH_KEY in out and stage in ("dispatch", "device_wait") \
+                    and _mesh_own(s, by_id):
+                stage = MESH_KEY
             out[stage] += selfs[s.span_id]
     out["unattributed"] = max(0.0, seconds - sum(out.values()))
     return out
+
+
+def _mesh_own(span, by_id: dict) -> bool:
+    """Whether the nearest ``mesh`` or scan span above ``span`` is the
+    ``mesh`` span: the mesh executor's own work, not a shard scan's."""
+    parent = by_id.get(span.parent_id)
+    while parent is not None:
+        if parent.name in SCAN_SPANS:
+            return False
+        if parent.name == MESH_KEY:
+            return True
+        parent = by_id.get(parent.parent_id)
+    return False
 
 
 def subtree(spans, root_span_id: int) -> list:
@@ -393,8 +420,9 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
     st = profile.stages
     lines.append("stages: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in STAGE_KEYS))
+    keys = STATEMENT_KEYS + ((MESH_KEY,) if MESH_KEY in st else ())
     lines.append("statement: " + " ".join(
-        f"{k}={st.get(k, 0.0):.6f}" for k in STATEMENT_KEYS))
+        f"{k}={st.get(k, 0.0):.6f}" for k in keys))
     pr = profile.pruning
     lines.append("rows: " + " ".join(
         f"{k}={pr.get(k, 0)}" for k in PRUNING_KEYS))
@@ -411,7 +439,7 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
             continue
         a = s["attrs"]
         bits = [f"seconds={s['seconds']:.6f}"]
-        for k in ("table", "shard", "rows", "compile_cache",
+        for k in ("table", "shard", "device", "rows", "compile_cache",
                   "agg_pushdown"):
             if k in a:
                 bits.append(f"{k}={a[k]}")

@@ -33,11 +33,17 @@ from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.blocks.dictionary import DictionarySet
 from ydb_tpu.engine.oracle import OracleTable
 from ydb_tpu.engine.scan import ColumnSource, required_columns
+from ydb_tpu.obs import tracing
 from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
 from ydb_tpu.ssa import twophase
 from ydb_tpu.ssa.compiler import compile_program
 from ydb_tpu.ssa.ops import Agg
 from ydb_tpu.ssa.program import Program
+
+
+#: named scope of the collectives that merge the shards' partial states
+#: (psum / pmin / pmax, or all_gather): what only a mesh trace shows
+MERGE_SCOPE = "ydb.mesh_merge"
 
 
 def stack_blocks(blocks: list[TableBlock]) -> TableBlock:
@@ -96,7 +102,8 @@ def place_shards(blocks: list[TableBlock], mesh,
         (len(blocks), mesh.devices.shape)
     if capacity is None:
         capacity = blocks[0].capacity
-    with memsan.seam("staging"):
+    with tracing.span("dispatch", program="mesh_place"), \
+            memsan.seam("staging"):
         pieces = [_fit_with_device_axis(jax.device_put(b, d), capacity)
                   for b, d in zip(blocks, devices)]
         out = jax.tree_util.tree_map(
@@ -165,14 +172,15 @@ def _merge_slots(
     rank_tables: dict[str, jax.Array],
 ):
     """Elementwise merge of slot-aligned partial states across the mesh."""
-    cols = _merge_states(
-        {n: (c.data, c.validity) for n, c in block.columns.items()},
-        merge_kinds, rank_tables,
-        red_max=lambda x: jax.lax.pmax(x, SHARD_AXIS),
-        red_min=lambda x: jax.lax.pmin(x, SHARD_AXIS),
-        red_sum=lambda x: jax.lax.psum(x, SHARD_AXIS),
-        red_any=lambda v: jax.lax.pmax(v, SHARD_AXIS),
-    )
+    with jax.named_scope(MERGE_SCOPE):
+        cols = _merge_states(
+            {n: (c.data, c.validity) for n, c in block.columns.items()},
+            merge_kinds, rank_tables,
+            red_max=lambda x: jax.lax.pmax(x, SHARD_AXIS),
+            red_min=lambda x: jax.lax.pmin(x, SHARD_AXIS),
+            red_sum=lambda x: jax.lax.psum(x, SHARD_AXIS),
+            red_any=lambda v: jax.lax.pmax(v, SHARD_AXIS),
+        )
     return TableBlock(cols, block.length, block.schema)
 
 
@@ -257,11 +265,12 @@ def _gather_rows(block: TableBlock) -> TableBlock:
     """all_gather compacted partial rows from every shard into one block."""
     cap = block.capacity
     cols = {}
-    for n, c in block.columns.items():
-        d = jax.lax.all_gather(c.data, SHARD_AXIS)      # (ndev, cap)
-        v = jax.lax.all_gather(c.validity, SHARD_AXIS)
-        cols[n] = Column(d.reshape(-1), v.reshape(-1))
-    lens = jax.lax.all_gather(block.length, SHARD_AXIS)  # (ndev,)
+    with jax.named_scope(MERGE_SCOPE):
+        for n, c in block.columns.items():
+            d = jax.lax.all_gather(c.data, SHARD_AXIS)      # (ndev, cap)
+            v = jax.lax.all_gather(c.validity, SHARD_AXIS)
+            cols[n] = Column(d.reshape(-1), v.reshape(-1))
+        lens = jax.lax.all_gather(block.length, SHARD_AXIS)  # (ndev,)
     ndev = lens.shape[0]
     row = jnp.arange(cap, dtype=jnp.int32)
     mask = (row[None, :] < lens[:, None]).reshape(-1)
@@ -346,11 +355,13 @@ class MeshScan:
                     merged = kernels.compact(merged, live & merged.row_mask())
             else:
                 merged = _gather_rows(part)
-            return self.final.run(merged, faux)
+            with jax.named_scope("ydb.mesh_final"):
+                return self.final.run(merged, faux)
 
         def step(stacked: TableBlock) -> TableBlock:
             block = _local(stacked)
-            part = self.partial.run(block, paux)
+            with jax.named_scope("ydb.mesh_partial"):
+                part = self.partial.run(block, paux)
             return merge_final(part)
 
         self._step = jax.jit(
@@ -384,12 +395,14 @@ class MeshScan:
     def run_stacked(self, stacked: TableBlock) -> TableBlock:
         """stacked: leading device axis == mesh shard count."""
         sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
-        with memsan.seam("staging"):
+        with tracing.span("dispatch", program="mesh_place"), \
+                memsan.seam("staging"):
             stacked = jax.device_put(stacked, sharding)
         if memsan.armed():
             memsan.charge(memsan.nbytes_of(stacked), "staging",
                           owner="mesh_place")
-        return self._step(stacked)
+        with tracing.span("dispatch", program="mesh_step"):
+            return self._step(stacked)
 
     def execute_sources(self, sources, block_rows: int = 1 << 20
                         ) -> OracleTable:

@@ -48,7 +48,8 @@ from ydb_tpu.parallel.dist import (
     _relocal,
     place_shards,
 )
-from ydb_tpu.obs import timeline
+from ydb_tpu.obs import timeline, tracing
+from ydb_tpu.obs.probes import StageTimer
 from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
 from ydb_tpu.parallel.shuffle import (
     exchange_bytes_per_device,
@@ -56,6 +57,7 @@ from ydb_tpu.parallel.shuffle import (
     repartition,
     size_buckets,
 )
+from ydb_tpu.plan.executor import pruning_since, source_counters
 from ydb_tpu.plan.nodes import ExpandJoin, LookupJoin, TableScan, Transform
 from ydb_tpu.ssa import join as join_kernels
 from ydb_tpu.ssa import kernels
@@ -143,6 +145,14 @@ def device_partitions(sources: list, n: int, schema, dicts) -> list:
         else:
             out.append(_ChainSource(g))
     return out
+
+
+def _set_timer(sub, timer) -> None:
+    """Bind a StageTimer (or None) to one device's scan source: a
+    shard's portion stream, or a chain of them."""
+    for s in getattr(sub, "subs", (sub,)):
+        if hasattr(s, "timer"):
+            s.timer = timer
 
 
 def _chaos_dispatch(n_devices: int) -> None:
@@ -294,7 +304,25 @@ class MeshPlanExecutor:
                 f"table {plan.table} has {len(subs)} shards for a"
                 f" {self.n}-device mesh (need exactly one per device)")
         locals_: list[TableBlock] = []
-        for sub in subs:
+        for d, sub in enumerate(subs):
+            # one "scan" span a shard, as the walk has one a TableScan:
+            # the shards are scanned one after another on this thread
+            with tracing.span("scan") as sp:
+                locals_.append(self._scan_shard(plan, sub, d, sp))
+        with tracing.span("device.wait"):
+            cap = _round_up(max(int(b.length) for b in locals_))
+        return place_shards(locals_, self.mesh, capacity=cap)
+
+    def _scan_shard(self, plan: TableScan, sub, device: int,
+                    sp) -> TableBlock:
+        """One shard's scan on this thread. Under a recording span its
+        source charges a StageTimer (``stage_*``) and the span carries
+        the source's pruning counters, as the walk's scan span does."""
+        timer = StageTimer() if sp.recording else None
+        before = source_counters(sub)
+        fresh = False
+        _set_timer(sub, timer)
+        try:
             if plan.program is None:
                 names = plan.columns or sub.schema.names
                 blks = list(sub.blocks(DEFAULT_BLOCK_ROWS, names))
@@ -306,16 +334,24 @@ class MeshPlanExecutor:
                 # i.e. an XLA compile per shard per statement
                 key = ("scan", plan.table, plan.program)
                 ex = self._jit_cache.get(key)
-                if ex is None:
+                fresh = ex is None
+                if fresh:
                     ex = ScanExecutor(
                         plan.program, sub, block_rows=DEFAULT_BLOCK_ROWS,
                         key_spaces=self.db.key_spaces).detach()
                     self._jit_cache[key] = ex
                 blk = ex.run_stream(
-                    sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols))
-            locals_.append(blk)
-        cap = _round_up(max(int(b.length) for b in locals_))
-        return place_shards(locals_, self.mesh, capacity=cap)
+                    sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols),
+                    timer=timer)
+        finally:
+            _set_timer(sub, None)
+        if timer is not None:
+            sp.set(table=plan.table, device=device,
+                   compile_cache=("miss" if fresh else "hit"),
+                   **{f"stage_{k}": v
+                      for k, v in timer.snapshot().items()},
+                   **pruning_since(sub, before))
+        return blk
 
     def _join(self, plan, memo, expand: bool) -> TableBlock:
         probe = self._exec(plan.probe, memo)
@@ -356,7 +392,8 @@ class MeshPlanExecutor:
                     check_vma=False,
                 ))
                 self._jit_cache[key] = step
-            out, worst = step(stacked)
+            with tracing.span("dispatch", program="mesh_repartition"):
+                out, worst = step(stacked)
             # every attempt (including an overflow retry) was a real
             # mesh exchange — account its per-device bytes, and charge
             # the send/recv bucket capacity to the shuffle budget (an
@@ -368,7 +405,8 @@ class MeshPlanExecutor:
             if memsan.armed():
                 memsan.charge(per_dev * self.n, "shuffle",
                               owner="repartition")
-            w = int(np.asarray(worst))
+            with tracing.span("device.wait"):
+                w = int(np.asarray(worst))
             if w <= B:
                 return self._tighten(out)
             B = shape_class(w)  # grace respill, sized by the observation
@@ -376,7 +414,8 @@ class MeshPlanExecutor:
     def _tighten(self, stacked: TableBlock) -> TableBlock:
         """Slice a front-packed stacked block down to a tight capacity so
         join/shuffle output capacities do not compound across stages."""
-        max_len = int(np.asarray(stacked.length).max())
+        with tracing.span("device.wait"):
+            max_len = int(np.asarray(stacked.length).max())
         cap = _round_up(max_len)
         if cap >= stacked.capacity:
             return stacked
@@ -409,7 +448,9 @@ class MeshPlanExecutor:
                 out_specs=P(SHARD_AXIS), check_vma=False,
             ))
             self._jit_cache[key] = step
-        return self._tighten(step(probe, build))
+        with tracing.span("dispatch", program="mesh_lookup"):
+            out = step(probe, build)
+        return self._tighten(out)
 
     def _local_expand(self, plan: ExpandJoin, probe, build):
         cap = _round_up(max(int(probe.capacity * plan.fanout_hint), 1024))
@@ -436,8 +477,10 @@ class MeshPlanExecutor:
                     check_vma=False,
                 ))
                 self._jit_cache[key] = step
-            out, totals = step(probe, build)
-            worst = int(np.asarray(totals).max())
+            with tracing.span("dispatch", program="mesh_expand"):
+                out, totals = step(probe, build)
+            with tracing.span("device.wait"):
+                worst = int(np.asarray(totals).max())
             if worst <= cap:
                 return self._tighten(out)
             cap = _round_up(worst)
@@ -480,7 +523,9 @@ class MeshPlanExecutor:
                     out_specs=P(SHARD_AXIS), check_vma=False,
                 ))
                 self._jit_cache[key] = step
-            return self._tighten(step(stacked))
+            with tracing.span("dispatch", program="mesh_xform"):
+                out = step(stacked)
+            return self._tighten(out)
         if not root:
             raise NotImplementedError(
                 "non-root aggregating Transform on the mesh")

@@ -227,7 +227,7 @@ def _execute_plan_mesh(plan: PlanNode, db: Database):
         return None
 
 
-def _source_counters(src) -> dict:
+def source_counters(src) -> dict:
     """The cumulative chunk and resident counters of a scan source:
     shared unpruned sources accumulate them across statements, so a
     span reports a run's DELTA (pruned views are fresh per run)."""
@@ -236,16 +236,18 @@ def _source_counters(src) -> dict:
                       "resident_rows")}
 
 
-def _pruning_since(src, before: dict) -> dict:
+def pruning_since(src, before: dict) -> dict:
     """The pruning attrs of a scan span: the run's counter deltas,
     portions skipped by zone maps and portions in all."""
-    pruning = {k: v - before[k] for k, v in _source_counters(src).items()}
+    pruning = {k: v - before[k] for k, v in source_counters(src).items()}
     # resident-hit attribution: EXPLAIN ANALYZE shows how much of the
     # scan the HBM tier served without touching host bytes
     pruning["resident_portions"] = pruning.pop("resident_hits")
     pruning["portions_skipped"] = int(getattr(src, "portions_skipped", 0))
+    # a sharded table's source holds one portion stream a shard; the
+    # mesh walk scans a shard's stream by itself
     pruning["portions_total"] = pruning["portions_skipped"] + sum(
-        len(s.metas) for s in getattr(src, "subs", ()))
+        len(getattr(s, "metas", ())) for s in getattr(src, "subs", (src,)))
     return pruning
 
 
@@ -319,7 +321,7 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
     timed = [db.sources[t] for t in parts
              if bsp.recording and hasattr(db.sources[t], "attach_timer")]
     timer = StageTimer() if timed else None
-    before = [_source_counters(src) for src in timed]
+    before = [source_counters(src) for src in timed]
     for src in timed:
         src.attach_timer(timer)
     try:
@@ -331,7 +333,7 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
             if timer is not None:
                 pruning = collections.Counter()
                 for src, b in zip(timed, before):
-                    pruning.update(_pruning_since(src, b))
+                    pruning.update(pruning_since(src, b))
                 sp.set(**{f"stage_{k}": v
                           for k, v in timer.snapshot().items()},
                        **pruning)
@@ -488,7 +490,7 @@ def _scan_node(plan: TableScan, db: Database, sp, ex: ScanExecutor,
         # ones.
         with tracing.span("scan.prune"):
             src = _pruned_source(src, plan.program, db)
-        chunks0 = _source_counters(src)
+        chunks0 = source_counters(src)
         raw_stream = src.blocks(db.scan_block_rows, ex.read_cols)
         stream = raw_stream
         bc = db.block_cache
@@ -515,7 +517,7 @@ def _scan_node(plan: TableScan, db: Database, sp, ex: ScanExecutor,
             base_src.attach_timer(None)
     if want_stats:
         stages = timer.snapshot()
-        pruning = _pruning_since(src, chunks0)
+        pruning = pruning_since(src, chunks0)
         if sp.recording:
             sp.set(table=plan.table, rows=out.live_rows(),
                    compile_cache=("miss" if fresh else "hit"),
@@ -555,7 +557,7 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
     try:
         if site.node.program is not None:
             src = _pruned_source(src, site.node.program, db)
-        chunks0 = _source_counters(src)
+        chunks0 = source_counters(src)
         staging = (timer.stage("stage") if timer is not None
                    else contextlib.nullcontext())
         if isinstance(src, ColumnSource):
@@ -601,7 +603,7 @@ def _stage_fused_site(site, db: Database, timer, donate: bool):
     finally:
         if timer is not None and hasattr(base_src, "attach_timer"):
             base_src.attach_timer(None)
-    pruning = _pruning_since(src, chunks0)
+    pruning = pruning_since(src, chunks0)
     return blk, pruning
 
 
