@@ -190,6 +190,7 @@ def breakdown(path: str) -> dict:
     lo, hi = trace_reduce.window_of(loaded["spans"])
     lines = host_lines(profile)
     idle = {}
+    busy_of = []        # each device's busy intervals, merged
     for plane in profile.planes:
         if not trace_reduce.DEVICE_PLANE.match(plane.name):
             continue
@@ -199,8 +200,8 @@ def breakdown(path: str) -> dict:
             busy = [(float(e.start_ns), float(e.start_ns + e.duration_ns))
                     for e in ln.events
                     if e.start_ns + e.duration_ns > lo and e.start_ns < hi]
-            for k, v in idle_by_span(trace_reduce.union(busy), lines,
-                                     lo, hi).items():
+            busy_of.append(trace_reduce.union(busy))
+            for k, v in idle_by_span(busy_of[-1], lines, lo, hi).items():
                 idle[k] = idle.get(k, 0.0) + v
     scopes = device_time_by_scope(path, lo, hi)
 
@@ -216,8 +217,16 @@ def breakdown(path: str) -> dict:
         for _, _, n in evs:
             per_statement[n] = per_statement.get(n, 0) + 1
     statements = max(per_statement.get("ydb.query", 0), 1)
+    busy_s = [sum(e - s for s, e in b) / 1e9 for b in busy_of]
+    any_busy = trace_reduce.union([iv for b in busy_of for iv in b])
     return {
         "window_s": (hi - lo) / 1e9,
+        # do the devices of a mesh work side by side? each device's
+        # busy seconds, and the seconds in which any of them is busy:
+        # the sum of the first equals the second where they take turns
+        "device_busy_s": [round(v, 6) for v in busy_s],
+        "any_device_busy_s": round(
+            sum(e - s for s, e in any_busy) / 1e9, 6),
         "idle_s": total_idle / 1e9,
         "idle_named_leaf_share": named / total_idle if total_idle else None,
         "idle_by_span": top(idle, 20),

@@ -6,6 +6,7 @@ mesh walk, held to the benchmark's plain numpy references and to the
 same statements on one shard, with the spans, the ``mesh`` statement key
 and the per-device report that say where a statement's time went."""
 
+import collections
 import importlib.util
 import json
 import pathlib
@@ -14,17 +15,34 @@ import jax
 import numpy as np
 import pytest
 
+from ydb_tpu.analysis import syncsan
 from ydb_tpu.engine import resident as resident_mod
 from ydb_tpu.kqp.session import Cluster
+from ydb_tpu.obs import profile as profile_mod
 from ydb_tpu.obs.profile import MESH_KEY, STATEMENT_KEYS
+from ydb_tpu.parallel import mesh_exec
 from ydb_tpu.parallel.mesh import make_mesh
+from ydb_tpu.plan import execute_plan, to_host
+from ydb_tpu.plan.nodes import LookupJoin, TableScan, Transform
 from ydb_tpu.ssa import plan_fuse
+from ydb_tpu.ssa.ops import Agg, Op
+from ydb_tpu.ssa.program import (
+    AggSpec,
+    AssignStep,
+    Call,
+    Col,
+    GroupByStep,
+    Program,
+    ProjectStep,
+    lit,
+)
 
 BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
 SCALE_FACTOR = 0.01
 SEED = 2147483999       # the driver's seeds pass 2**31
 DEVICES = 4
 STATEMENTS = ("q1", "q6")
+BLOCK_ROWS = 4096       # ~15K lineitem rows a shard: 4 blocks, 3 folds
 
 
 def bench_module(relative: str):
@@ -83,6 +101,14 @@ def deployment():
         mp.undo()
 
 
+@pytest.fixture
+def several_blocks(monkeypatch):
+    """A shard's slice in several blocks, as at SF 3 (at SF 0.01 the
+    mesh walk's 2^20-row blocks hold a shard each): every shard folds."""
+    monkeypatch.setattr(mesh_exec, "DEFAULT_BLOCK_ROWS", BLOCK_ROWS)
+    return BLOCK_ROWS
+
+
 def answer(res) -> dict:
     return {name: np.asarray(res.cols[name][0])
             for name in res.schema.names}
@@ -99,8 +125,27 @@ def by_name(profile, name: str) -> list:
     return [sp for sp in profile.spans if sp["name"] == name]
 
 
+def under(profile, ancestors: set) -> list:
+    """The spans of ``profile`` beneath any span of ``ancestors``."""
+    by_id = {sp["span_id"]: sp for sp in profile.spans}
+
+    def beneath(sp) -> bool:
+        sp = by_id.get(sp["parent_id"])
+        while sp is not None:
+            if sp["span_id"] in ancestors:
+                return True
+            sp = by_id.get(sp["parent_id"])
+        return False
+
+    return [sp for sp in profile.spans if beneath(sp)]
+
+
+@pytest.mark.parametrize("blocks", ("one_block", "several_blocks"))
 @pytest.mark.parametrize("sid", STATEMENTS)
-def test_answers_equal_the_plain_reference_and_one_shard(deployment, sid):
+def test_answers_equal_the_plain_reference_and_one_shard(
+        deployment, sid, blocks, request):
+    if blocks == "several_blocks":
+        request.getfixturevalue(blocks)
     data, statements, sharded, one = deployment
     sql = statements[sid]["sql"]
     got = answer(sharded.session().execute(sql))
@@ -159,31 +204,231 @@ def test_the_mesh_key_holds_the_mesh_spans_apart_from_the_scans(
     assert programs["scan_partial"] in scans
     assert programs["mesh_place"] not in scans
     assert programs["mesh_step"] not in scans
-    # the wait for the shards' row counts, the wait for the collective
-    # step and the answer's copy out are the mesh's own
+    # the wait for the collective step and the answer's copy out are
+    # the mesh's own, and a statement's only ones: the shards' row
+    # counts are not waited for, their rows never leave the devices
     own = [sp for sp in p.spans
-           if sp["name"] in ("device.wait", "device.get")
-           and sp["parent_id"] == mesh["span_id"]]
-    assert {sp["name"] for sp in own} == {"device.wait", "device.get"}
-    assert len(own) >= 3
-    by_id = {sp["span_id"]: sp for sp in p.spans}
-
-    def under_a_scan(sp) -> bool:
-        while sp is not None:
-            if sp["span_id"] in scans:
-                return True
-            sp = by_id.get(sp["parent_id"])
-        return False
-
+           if sp["name"] in ("device.wait", "device.get")]
+    assert sorted(sp["name"] for sp in own) == ["device.get",
+                                                "device.wait"]
+    assert all(sp["parent_id"] == mesh["span_id"] for sp in own)
     # spans nest on one thread here: a leaf's seconds are its self time
+    beneath = {sp["span_id"] for sp in under(p, scans)}
     leaves = [sp for sp in p.spans
               if sp["name"] in ("dispatch", "device.wait", "device.get")]
-    own_s = sum(sp["seconds"] for sp in leaves if not under_a_scan(sp))
-    scan_s = sum(sp["seconds"] for sp in leaves if under_a_scan(sp))
+    own_s = sum(sp["seconds"] for sp in leaves
+                if sp["span_id"] not in beneath)
+    scan_s = sum(sp["seconds"] for sp in leaves
+                 if sp["span_id"] in beneath)
     # (each rounded to the microsecond in the profile)
     assert p.stages[MESH_KEY] == pytest.approx(own_s, abs=1e-4)
     assert p.stages["dispatch"] + p.stages["device_wait"] == \
         pytest.approx(scan_s, abs=1e-4)
+
+
+@pytest.mark.parametrize("sid", STATEMENTS)
+def test_every_shard_aggregates_its_blocks_on_its_own_device(
+        deployment, several_blocks, sid):
+    """The aggregate pushdown on the mesh walk: one program a resident
+    block, nothing waited for or fetched beneath a shard's scan, no
+    program built or fetched by a second run."""
+    _, statements, sharded, _ = deployment
+    sql = statements[sid]["sql"]
+    s = sharded.session()
+    s.execute(sql)
+    with syncsan.activate():
+        s.execute(sql)
+        p = s.last_profile
+        text = s.execute("EXPLAIN ANALYZE " + sql)
+        again = s.last_profile
+    (mesh,) = by_name(p, "mesh")
+    scans = by_name(p, "scan")
+    assert len(scans) == DEVICES
+    assert all(sp["attrs"]["agg_pushdown"] == 1 for sp in scans)
+    assert mesh["attrs"]["agg_pushdown"] == DEVICES
+    assert mesh["attrs"]["answered"] == 1 and not by_name(p, "plan.fuse")
+    assert p.agg_pushdown == DEVICES
+    beneath = under(p, {sp["span_id"] for sp in scans})
+    assert not [sp["name"] for sp in beneath
+                if sp["name"] in ("host.concat", "device.get",
+                                  "device.wait")]
+    assert not by_name(p, "host.concat") and not by_name(p, "transform")
+    # one program a block, the fold inside it; then the placement and
+    # the collective step
+    blocks = sum(-(-d["tables"]["lineitem"]["rows"] // several_blocks)
+                 for d in sharded.mesh_report())
+    assert blocks >= 3 * DEVICES        # every shard folds
+    partials = [sp for sp in by_name(p, "dispatch")
+                if sp["attrs"]["program"] == "scan_partial"]
+    assert len(partials) == blocks
+    assert len(by_name(p, "dispatch")) <= blocks + 2 * DEVICES + 2
+    # the CPU stand-in for compiles_inside_the_window
+    assert p.syncsan["compiles"] == 0 and again.syncsan["compiles"] == 0
+    assert p.compile_cache == "hit"
+    # EXPLAIN ANALYZE shows both: a line a shard's scan, one the mesh's
+    assert text.count("agg_pushdown=1") == DEVICES
+    (line,) = [ln for ln in text.splitlines()
+               if ln.startswith("  mesh: ")]
+    assert f"devices={DEVICES} answered=1" in line
+    assert line.endswith(f"agg_pushdown={DEVICES}")
+
+
+AGGS = ("sum(l_extendedprice) AS sv, count(l_tax) AS cn, count(*) AS c, "
+        "avg(l_discount) AS av, min(l_quantity) AS mn, "
+        "max(l_shipdate) AS mx")
+#: aggregating SELECTs over one table that take the pushdown on the
+#: mesh, beside the benchmark's Q1 and Q6: the shapes of
+#: tests/test_agg_pushdown.py's PUSHED over the deployment's tables
+PUSHED = {
+    "keyed": (f"SELECT l_returnflag, l_linestatus, {AGGS} FROM lineitem "
+              "WHERE l_quantity >= 10 GROUP BY l_returnflag, l_linestatus"),
+    "keyless": f"SELECT {AGGS} FROM lineitem WHERE l_quantity >= 10",
+    "nothing_selected_keyless": (f"SELECT {AGGS} FROM lineitem "
+                                 "WHERE l_quantity < 0"),
+    "nothing_selected_keyed": (f"SELECT l_shipmode, {AGGS} FROM lineitem "
+                               "WHERE l_quantity < 0 GROUP BY l_shipmode"),
+    "having": ("SELECT l_shipmode, l_linestatus, sum(l_tax) AS st "
+               "FROM lineitem GROUP BY l_shipmode, l_linestatus "
+               "HAVING sum(l_tax) > 100"),
+    "order_limit": ("SELECT l_shipmode, l_returnflag, count(*) AS c "
+                    "FROM lineitem GROUP BY l_shipmode, l_returnflag "
+                    "ORDER BY c DESC, l_shipmode, l_returnflag LIMIT 3"),
+    "string_min": ("SELECT l_returnflag, min(l_shipmode) AS lo, "
+                   "max(l_shipinstruct) AS hi FROM lineitem "
+                   "GROUP BY l_returnflag ORDER BY lo, l_returnflag"),
+    "distinct_dense": "SELECT DISTINCT o_orderstatus, o_orderpriority "
+                      "FROM orders",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PUSHED))
+def test_pushed_down_shapes_answer_what_one_shard_answers(
+        deployment, several_blocks, case):
+    _, _, sharded, one = deployment
+    s = sharded.session()
+    got = s.execute(PUSHED[case])
+    p = s.last_profile
+    (mesh,) = by_name(p, "mesh")
+    assert mesh["attrs"]["answered"] == 1
+    assert mesh["attrs"]["agg_pushdown"] == DEVICES
+    assert p.agg_pushdown == DEVICES and not by_name(p, "host.concat")
+    want = one.session().execute(PUSHED[case])
+    # names, types and scales (the mesh's slot-aligned states make
+    # every column nullable in the schema; the masks are compared below)
+    assert [(f.name, f.type) for f in got.schema.fields] == \
+        [(f.name, f.type) for f in want.schema.fields]
+    ordered = "ORDER BY" in PUSHED[case]
+    got_cols = answer(got) if ordered else _rows_in_order(got)
+    want_cols = answer(want) if ordered else _rows_in_order(want)
+    for name in want_cols:
+        assert got_cols[name].dtype == want_cols[name].dtype
+        assert got_cols[name].tobytes() == want_cols[name].tobytes(), name
+    for name in got.schema.names:       # the validity masks too
+        assert np.array_equal(np.sort(got.cols[name][1]),
+                              np.sort(want.cols[name][1])), name
+    rows = len(next(iter(want_cols.values())))
+    assert rows == {"nothing_selected_keyless": 1,
+                    "nothing_selected_keyed": 0}.get(case, rows)
+    if case == "string_min":
+        # the aggregate's output decodes through its input's dictionary
+        assert got.strings("lo") == want.strings("lo")
+        assert got.strings("lo") == sorted(got.strings("lo"))
+
+
+def _sum_by_flag(column: str) -> Program:
+    return Program((GroupByStep(
+        ("l_returnflag",), (AggSpec(Agg.SUM, column, "x"),
+                            AggSpec(Agg.COUNT_ALL, None, "c"))),))
+
+
+def _scan_without_a_program():
+    return Transform(
+        TableScan("lineitem", None, ("l_quantity", "l_returnflag")),
+        _sum_by_flag("l_quantity"))
+
+
+def _scan_read_twice():
+    """One TableScan node read by both sides of a join (every order
+    matches itself), under an aggregating root."""
+    scan = TableScan("orders", Program((
+        ProjectStep(("o_orderkey", "o_totalprice", "o_shippriority")),)))
+    return Transform(
+        LookupJoin(scan, scan, ("o_orderkey",), ("o_orderkey",),
+                   kind="semi"),
+        Program((GroupByStep(
+            (), (AggSpec(Agg.SUM, "o_totalprice", "x"),
+                 AggSpec(Agg.COUNT_ALL, None, "c"))),)))
+
+
+def _elementwise_below_the_root():
+    """An elementwise Transform that stays sharded (``mesh_xform``)
+    between the scan and the aggregating root."""
+    return Transform(
+        Transform(
+            TableScan("lineitem", Program((ProjectStep(
+                ("l_quantity", "l_returnflag")),))),
+            Program((AssignStep("twice", Call(Op.MUL, Col("l_quantity"),
+                                              lit(2))),))),
+        _sum_by_flag("twice"))
+
+
+#: the shapes that keep the mesh walk's old path (the shards' rows are
+#: scanned out, placed over the mesh and aggregated in the collective
+#: step), as tests/test_agg_pushdown.py's KEPT are the one-chip walk's:
+#: SQL text, or a hand-made plan where the planner makes no such shape
+KEPT = {
+    "sort_derived_layout": (
+        "SELECT l_orderkey, sum(l_quantity) AS q, count(*) AS c "
+        "FROM lineitem GROUP BY l_orderkey"),
+    "scan_read_twice": _scan_read_twice,
+    "scan_without_a_program": _scan_without_a_program,
+    "aggregate_over_a_join": (
+        "SELECT o_orderpriority, count(*) AS c, sum(l_quantity) AS q "
+        "FROM lineitem JOIN orders ON l_orderkey = o_orderkey "
+        "WHERE o_orderdate < date '1995-01-01' GROUP BY o_orderpriority"),
+    "elementwise_transform": _elementwise_below_the_root,
+}
+
+
+def _run(cluster, source):
+    if callable(source):
+        with profile_mod.profiled() as held:
+            out = to_host(execute_plan(source(), cluster.snapshot_db()))
+        return out, held.profile
+    s = cluster.session()
+    return s.execute(source), s.last_profile
+
+
+def _rows_in_order(res) -> dict:
+    """The answer's columns, its rows in the order of their values (a
+    group-by without ORDER BY promises no order)."""
+    cols = answer(res)
+    order = np.lexsort(tuple(cols[n] for n in reversed(list(cols))))
+    return {n: v[order] for n, v in cols.items()}
+
+
+@pytest.mark.parametrize("case", sorted(KEPT))
+def test_other_shapes_keep_the_old_path_on_the_mesh(deployment, case):
+    _, _, sharded, one = deployment
+    got, p = _run(sharded, KEPT[case])
+    (mesh,) = by_name(p, "mesh")
+    assert mesh["attrs"]["answered"] == 1 and not by_name(p, "plan.fuse")
+    assert p.agg_pushdown == 0
+    assert not [sp for sp in p.spans if "agg_pushdown" in sp["attrs"]]
+    scans = collections.Counter(sp["attrs"]["table"]
+                                for sp in by_name(p, "scan"))
+    # one scan a shard of each table; the memo ran the shared one once
+    assert set(scans.values()) == {DEVICES}
+    # the shards' row counts are waited for, as before
+    assert [sp for sp in by_name(p, "device.wait")
+            if sp["parent_id"] == mesh["span_id"]]
+    want, _ = _run(one, KEPT[case])
+    got, want = _rows_in_order(got), _rows_in_order(want)
+    assert list(got) == list(want)
+    for name in want:
+        assert got[name].dtype == want[name].dtype
+        assert got[name].tobytes() == want[name].tobytes(), name
+    assert len(next(iter(got.values()))) > 0
 
 
 @pytest.mark.parametrize("sid", STATEMENTS)

@@ -129,12 +129,13 @@ def test_mesh_scan_from_portion_store(tmp_path, data):
     prog = tpch.q1_program()
     scan = MeshScan(prog, tpch.LINEITEM_SCHEMA, data.dicts, mesh=mesh)
     assert scan.partial.group_layout[0] == "dense_slots"
-    sources = [
-        PortionStreamSource(sh, sh.visible_portions(),
-                            columns=scan.read_cols)
-        for sh in shards
-    ]
-    res = scan.execute_sources(sources, block_rows=1 << 12)
+
+    def fresh_sources(of):
+        return [PortionStreamSource(sh, sh.visible_portions(),
+                                    columns=of.read_cols)
+                for sh in shards]
+
+    res = scan.execute_sources(fresh_sources(scan), block_rows=1 << 12)
 
     table = OracleTable(
         {k: (v, np.ones(len(v), dtype=bool)) for k, v in li.items()},
@@ -147,7 +148,36 @@ def test_mesh_scan_from_portion_store(tmp_path, data):
             np.asarray(ora.cols[name][0], dtype=np.float64), rtol=1e-9,
             err_msg=name)
 
-    # compact layout (unbounded keys) takes the gather path
+    # past INFLIGHT_BLOCKS programs in a device's queue the driver
+    # waits for the oldest (each pins its input block): the same answer
+    from ydb_tpu.obs import profile as profile_mod
+    from ydb_tpu.parallel import dist
+
+    def waits(limit):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(dist, "INFLIGHT_BLOCKS", limit)
+            with profile_mod.profiled() as held:
+                out = scan.execute_sources(fresh_sources(scan),
+                                           block_rows=1 << 9)
+        for name in res.cols:
+            np.testing.assert_array_equal(out.cols[name][0],
+                                          res.cols[name][0])
+        spans = held.profile.spans
+        blocks = sum(sp["name"] == "dispatch"
+                     and sp["attrs"].get("program") == "scan_partial"
+                     for sp in spans)
+        # the driver's own waits sit right under a shard's scan span (a
+        # new shape's one-off timing sync sits under its dispatch)
+        scans = {sp["span_id"] for sp in spans if sp["name"] == "scan"}
+        return blocks, sum(sp["name"] == "device.wait"
+                           and sp["parent_id"] in scans for sp in spans)
+
+    blocks, waited = waits(1)
+    assert blocks > N_DEV and waited == blocks - N_DEV
+    assert waits(blocks) == (blocks, 0)
+
+    # a compact layout (unbounded keys) has no slot-aligned state to
+    # fold: the streaming driver refuses it, MeshScan.execute takes it
     from ydb_tpu.ssa import Agg, AggSpec, GroupByStep, Program, SortStep
 
     prog2 = Program((
@@ -159,20 +189,9 @@ def test_mesh_scan_from_portion_store(tmp_path, data):
     ))
     scan2 = MeshScan(prog2, tpch.LINEITEM_SCHEMA, data.dicts, mesh=mesh)
     assert scan2.partial.group_layout[0] == "compact"
-    sources2 = [
-        PortionStreamSource(sh, sh.visible_portions(),
-                            columns=scan2.read_cols)
-        for sh in shards
-    ]
-    res2 = scan2.execute_sources(sources2, block_rows=1 << 12)
-    ora2 = run_oracle(prog2, table, data.dicts)
-    assert res2.num_rows == ora2.num_rows
-    np.testing.assert_array_equal(
-        np.asarray(res2.cols["l_orderkey"][0]),
-        np.asarray(ora2.cols["l_orderkey"][0]))
-    np.testing.assert_array_equal(
-        np.asarray(res2.cols["total"][0]),
-        np.asarray(ora2.cols["total"][0]))
+    assert not scan2.folds_partials
+    with pytest.raises(ValueError, match="compact"):
+        scan2.execute_sources(fresh_sources(scan2), block_rows=1 << 12)
 
 
 def test_mesh_from_sql_session():

@@ -197,3 +197,54 @@ def test_scan_partial_fits_beside_a_resident_table(
     assert not moved, (query, moved[:3])
     if gemm and query.startswith("q1"):
         assert temp > 1e9, "the GEMM tier's hit matrix went somewhere?"
+
+
+@pytest.mark.parametrize("which", ("first_block", "later_block"))
+@pytest.mark.parametrize("query", ("q1.sql", "q6.sql"))
+def test_mesh_walk_block_program_moves_no_rows(
+        query, which, one_chip, no_persistent_cache, monkeypatch):
+    """What the mesh walk's aggregate pushdown enqueues a resident
+    block on the block's own chip (parallel/dist.py, MeshScan): the
+    slot-aligned partial of the benchmark's statement, and for every
+    block after a shard's first the fold into the shard's state in the
+    same program. As on the one-chip walk the block is aggregated under
+    its filter mask: nothing sorts, gathers or scatters over its rows,
+    and the temporaries fit beside a resident slice."""
+    from ydb_tpu.parallel.dist import MeshScan
+    from ydb_tpu.ssa import kernels
+
+    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: False)
+    cap = 1 << 12
+    data = tpch.TpchData(sf=0.001, seed=5)
+    src = ColumnSource(columns=data.tables["lineitem"],
+                       schema=tpch.LINEITEM_SCHEMA, dicts=data.dicts)
+    program, aliases = _pushed_down(query, data)
+    scan = MeshScan(program, tpch.LINEITEM_SCHEMA, data.dicts,
+                    dict_aliases=aliases)
+    assert scan.folds_partials
+    block = next(iter(src.blocks(cap, scan.read_cols)))
+    aux = dict(scan.partial.aux)
+    state = jax.eval_shape(scan._first_jit, block, aux)
+    rows = ShardConfig().scan_block_rows
+
+    def described(x):
+        x = np.asarray(x) if not hasattr(x, "shape") else x
+        shape = tuple(rows if d == cap else d for d in x.shape)
+        return _shape(shape, x.dtype, one_chip)
+
+    if which == "first_block":
+        lowered = scan._first_jit.lower(
+            *jax.tree_util.tree_map(described, (block, aux)))
+    else:
+        lowered = scan._fold_jit.lower(
+            *jax.tree_util.tree_map(described, (state, block, aux)))
+    compiled = lowered.compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, which, temp)
+    moved = [ln.strip()[:120] for ln in compiled.as_text().splitlines()
+             if re.search(r"\b(sort|gather|scatter)\(", ln)
+             and f"[{rows}]" in ln]
+    assert not moved, (query, which, moved[:3])
+    # the state the next block's program takes is a handful of slots
+    assert all(leaf.shape[0] == 1 and leaf.size <= 1024
+               for leaf in jax.tree_util.tree_leaves(state))

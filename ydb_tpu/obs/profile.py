@@ -435,12 +435,12 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
             bits.append(f"{pair}={coeff:.4f}")
         lines.append("occupancy: " + " ".join(bits))
     for s in profile.spans:
-        if s["name"] not in SCAN_SPANS:
+        if s["name"] not in SCAN_SPANS and s["name"] != MESH_KEY:
             continue
         a = s["attrs"]
         bits = [f"seconds={s['seconds']:.6f}"]
-        for k in ("table", "shard", "device", "rows", "compile_cache",
-                  "agg_pushdown"):
+        for k in ("table", "shard", "device", "devices", "answered",
+                  "rows", "compile_cache", "agg_pushdown"):
             if k in a:
                 bits.append(f"{k}={a[k]}")
         lines.append(f"  {s['name']}: " + " ".join(bits))

@@ -20,6 +20,8 @@ collectives, not a message exchange.
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 
 import numpy as np
@@ -31,10 +33,18 @@ from ydb_tpu import dtypes
 from ydb_tpu.analysis import memsan
 from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.blocks.dictionary import DictionarySet
+from ydb_tpu.chaos import deadline as statement_deadline
 from ydb_tpu.engine.oracle import OracleTable
-from ydb_tpu.engine.scan import ColumnSource, required_columns
+from ydb_tpu.engine.scan import (
+    DEFAULT_BLOCK_ROWS,
+    ColumnSource,
+    _pulled,
+    required_columns,
+)
 from ydb_tpu.obs import tracing
+from ydb_tpu.obs.probes import StageTimer
 from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
+from ydb_tpu.plan.executor import pruning_since, source_counters
 from ydb_tpu.ssa import twophase
 from ydb_tpu.ssa.compiler import compile_program
 from ydb_tpu.ssa.ops import Agg
@@ -44,6 +54,12 @@ from ydb_tpu.ssa.program import Program
 #: named scope of the collectives that merge the shards' partial states
 #: (psum / pmin / pmax, or all_gather): what only a mesh trace shows
 MERGE_SCOPE = "ydb.mesh_merge"
+#: named scope of the fold of a block's partial state into its shard's
+#: running state, inside the block's program (MeshScan.run_sources)
+FOLD_SCOPE = "ydb.mesh_fold"
+#: programs a shard's device may have queued before the streaming
+#: driver waits for the oldest (each pins its input block)
+INFLIGHT_BLOCKS = 8
 
 
 def stack_blocks(blocks: list[TableBlock]) -> TableBlock:
@@ -84,6 +100,17 @@ def _fit_with_device_axis(block: TableBlock, capacity: int) -> TableBlock:
     return TableBlock(cols, jnp.asarray(block.length)[None], block.schema)
 
 
+def _assemble(pieces: list[TableBlock], mesh) -> TableBlock:
+    """Single-device pieces, each with a leading size-1 device axis and
+    on ITS mesh device, as one block sharded over the mesh's shard
+    axis: no copy, no program."""
+    sharding = NamedSharding(mesh, P(SHARD_AXIS))
+    return jax.tree_util.tree_map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (len(xs),) + xs[0].shape[1:], sharding, list(xs)),
+        *pieces)
+
+
 def place_shards(blocks: list[TableBlock], mesh,
                  capacity: int | None = None,
                  owner: str = "mesh_place") -> TableBlock:
@@ -96,7 +123,6 @@ def place_shards(blocks: list[TableBlock], mesh,
     already live on different devices (the resident tier binds each
     shard's columns to the device that scans it): assemble the global
     arrays from the single-device pieces instead."""
-    sharding = NamedSharding(mesh, P(SHARD_AXIS))
     devices = [row[0] for row in mesh.devices]
     assert len(blocks) == len(devices) == mesh.devices.size, \
         (len(blocks), mesh.devices.shape)
@@ -104,15 +130,72 @@ def place_shards(blocks: list[TableBlock], mesh,
         capacity = blocks[0].capacity
     with tracing.span("dispatch", program="mesh_place"), \
             memsan.seam("staging"):
-        pieces = [_fit_with_device_axis(jax.device_put(b, d), capacity)
-                  for b, d in zip(blocks, devices)]
-        out = jax.tree_util.tree_map(
-            lambda *xs: jax.make_array_from_single_device_arrays(
-                (len(xs),) + xs[0].shape[1:], sharding, list(xs)),
-            *pieces)
+        out = _assemble(
+            [_fit_with_device_axis(jax.device_put(b, d), capacity)
+             for b, d in zip(blocks, devices)], mesh)
     if memsan.armed():
         memsan.charge(memsan.nbytes_of(out), "staging", owner=owner)
     return out
+
+
+def _on_device(block: TableBlock, device) -> TableBlock:
+    """``block`` with its columns on ``device``: as it is where they
+    already live there (a resident slice's block; its host-made row
+    count follows the committed columns into the program), else put
+    there. The check is a set comparison; ``device_put`` of a block
+    that is already in place walks every leaf, and costs the host as
+    much as enqueueing the block's program."""
+    col = next(iter(block.columns.values()), None)
+    if col is not None and col.data.devices() == {device}:
+        return block
+    with memsan.seam("staging"):
+        block = jax.device_put(block, device)
+    if memsan.armed():
+        memsan.charge(memsan.nbytes_of(block), "staging",
+                      owner="mesh_scan")
+    return block
+
+
+def _set_timer(sub, timer) -> None:
+    """Bind a StageTimer (or None) to one device's scan source: a
+    shard's portion stream, or a chain of them."""
+    for s in getattr(sub, "subs", (sub,)):
+        if hasattr(s, "timer"):
+            s.timer = timer
+
+
+@contextlib.contextmanager
+def shard_scan_span(sub, table: str | None, device: int, fresh: bool,
+                    agg_pushdown: bool = False):
+    """One shard's scan on the statement's thread, under a ``scan``
+    span as the walk has one a TableScan; yields the shard's
+    StageTimer, or None. Under a recording span the shard's source
+    charges the timer (``stage_*``) and the span carries the source's
+    pruning counters, as the walk's scan span does; ``agg_pushdown=1``
+    where the scan aggregates its blocks under their filter masks (the
+    walk's attr)."""
+    with tracing.span("scan") as sp:
+        if agg_pushdown:
+            sp.set(agg_pushdown=1)
+        timer = StageTimer() if sp.recording else None
+        before = source_counters(sub)
+        _set_timer(sub, timer)
+        try:
+            yield timer
+        finally:
+            _set_timer(sub, None)
+        if timer is not None:
+            sp.set(table=table, device=device,
+                   compile_cache=("miss" if fresh else "hit"),
+                   **{f"stage_{k}": v
+                      for k, v in timer.snapshot().items()},
+                   **pruning_since(sub, before))
+
+
+def _computing(timer):
+    """The ``compute`` stage of a shard's StageTimer, where it has one."""
+    return (timer.stage("compute") if timer is not None
+            else contextlib.nullcontext())
 
 
 def _local(stacked: TableBlock) -> TableBlock:
@@ -182,27 +265,6 @@ def _merge_slots(
             red_any=lambda v: jax.lax.pmax(v, SHARD_AXIS),
         )
     return TableBlock(cols, block.length, block.schema)
-
-
-def _live_prefix_host(block: TableBlock):
-    """(host arrays dict, host validity dict, schema) of the live rows."""
-    n = int(block.length)
-    arrays = {m: np.asarray(c.data)[:n] for m, c in block.columns.items()}
-    valid = {m: np.asarray(c.validity)[:n]
-             for m, c in block.columns.items()}
-    return arrays, valid, block.schema
-
-
-def _concat_states(parts: list) -> TableBlock:
-    """Concatenate host live-prefix states (from _live_prefix_host)."""
-    sch = parts[0][2]
-    arrays = {
-        n: np.concatenate([p[0][n] for p in parts]) for n in sch.names
-    }
-    validity = {
-        n: np.concatenate([p[1][n] for p in parts]) for n in sch.names
-    }
-    return TableBlock.from_numpy(arrays, sch, validity)
 
 
 def _merge_pair(a: TableBlock, b: TableBlock, merge_kinds, rank_tables):
@@ -384,11 +446,33 @@ class MeshScan:
                 check_vma=False,
             )
         )
-        self._partial_jit = jax.jit(
-            lambda blk: self.partial.run(blk, paux))
-        self._pair_jit = jax.jit(
-            lambda a, b: _merge_pair(a, b, self._merge_kinds,
-                                     self._rank_tables))
+        # the streaming driver's programs, each on the device of the
+        # block it is handed (aux rides as an argument, placed once a
+        # device: _aux_on). A shard's state keeps the size-1 device
+        # axis between them, so the mesh assembles the states as they
+        # are. The first block's program is the partial alone; every
+        # later block's folds its partial state into the shard's
+        # running one, so a shard costs ONE program a block.
+        self._devices = [row[0] for row in self.mesh.devices]
+        self._aux_by_device: dict = {}
+        partial_run = self.partial.run
+
+        def fold(state, blk, aux):
+            part = partial_run(blk, aux)
+            with jax.named_scope(FOLD_SCOPE):
+                return _relocal(_merge_pair(
+                    _local(state), part, merge_kinds, rank_tables))
+
+        self._first_jit = jax.jit(
+            lambda blk, aux: _relocal(partial_run(blk, aux)))
+        self._fold_jit = jax.jit(fold)
+
+    @property
+    def folds_partials(self) -> bool:
+        """The partial states are slot-aligned (a keyless or dense
+        group layout): a shard's blocks fold into one bounded state on
+        its device and the states merge elementwise over the mesh."""
+        return self._use_slots
 
     # ---- host-side drivers ----
 
@@ -404,46 +488,106 @@ class MeshScan:
         with tracing.span("dispatch", program="mesh_step"):
             return self._step(stacked)
 
-    def execute_sources(self, sources, block_rows: int = 1 << 20
-                        ) -> OracleTable:
+    def run_sources(self, sources, block_rows: int = DEFAULT_BLOCK_ROWS,
+                    table: str | None = None,
+                    fresh: bool = False) -> TableBlock:
         """Streaming SPMD scan over per-shard block-stream sources (the
-        portion store feeding the mesh — VERDICT r4 item 4).
+        portion store, or the resident tier's per-device slices,
+        feeding the mesh): the driver of the mesh walk's aggregate
+        pushdown (parallel/mesh_exec.py) and of ``execute_sources``.
+        Slot-aligned states only (``folds_partials``).
 
-        Each shard's stream (e.g. a PortionStreamSource over its on-disk
-        portions) folds block-by-block into ONE bounded partial state on
-        its device (slot layouts: pairwise merge; compact layouts:
-        concatenated partial rows), then a single collective step merges
-        states across the mesh and finalizes. Host memory per shard stays
-        bounded by the stream's working set — out-of-core and multi-chip
-        compose."""
+        Each shard's stream folds block by block into ONE bounded
+        partial state on its device, under a ``scan`` span a shard
+        (``table`` and ``fresh`` are that span's ``table`` and
+        ``compile_cache``): every block is aggregated under its filter
+        mask by one program that also folds. Nothing here waits for a
+        device: the host enqueues shard after shard while each device
+        works through its own queue. Then one collective step merges
+        the states across the mesh and finalizes. Host memory per shard
+        stays bounded by the stream's working set — out-of-core and
+        multi-chip compose."""
+        if not self._use_slots:
+            raise ValueError(
+                "run_sources folds slot-aligned partial states; a "
+                f"{self.partial.group_layout[0]} layout has none")
         n_shards = self.mesh.shape[SHARD_AXIS]
         if len(sources) != n_shards:
             raise ValueError(
                 f"{len(sources)} sources for a {n_shards}-shard mesh")
-        layout = self.partial.group_layout[0]
-        foldable = layout in ("keyless", "dense_slots")
         states = []
-        for sub in sources:
-            st = None
-            parts = []
-            for blk in sub.blocks(block_rows, self.read_cols):
-                part = self._partial_jit(blk)
-                if not foldable:
-                    # keep only the live prefix ON HOST: holding every
-                    # full-capacity device block would grow device memory
-                    # linearly with the stream
-                    parts.append(_live_prefix_host(part))
-                elif st is None:
-                    st = part
-                else:
-                    st = self._pair_jit(st, part)
-            states.append(st if foldable else _concat_states(parts))
-        # compact (non-foldable) states vary in size shard-to-shard:
-        # pad to the common capacity
-        out = self._merge_final_step(place_shards(
-            states, self.mesh,
-            capacity=max(s.capacity for s in states)))
-        return OracleTable.from_block(out)
+        for d, sub in enumerate(sources):
+            with shard_scan_span(sub, table, d, fresh,
+                                 agg_pushdown=True) as timer:
+                states.append(self._fold_shard(
+                    sub, self._devices[d], block_rows, timer))
+        # the states are where they belong, device axis and all
+        with tracing.span("dispatch", program="mesh_place"):
+            placed = _assemble(states, self.mesh)
+        with tracing.span("dispatch", program="mesh_step"):
+            return self._merge_final_step(placed)
+
+    def _aux_on(self, device) -> dict:
+        """The partial program's aux tables on ``device`` (put there
+        once: an argument that lives elsewhere is copied over on every
+        dispatch)."""
+        aux = self._aux_by_device.get(device)
+        if aux is None:
+            with memsan.seam("staging"):
+                # staged once a device and kept, not once a dispatch
+                # ydb-lint: disable=M007
+                aux = jax.device_put(dict(self.partial.aux), device)
+            if memsan.armed():
+                memsan.charge(memsan.nbytes_of(aux), "staging",
+                              owner="mesh_scan")
+            self._aux_by_device[device] = aux
+        return aux
+
+    def _shard_blocks(self, sub, block_rows: int):
+        """``sub``'s blocks of the read columns, each pulled under a
+        ``scan.pull`` span; an empty shard (a portion stream yields
+        nothing) gives one empty block, so it still has a state."""
+        empty = True
+        for blk in _pulled(sub.blocks(block_rows, self.read_cols)):
+            empty = False
+            # block-boundary cancellation point, as in ScanExecutor
+            statement_deadline.check_current("scan")
+            yield blk
+        if empty:
+            yield TableBlock.from_numpy(
+                {f.name: np.empty(0, dtype=f.type.physical)
+                 for f in self._in_schema.fields},
+                self._in_schema, capacity=1)
+
+    def _fold_shard(self, sub, device, block_rows: int,
+                    timer) -> TableBlock:
+        """One shard's blocks folded into one slot-aligned state (with
+        its device axis) on ``device``: one program a block, enqueued
+        and not waited for. Back-pressure is the shard's own: past
+        ``INFLIGHT_BLOCKS`` programs in this device's queue the loop
+        waits for the oldest (each pins its input block; a resident
+        shard of a handful of blocks never gets there)."""
+        aux = self._aux_on(device)
+        state = None
+        window: collections.deque = collections.deque()
+        for blk in self._shard_blocks(sub, block_rows):
+            with _computing(timer), tracing.span(
+                    "dispatch", program="scan_partial"):
+                blk = _on_device(blk, device)
+                state = (self._first_jit(blk, aux) if state is None
+                         else self._fold_jit(state, blk, aux))
+            window.append(state)
+            if len(window) > INFLIGHT_BLOCKS:
+                with tracing.span("device.wait"):
+                    # ydb-lint: disable=H001
+                    jax.block_until_ready(window.popleft())
+        return state
+
+    def execute_sources(self, sources, block_rows: int = DEFAULT_BLOCK_ROWS
+                        ) -> OracleTable:
+        """``run_sources``, the answer copied out to the host."""
+        return OracleTable.from_block(
+            self.run_sources(sources, block_rows))
 
     def execute(self, source: ColumnSource) -> OracleTable:
         """Partition a host table across the mesh and run one SPMD step."""

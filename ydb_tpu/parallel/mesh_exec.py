@@ -47,9 +47,9 @@ from ydb_tpu.parallel.dist import (
     _local,
     _relocal,
     place_shards,
+    shard_scan_span,
 )
 from ydb_tpu.obs import timeline, tracing
-from ydb_tpu.obs.probes import StageTimer
 from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
 from ydb_tpu.parallel.shuffle import (
     exchange_bytes_per_device,
@@ -57,7 +57,7 @@ from ydb_tpu.parallel.shuffle import (
     repartition,
     size_buckets,
 )
-from ydb_tpu.plan.executor import pruning_since, source_counters
+from ydb_tpu.plan.executor import _Memo, _pushdown_scan
 from ydb_tpu.plan.nodes import ExpandJoin, LookupJoin, TableScan, Transform
 from ydb_tpu.ssa import join as join_kernels
 from ydb_tpu.ssa import kernels
@@ -147,14 +147,6 @@ def device_partitions(sources: list, n: int, schema, dicts) -> list:
     return out
 
 
-def _set_timer(sub, timer) -> None:
-    """Bind a StageTimer (or None) to one device's scan source: a
-    shard's portion stream, or a chain of them."""
-    for s in getattr(sub, "subs", (sub,)):
-        if hasattr(s, "timer"):
-            s.timer = timer
-
-
 def _chaos_dispatch(n_devices: int) -> None:
     """``mesh.dispatch`` injection site: 'device_lost' raises
     :class:`chaos.DeviceLostError`, which the plan executor's fallback
@@ -180,7 +172,7 @@ class MeshPlanExecutor:
 
     def execute(self, plan) -> OracleTable:
         _chaos_dispatch(self.n)
-        out = self._exec(plan, {}, root=True)
+        out = self._exec(plan, _Memo(plan), root=True)
         return OracleTable.from_block(out)
 
     # ---- whole-plan sharded fusion (parallel/mesh_fuse) ----
@@ -293,65 +285,51 @@ class MeshPlanExecutor:
         memo[id(plan)] = out
         return out
 
-    def _scan(self, plan: TableScan) -> TableBlock:
-        """Per-shard scan: pushdown program runs in each shard's scan
-        executor; per-shard results pad-stack onto the mesh."""
-        subs = self.db.sources[plan.table]
+    def _shards_of(self, table: str) -> list:
+        subs = self.db.sources[table]
         if len(subs) != self.n:
             # more sources than devices would silently drop every block
             # past the first per device (sharded leading axis)
             raise ValueError(
-                f"table {plan.table} has {len(subs)} shards for a"
+                f"table {table} has {len(subs)} shards for a"
                 f" {self.n}-device mesh (need exactly one per device)")
+        return subs
+
+    def _scan(self, plan: TableScan) -> TableBlock:
+        """Per-shard scan: pushdown program runs in each shard's scan
+        executor; per-shard results pad-stack onto the mesh."""
+        subs = self._shards_of(plan.table)
+        ex, fresh = None, False
+        if plan.program is not None:
+            # one compiled executor per (table, program), shared by the
+            # shards and kept across statements like every other step
+            # here: a fresh ScanExecutor is a fresh jit, i.e. an XLA
+            # compile per shard per statement
+            key = ("scan", plan.table, plan.program)
+            ex = self._jit_cache.get(key)
+            fresh = ex is None
+            if fresh:
+                ex = ScanExecutor(
+                    plan.program, subs[0], block_rows=DEFAULT_BLOCK_ROWS,
+                    key_spaces=self.db.key_spaces).detach()
+                self._jit_cache[key] = ex
         locals_: list[TableBlock] = []
         for d, sub in enumerate(subs):
-            # one "scan" span a shard, as the walk has one a TableScan:
-            # the shards are scanned one after another on this thread
-            with tracing.span("scan") as sp:
-                locals_.append(self._scan_shard(plan, sub, d, sp))
+            # the shards are scanned one after another on this thread,
+            # each waited for: its rows are fetched and concatenated
+            with shard_scan_span(sub, plan.table, d, fresh) as timer:
+                if ex is None:
+                    names = plan.columns or sub.schema.names
+                    blks = list(sub.blocks(DEFAULT_BLOCK_ROWS, names))
+                    blk = blks[0] if len(blks) == 1 else _concat(blks)
+                else:
+                    blk = ex.run_stream(
+                        sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols),
+                        timer=timer)
+                locals_.append(blk)
         with tracing.span("device.wait"):
             cap = _round_up(max(int(b.length) for b in locals_))
         return place_shards(locals_, self.mesh, capacity=cap)
-
-    def _scan_shard(self, plan: TableScan, sub, device: int,
-                    sp) -> TableBlock:
-        """One shard's scan on this thread. Under a recording span its
-        source charges a StageTimer (``stage_*``) and the span carries
-        the source's pruning counters, as the walk's scan span does."""
-        timer = StageTimer() if sp.recording else None
-        before = source_counters(sub)
-        fresh = False
-        _set_timer(sub, timer)
-        try:
-            if plan.program is None:
-                names = plan.columns or sub.schema.names
-                blks = list(sub.blocks(DEFAULT_BLOCK_ROWS, names))
-                blk = blks[0] if len(blks) == 1 else _concat(blks)
-            else:
-                # one compiled executor per (table, program), shared by
-                # the shards and kept across statements like every
-                # other step here: a fresh ScanExecutor is a fresh jit,
-                # i.e. an XLA compile per shard per statement
-                key = ("scan", plan.table, plan.program)
-                ex = self._jit_cache.get(key)
-                fresh = ex is None
-                if fresh:
-                    ex = ScanExecutor(
-                        plan.program, sub, block_rows=DEFAULT_BLOCK_ROWS,
-                        key_spaces=self.db.key_spaces).detach()
-                    self._jit_cache[key] = ex
-                blk = ex.run_stream(
-                    sub.blocks(DEFAULT_BLOCK_ROWS, ex.read_cols),
-                    timer=timer)
-        finally:
-            _set_timer(sub, None)
-        if timer is not None:
-            sp.set(table=plan.table, device=device,
-                   compile_cache=("miss" if fresh else "hit"),
-                   **{f"stage_{k}": v
-                      for k, v in timer.snapshot().items()},
-                   **pruning_since(sub, before))
-        return blk
 
     def _join(self, plan, memo, expand: bool) -> TableBlock:
         probe = self._exec(plan.probe, memo)
@@ -487,15 +465,56 @@ class MeshPlanExecutor:
 
     # -- final transform (two-phase over the mesh) --
 
+    def _scan_aggregated(self, plan: Transform,
+                         shared: set) -> TableBlock | None:
+        """The walk's aggregate pushdown (plan/executor.py
+        ``_scan_aggregated``) over the mesh: ``Transform(TableScan)``
+        as ONE streaming scan a shard, each on its own device, that
+        aggregates every block under its filter mask and folds the
+        partial states into one; one collective step merges the shards'
+        states and finalizes (``MeshScan.run_sources``). No block is
+        compacted, nothing is fetched before the answer, no program is
+        shaped by a selected row count, and the shards run side by
+        side. None where the shape does not allow it (the caller scans
+        the shards' rows out and aggregates them in the collective
+        step): ``_pushdown_scan``'s conditions, or a group layout whose
+        states are not slot-aligned (sort-derived). The MeshScan that
+        says so stays cached, so the next run of the statement asks a
+        dict."""
+        pushed = _pushdown_scan(plan, shared)
+        if pushed is None:
+            return None
+        subs = self._shards_of(pushed.table)
+        key = ("pushdown", pushed.table, pushed.program, plan.dict_aliases)
+        scan = self._jit_cache.get(key)
+        fresh = scan is None
+        if fresh:
+            scan = MeshScan(
+                pushed.program, subs[0].schema, self.db.dicts,
+                self.db.key_spaces, mesh=self.mesh,
+                dict_aliases=dict(plan.dict_aliases),
+            )
+            self._jit_cache[key] = scan
+        if not scan.folds_partials:
+            return None
+        # on the ``mesh`` span: the shards whose scans took the pushdown
+        tracing.annotate(agg_pushdown=self.n)
+        return scan.run_sources(subs, DEFAULT_BLOCK_ROWS,
+                                table=pushed.table, fresh=fresh)
+
     def _transform(self, plan: Transform, memo, root: bool):
-        stacked = self._exec(plan.input, memo)
-        has_gb = plan.program.group_by is not None
-        has_sort = any(isinstance(s, SortStep) for s in plan.program.steps)
         if any(isinstance(s, WindowStep) for s in plan.program.steps):
             # ranking windows need every row at once; a per-shard
             # elementwise run would rank within shards. Fall back to
             # the single-chip/DQ path.
             raise NotImplementedError("window function on the mesh")
+        if root:
+            out = self._scan_aggregated(plan, memo.shared)
+            if out is not None:
+                return out
+        stacked = self._exec(plan.input, memo)
+        has_gb = plan.program.group_by is not None
+        has_sort = any(isinstance(s, SortStep) for s in plan.program.steps)
         if not (has_gb or has_sort):
             # distributed elementwise transform: stays sharded
             key = ("xform", plan.program, plan.dict_aliases,
