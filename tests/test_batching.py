@@ -234,6 +234,45 @@ def test_run_stacked_slices_match_run_shared():
             != rb.to_numpy()["revenue"][0])
 
 
+def test_run_stacked_q3_members_match_serial():
+    """The join shape: TPC-H Q3 (semi + inner join, grouped top-10)
+    fuses, and three members staged from the same tables stacked into
+    ONE vmapped dispatch each equal the serial non-donating dispatch,
+    as does a second shared dispatch (the dedup path's result)."""
+    from ydb_tpu.engine.scan import ColumnSource
+    from ydb_tpu.plan.executor import Database, _stage_fused_site
+    from ydb_tpu.ssa import plan_fuse
+    from ydb_tpu.workload import tpch
+
+    data = tpch.TpchData(sf=0.002, seed=5)
+    db = Database(
+        sources={t: ColumnSource(cols, data.schema(t), data.dicts)
+                 for t, cols in data.tables.items()},
+        dicts=data.dicts)
+    sig = plan_fuse.plan_signature(tpch.q3_plan(), db)
+    assert sig is not None and len(sig.sites) >= 3
+    fused = plan_fuse.build(sig, db)
+    inputs = {s.key: _stage_fused_site(s, db, None, donate=False)[0]
+              for s in sig.sites}
+    serial, totals = fused.run_shared(inputs)
+    assert not fused.overflowed(totals)
+    assert int(serial.length) > 0
+    stacked, totals = fused.run_stacked([inputs] * 3)
+    assert not fused.overflowed(totals)
+    again, totals = fused.run_shared(inputs)
+    assert not fused.overflowed(totals)
+
+    sv, sok = serial.to_numpy(), serial.validity_numpy()
+    members = [plan_fuse.slice_member(stacked, i) for i in range(3)]
+    for blk in members + [again]:
+        bv, bok = blk.to_numpy(), blk.validity_numpy()
+        for name in serial.schema.names:
+            np.testing.assert_array_equal(sok[name], bok[name])
+            np.testing.assert_array_equal(
+                np.where(sok[name], sv[name], 0),
+                np.where(bok[name], bv[name], 0), err_msg=name)
+
+
 # ---------------- window gating ----------------
 
 def test_window_zero_is_serial(cluster):

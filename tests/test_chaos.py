@@ -86,6 +86,29 @@ def test_force_overrides_env(monkeypatch):
     assert chaos.chaos_enabled()
 
 
+def test_disarmed_statement_counts_no_site_hits():
+    """The production state: a statement crosses the blob, conveyor and
+    fuse sites with nothing installed, and no site may count a hit. A
+    dormant scenario (p = 0 on the same sites) then counts hits, fires
+    nothing and answers the same."""
+    c, s = _kv_cluster()
+    chaos.clear()
+    want = s.execute(AGG_SQL)
+    c.scan_block_cache.clear()
+    _same_result(s.execute(AGG_SQL), want)  # re-read from the blobs
+    assert not chaos.counters_snapshot().get("sites")
+    _armed(chaos.Scenario(seed=7, sites={
+        "blob.get": {"kind": "io_error", "p": 0.0},
+        "blob.get_range": {"kind": "io_error", "p": 0.0},
+        "conveyor.task": {"kind": "delay", "p": 0.0},
+    }))
+    c.scan_block_cache.clear()
+    _same_result(s.execute(AGG_SQL), want)
+    sites = chaos.counters_snapshot()["sites"]
+    assert sum(v["hits"] for v in sites.values()) > 0
+    assert sum(v["fired"] for v in sites.values()) == 0
+
+
 def test_seeded_replay_is_deterministic():
     def fire_seq(seed):
         p = chaos.FaultPoint("blob.get", "io_error", p=0.5, seed=seed)

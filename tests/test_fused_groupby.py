@@ -1,9 +1,8 @@
-"""Fused vs per-aggregate group-by equivalence (PR 3 tentpole).
+"""The group-by lowering against the independent numpy oracle.
 
-Every case compiles the SAME program twice — kernels.FUSED_FORCE
-True/False — and cross-checks both lowerings against each other and
-against the independent CPU oracle, across dtypes, NULL patterns,
-decimals, and all three group-id tiers (dense one-hot, sorted, and the
+Every case compiles its program and cross-checks the device answer
+against engine/oracle.py, across dtypes, NULL patterns, decimals, and
+all three group-id tiers (dense one-hot, sorted, and the
 >ONEHOT_GROUP_LIMIT scatter/Pallas tier).
 """
 
@@ -35,16 +34,11 @@ def _block(cols, validity=None):
         arrays, dtypes.schema(*sch), validity or None)
 
 
-def _run(prog, blk, dicts=None, key_spaces=None, fused=True):
-    kernels.FUSED_FORCE = fused
-    try:
-        cp = compile_program(prog, blk.schema, dicts, key_spaces)
-        out = jax.jit(cp.run)(
-            blk, {k: jnp.asarray(v) for k, v in cp.aux.items()})
-        data, valid = out.host_columns()
-        return data, valid
-    finally:
-        kernels.FUSED_FORCE = None
+def _run(prog, blk, dicts=None, key_spaces=None):
+    cp = compile_program(prog, blk.schema, dicts, key_spaces)
+    out = jax.jit(cp.run)(
+        blk, {k: jnp.asarray(v) for k, v in cp.aux.items()})
+    return out.host_columns()
 
 
 def _run_oracle(prog, blk, dicts=None):
@@ -58,7 +52,7 @@ def _run_oracle(prog, blk, dicts=None):
 
 def _sorted_by(data, valid, keys):
     # NULL key groups carry arbitrary data under validity=False: align
-    # rows by (validity, value) per key so all three runs sort alike
+    # rows by (validity, value) per key so both runs sort alike
     subkeys = []
     for k in reversed(keys):
         subkeys.append(np.asarray(data[k]))
@@ -68,46 +62,28 @@ def _sorted_by(data, valid, keys):
 
 def _assert_equivalent(prog, blk, dicts=None, key_spaces=None,
                        keys=("k",)):
-    fd, fv = _run(prog, blk, dicts, key_spaces, fused=True)
-    pd_, pv = _run(prog, blk, dicts, key_spaces, fused=False)
+    fd, fv = _run(prog, blk, dicts, key_spaces)
     od, ov = _run_oracle(prog, blk, dicts)
-    fo, po, oo = (_sorted_by(fd, fv, keys), _sorted_by(pd_, pv, keys),
-                  _sorted_by(od, ov, keys)) if keys else (None,) * 3
     for name in fd:
-        f = np.asarray(fd[name])
-        p = np.asarray(pd_[name])
-        o = np.asarray(od[name])
+        f, fvv = np.asarray(fd[name]), np.asarray(fv[name])
+        o, ovv = np.asarray(od[name]), np.asarray(ov[name])
         if keys:
-            f, p, o = f[fo], p[po], o[oo]
-            fvv, pvv, ovv = (np.asarray(fv[name])[fo],
-                             np.asarray(pv[name])[po],
-                             np.asarray(ov[name])[oo])
-        else:
-            fvv, pvv, ovv = (np.asarray(fv[name]), np.asarray(pv[name]),
-                             np.asarray(ov[name]))
-        np.testing.assert_array_equal(fvv, pvv,
-                                      err_msg=f"validity {name}")
+            fo, oo = _sorted_by(fd, fv, keys), _sorted_by(od, ov, keys)
+            f, fvv, o, ovv = f[fo], fvv[fo], o[oo], ovv[oo]
         np.testing.assert_array_equal(fvv, ovv,
                                       err_msg=f"oracle validity {name}")
         live = fvv
         # key columns under validity=False hold arbitrary padding;
-        # SOME is "any valid value" — its value is only comparable
-        # between the two device lowerings, not against the oracle
-        check_oracle = not name.startswith("some_")
+        # SOME is "any valid value": only its validity is comparable
+        if name.startswith("some_"):
+            continue
         if np.issubdtype(f.dtype, np.integer) or f.dtype == bool:
             np.testing.assert_array_equal(
-                f[live], p[live], err_msg=f"fused vs peragg {name}")
-            if check_oracle:
-                np.testing.assert_array_equal(
-                    f[live], o[live], err_msg=f"fused vs oracle {name}")
+                f[live], o[live], err_msg=f"device vs oracle {name}")
         else:
             np.testing.assert_allclose(
-                f[live], p[live], rtol=1e-9,
-                err_msg=f"fused vs peragg {name}")
-            if check_oracle:
-                np.testing.assert_allclose(
-                    f[live], o[live], rtol=1e-9,
-                    err_msg=f"fused vs oracle {name}")
+                f[live], o[live], rtol=1e-9,
+                err_msg=f"device vs oracle {name}")
 
 
 _ALL_AGGS = (
@@ -206,8 +182,8 @@ def test_keyless_global_aggregate():
 
 
 def test_large_group_scatter_tier():
-    # > ONEHOT_GROUP_LIMIT dense groups: the fused path takes the 2D
-    # scatter (or Pallas) tier instead of the hit-matrix GEMM
+    # > ONEHOT_GROUP_LIMIT dense groups: the 2D scatter (or Pallas)
+    # tier instead of the masked sums over the hit matrix
     rng = np.random.default_rng(3)
     n, k = 20_000, 700
     assert k > kernels.ONEHOT_GROUP_LIMIT
@@ -241,32 +217,25 @@ def test_pallas_fused_multi_matches_scatter_tier():
 
 
 def test_decimal_sum_exactness_via_limb_split():
-    # values whose naive f64 accumulation would round: the limb-encoded
-    # GEMM must still produce bit-exact int64 sums
+    # values whose f64 accumulation would round ((2^50 + 1) * 1024 is
+    # past 2^53): the int64 decimal sums must stay bit-exact
     n = 1024
     big = (1 << 50) + 1
-    blk = _block(
-        {"k": (np.zeros(n, dtype=np.int64), dtypes.INT64),
-         "d": (np.full(n, big, dtype=np.int64), dtypes.decimal(2))},
-    )
     prog = Program((GroupByStep(
         ("k",), (AggSpec(Agg.SUM, "d", "s"),)),))
-    fd, _ = _run(prog, blk, key_spaces={"k": 1}, fused=True)
-    pd_, _ = _run(prog, blk, key_spaces={"k": 1}, fused=False)
-    assert int(fd["s"][0]) == n * big
-    assert int(pd_["s"][0]) == n * big
-    # negative values exercise the signed top limb
-    blk2 = _block(
-        {"k": (np.zeros(n, dtype=np.int64), dtypes.INT64),
-         "d": (np.full(n, -big, dtype=np.int64), dtypes.decimal(2))},
-    )
-    fd2, _ = _run(prog, blk2, key_spaces={"k": 1}, fused=True)
-    assert int(fd2["s"][0]) == -n * big
+    # negative values exercise the sign
+    for v in (big, -big):
+        blk = _block(
+            {"k": (np.zeros(n, dtype=np.int64), dtypes.INT64),
+             "d": (np.full(n, v, dtype=np.int64), dtypes.decimal(2))},
+        )
+        fd, _ = _run(prog, blk, key_spaces={"k": 1})
+        assert int(fd["s"][0]) == n * v
 
 
 def test_nullable_flag_does_not_change_results():
     # identical data, schema declared nullable vs non-nullable: the
-    # fused path's static count/mask collapse must be invisible
+    # lowering's static count/mask collapse must be invisible
     rng = np.random.default_rng(2)
     n = 3000
     k = rng.integers(0, 6, n).astype(np.int64)
@@ -283,8 +252,7 @@ def test_nullable_flag_does_not_change_results():
             dtypes.Field("v", dtypes.INT64, nullable),
         ))
         blk = TableBlock.from_numpy({"k": k, "v": v}, sch)
-        outs[nullable], _ = _run(prog, blk, key_spaces={"k": 6},
-                                 fused=True)
+        outs[nullable], _ = _run(prog, blk, key_spaces={"k": 6})
     order0 = np.argsort(outs[False]["k"])
     order1 = np.argsort(outs[True]["k"])
     for name in outs[False]:
@@ -293,44 +261,87 @@ def test_nullable_flag_does_not_change_results():
             np.asarray(outs[True][name])[order1], err_msg=name)
 
 
-def test_fused_flag_env_gating(monkeypatch):
-    monkeypatch.setattr(kernels, "FUSED_FORCE", None)
-    monkeypatch.setenv("YDB_TPU_FUSED_GROUPBY", "0")
-    assert not kernels.fused_group_by_enabled()
-    monkeypatch.setenv("YDB_TPU_FUSED_GROUPBY", "1")
-    assert kernels.fused_group_by_enabled()
-    monkeypatch.delenv("YDB_TPU_FUSED_GROUPBY")
-    assert kernels.fused_group_by_enabled()  # default on
-    monkeypatch.setattr(kernels, "FUSED_FORCE", False)
-    assert not kernels.fused_group_by_enabled()
+def _numpy_group_sums(vals, gid, k):
+    live = gid < k
+    want = np.zeros((k, vals.shape[1]), dtype=vals.dtype)
+    np.add.at(want, gid[live], vals[live])
+    return want
 
 
-def test_onehot_tier_off_the_gemm_is_bit_exact(monkeypatch):
-    """Where the platform GEMM is not exact (a TPU: f64 dots run as f32
-    MXU passes, s64 dots do not exist) the one-hot tier reduces on the
-    vector unit. Integer banks must equal the limb-GEMM path bit for
-    bit — including sums past 2^32, which is where the chip's f64 dot
-    went wrong — and float banks to rounding."""
+def test_onehot_tier_off_the_gemm_is_bit_exact():
+    """The one-hot tier reduces on the vector unit, not on a GEMM (on a
+    TPU an f64 dot runs as f32 MXU passes and an s64 dot does not
+    exist). Integer banks must equal numpy int64 bit for bit, including
+    sums past 2^32, which is where the chip's f64 dot went wrong, and
+    float banks to rounding."""
     rng = np.random.default_rng(11)
     n, k = 4096, 7
     ints = rng.integers(-(1 << 40), 1 << 50, (n, 5)).astype(np.int64)
     flts = rng.random((n, 3)) * 1e6
-    gid = jnp.asarray(rng.integers(0, k + 1, n), dtype=jnp.int32)
-    banks = {jnp.dtype("int64"): jnp.asarray(ints),
-             jnp.dtype("float64"): jnp.asarray(flts)}
-    gemm = kernels.fused_group_reduce_banks(banks, gid, k)
-    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: False)
-    vpu = kernels.fused_group_reduce_banks(banks, gid, k)
-    want = np.zeros((k, 5), dtype=np.int64)
-    live = np.asarray(gid) < k
-    np.add.at(want, np.asarray(gid)[live], ints[live])
-    for got in (gemm, vpu):
-        np.testing.assert_array_equal(
-            np.asarray(got[jnp.dtype("int64")]), want)
-    np.testing.assert_allclose(
-        np.asarray(vpu[jnp.dtype("float64")]),
-        np.asarray(gemm[jnp.dtype("float64")]), rtol=1e-12)
-    # the single-bank entry point takes the same turn
+    gid = rng.integers(0, k + 1, n).astype(np.int32)
     np.testing.assert_array_equal(
         np.asarray(kernels.fused_group_reduce(
-            jnp.asarray(ints), gid, k)), want)
+            jnp.asarray(ints), jnp.asarray(gid), k)),
+        _numpy_group_sums(ints, gid, k))
+    np.testing.assert_allclose(
+        np.asarray(kernels.fused_group_reduce(
+            jnp.asarray(flts), jnp.asarray(gid), k)),
+        _numpy_group_sums(flts, gid, k), rtol=1e-12)
+
+
+def _tier_case(dtype, n, k, seed):
+    rng = np.random.default_rng(seed)
+    if dtype == "int64":
+        # three slots near 2^62 / n: every group's sum stays inside
+        # int64 and far outside f64's 2^53 integers
+        hi = (1 << 62) // n
+        vals = rng.integers(hi // 2, hi, (n, 3)).astype(np.int64)
+        vals[::2] *= -1
+    elif dtype == "int32":
+        vals = rng.integers(-1000, 1000, (n, 3)).astype(np.int32)
+    else:
+        vals = rng.normal(0.0, 1e6, (n, 3))
+    # ids in [0, k]: k is the drop slot of dead rows
+    gid = rng.integers(0, k + 1, n).astype(np.int32)
+    return vals, gid
+
+
+@pytest.mark.parametrize("k", [1, 12, 512])
+@pytest.mark.parametrize("dtype", ["int64", "int32", "float64"])
+def test_onehot_tier_exact(dtype, k):
+    """The tier every TPC-H Q1/Q6 partial, combine and final program
+    takes, against numpy: integers bit for bit, floats to rounding."""
+    assert k <= kernels.ONEHOT_GROUP_LIMIT
+    vals, gid = _tier_case(dtype, 2048, k, seed=k)
+    got = np.asarray(kernels.fused_group_reduce(
+        jnp.asarray(vals), jnp.asarray(gid), k))
+    want = _numpy_group_sums(vals, gid, k)
+    assert got.dtype == want.dtype
+    if dtype == "float64":
+        np.testing.assert_allclose(got, want, rtol=1e-9, atol=1e-3)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+def test_tier_boundary_agrees(monkeypatch):
+    """512 groups take the masked sums, 513 the scatter: the same rows
+    (none in the 513th group) must give the same sums either side."""
+    monkeypatch.setattr(pallas_kernels, "FORCE", False)
+    k = kernels.ONEHOT_GROUP_LIMIT
+    rng = np.random.default_rng(21)
+    n = 8192
+    ints = rng.integers(-(1 << 50), 1 << 50, (n, 4)).astype(np.int64)
+    flts = rng.normal(0.0, 1e3, (n, 2))
+    gid = rng.integers(0, k, n).astype(np.int32)
+    for vals in (ints, flts):
+        lo = np.asarray(kernels.fused_group_reduce(
+            jnp.asarray(vals), jnp.asarray(gid), k))
+        hi = np.asarray(kernels.fused_group_reduce(
+            jnp.asarray(vals), jnp.asarray(gid), k + 1))
+        assert not hi[k].any()
+        if vals.dtype == np.int64:
+            np.testing.assert_array_equal(lo, hi[:k])
+            np.testing.assert_array_equal(
+                lo, _numpy_group_sums(vals, gid, k))
+        else:
+            np.testing.assert_allclose(lo, hi[:k], rtol=1e-9, atol=1e-6)

@@ -343,6 +343,60 @@ def test_leaksan_disabled_is_free(monkeypatch):
     leaksan.assert_drained()  # no-op when off
 
 
+def test_leaksan_scan_tracks_nothing_off_and_drains_armed():
+    """A scan crosses the conveyor, stream-morsel and blockcache track
+    sites. Off (the production state) it may track no handle; armed,
+    handles open during the scan and every one closes once the scan's
+    conveyor work completes."""
+    from ydb_tpu import dtypes
+    from ydb_tpu.engine.blobs import MemBlobStore
+    from ydb_tpu.engine.reader import PortionStreamSource
+    from ydb_tpu.engine.shard import ColumnShard, ShardConfig
+    from ydb_tpu.runtime.conveyor import shared_conveyor, stream_conveyor
+    from ydb_tpu.ssa import Agg, AggSpec, GroupByStep
+    from ydb_tpu.ssa.program import Program
+
+    schema = dtypes.schema(("id", dtypes.INT64, False),
+                           ("v", dtypes.INT64))
+    shard = ColumnShard(
+        "s1", schema, MemBlobStore(), pk_column="id", upsert=False,
+        config=ShardConfig(compact_portion_threshold=10**6,
+                           scan_block_rows=256))
+    for off in range(4):
+        ids = np.arange(off * 300, off * 300 + 300, dtype=np.int64)
+        shard.commit([shard.write({"id": ids, "v": ids % 7})])
+    prog = Program((GroupByStep(("v",), (
+        AggSpec(Agg.COUNT_ALL, None, "n"),
+        AggSpec(Agg.SUM, "id", "s"))),))
+
+    def drained():
+        stream_conveyor().wait_idle(timeout=10.0)
+        shared_conveyor().wait_idle(timeout=10.0)
+        deadline = time.monotonic() + 5.0
+        while leaksan.counts() and time.monotonic() < deadline:
+            time.sleep(0.005)  # a worker may close its handle post-idle
+        return leaksan.counts()
+
+    leaksan.reset()
+    assert not leaksan.enabled()
+    off_answer = shard.scan(prog)
+    assert drained() == {}
+    seen = []
+    with leaksan.activate():
+        src = PortionStreamSource(shard, shard.visible_portions(None))
+        it = src.blocks(256)
+        next(it)
+        seen = leaksan.live()  # flights admitted ahead of the consumer
+        for _ in it:
+            pass
+        armed_answer = shard.scan(prog)
+        assert drained() == {}
+    assert seen, "the armed scan tracked no handle: dead track sites"
+    for name in off_answer.cols:
+        for a, b in zip(off_answer.cols[name], armed_answer.cols[name]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_leaksan_track_close_and_stacks():
     with leaksan.activate():
         h = leaksan.track("broker.slot", "scan", owner="q1")
@@ -460,7 +514,6 @@ def test_kind_stream_morsel():
     retire: live while prefetched flights wait behind the consumer,
     zero once the scan drains."""
     from ydb_tpu import dtypes
-    from ydb_tpu.engine import stream_sched
     from ydb_tpu.engine.blobs import MemBlobStore
     from ydb_tpu.engine.reader import PortionStreamSource
     from ydb_tpu.engine.shard import ColumnShard, ShardConfig
@@ -468,38 +521,33 @@ def test_kind_stream_morsel():
 
     schema = dtypes.schema(("id", dtypes.INT64, False),
                            ("v", dtypes.INT64))
-    prev = stream_sched.PIPELINE_FORCE
-    stream_sched.PIPELINE_FORCE = True
-    try:
-        with leaksan.activate():
-            shard = ColumnShard(
-                "s1", schema, MemBlobStore(), pk_column="id",
-                upsert=False,
-                config=ShardConfig(compact_portion_threshold=10**6))
-            for off in range(6):
-                base = off * 200
-                wid = shard.write({
-                    "id": np.arange(base, base + 200, dtype=np.int64),
-                    "v": np.arange(base, base + 200, dtype=np.int64)})
-                shard.commit([wid])
-            src = PortionStreamSource(shard,
-                                      shard.visible_portions(None))
-            it = src.blocks(64)
-            next(it)  # later morsels are admitted ahead, uncollected
-            assert leaksan.live("stream.morsel")
-            for _ in it:
-                pass
-            deadline = time.monotonic() + 5.0
-            while leaksan.live("stream.morsel") and \
-                    time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert leaksan.live("stream.morsel") == []
-            stream_conveyor().wait_idle(timeout=10.0)
-            while leaksan.counts() and time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert leaksan.counts() == {}
-    finally:
-        stream_sched.PIPELINE_FORCE = prev
+    with leaksan.activate():
+        shard = ColumnShard(
+            "s1", schema, MemBlobStore(), pk_column="id",
+            upsert=False,
+            config=ShardConfig(compact_portion_threshold=10**6))
+        for off in range(6):
+            base = off * 200
+            wid = shard.write({
+                "id": np.arange(base, base + 200, dtype=np.int64),
+                "v": np.arange(base, base + 200, dtype=np.int64)})
+            shard.commit([wid])
+        src = PortionStreamSource(shard,
+                                  shard.visible_portions(None))
+        it = src.blocks(64)
+        next(it)  # later morsels are admitted ahead, uncollected
+        assert leaksan.live("stream.morsel")
+        for _ in it:
+            pass
+        deadline = time.monotonic() + 5.0
+        while leaksan.live("stream.morsel") and \
+                time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert leaksan.live("stream.morsel") == []
+        stream_conveyor().wait_idle(timeout=10.0)
+        while leaksan.counts() and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert leaksan.counts() == {}
 
 
 class _FakeCol:

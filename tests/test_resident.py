@@ -181,7 +181,7 @@ def test_no_stale_reads_after_ttl():
     assert _sum_n(shard) == (14, 2)
 
 
-def test_mid_stream_resident_host_fallback_equality():
+def test_mid_scan_resident_host_fallback_equality():
     """Some portions resident, some not: the mixed stream must produce
     exactly the all-host results (row order included)."""
     resident_mod.RESIDENT_FORCE = True

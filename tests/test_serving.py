@@ -164,6 +164,28 @@ def test_edf_orders_queued_admissions():
         c.stop()
 
 
+def test_no_front_door_by_default_installed_counts_default_pool():
+    """A cluster serves without a front door (one ``front_door is
+    None`` test a statement) until one is installed. Installed with no
+    registry, a plain session is the default pool's: the statement is
+    admitted, not shed, answers the same, and its seat is released."""
+    c = _lineitem_cluster()
+    try:
+        assert c.front_door is None
+        s = c.session()
+        want = s.execute(Q6_SQL)
+        fd = serving.install(c)
+        assert c.front_door is fd
+        assert not fd.snapshot().get(
+            serving.DEFAULT_TENANT, {}).get("admitted")
+        _same_result(s.execute(Q6_SQL), want)
+        pool = fd.snapshot()[serving.DEFAULT_TENANT]
+        assert pool["admitted"] == 1
+        assert pool["shed"] == 0 and pool["inflight"] == 0
+    finally:
+        c.stop()
+
+
 def test_session_overload_is_typed_and_named(front):
     c, _ = front
     fd = c.front_door

@@ -160,6 +160,68 @@ def test_size_buckets_uniform_and_gates():
         sh.SHUFFLE_STATS_FORCE = old
 
 
+def test_stats_sized_bucket_exchanges_uniform_keys_losslessly():
+    """Uniform keys over 8 destinations, the send bucket sized from a
+    real count-min sketch of them (mean load x margin + the heavy-hitter
+    bound): at least 4x under full capacity, never overflowed, and the
+    exchange delivers the row multiset the full-capacity one does, every
+    key on one shard."""
+    from ydb_tpu.parallel import shuffle as sh
+    from ydb_tpu.stats.sketch import CountMinSketch
+
+    n_dev, rows = 8, 8192
+    mesh = make_mesh(n_dev)
+    sch = dtypes.schema(("k", dtypes.INT64), ("v", dtypes.INT64))
+    rng = np.random.default_rng(11)
+    keys = [rng.integers(0, 1 << 30, rows).astype(np.int64)
+            for _ in range(n_dev)]
+    sk = CountMinSketch()
+    for arr in keys:
+        sk.add_many(arr)
+    old = sh.SHUFFLE_STATS_FORCE
+    sh.SHUFFLE_STATS_FORCE = True
+    try:
+        stats_b = sh.size_buckets(rows, n_dev, heavy=sk.max_freq())
+    finally:
+        sh.SHUFFLE_STATS_FORCE = old
+    assert rows / stats_b >= 4, (stats_b, sk.max_freq())
+
+    stacked = stack_blocks([TableBlock.from_numpy(
+        {"k": keys[d],
+         "v": np.arange(rows, dtype=np.int64) + d * rows},
+        sch, capacity=rows) for d in range(n_dev)])
+
+    def exchange(bucket):
+        def step(st):
+            blk, worst = repartition(_local(st), ["k"], n_dev,
+                                     bucket_rows=bucket, with_counts=True)
+            return _relocal(blk), worst
+        fn = jax.jit(shard_map(
+            step, mesh=mesh, in_specs=P(SHARD_AXIS),
+            out_specs=(P(SHARD_AXIS), P()), check_vma=False))
+        out, worst = fn(jax.device_put(
+            stacked, NamedSharding(mesh, P(SHARD_AXIS))))
+        assert int(np.asarray(worst)) <= bucket  # no overflow
+        lens = np.asarray(out.length)
+        ks = np.asarray(out.columns["k"].data)
+        vs = np.asarray(out.columns["v"].data)
+        got, per_dev = [], []
+        for d in range(n_dev):
+            got.extend(zip(ks[d][: lens[d]].tolist(),
+                           vs[d][: lens[d]].tolist()))
+            per_dev.append(set(ks[d][: lens[d]].tolist()))
+        return sorted(got), per_dev
+
+    want = sorted((int(k), d * rows + i)
+                  for d in range(n_dev) for i, k in enumerate(keys[d]))
+    for bucket in (stats_b, rows):
+        got, per_dev = exchange(bucket)
+        assert got == want  # no row lost or duplicated
+        for i in range(n_dev):
+            for j in range(i + 1, n_dev):
+                assert not (per_dev[i] & per_dev[j])
+
+
 def test_heavy_bound_joint_keys():
     from ydb_tpu.parallel.shuffle import heavy_bound
     from ydb_tpu.stats.cost import ColumnStats

@@ -3,8 +3,8 @@
 Covers: rechunk's aligned pass-through / single-buffer fast paths,
 TableBlock.from_numpy tail-only padding (padding validity never leaks),
 the shared-pool depth-k prefetch in stream_blocks (incl. abandoned
-generators not leaking producer tasks), per-scan stage timers, the
-scan-executor LRU cap, and the kernelbench smoke wiring.
+generators not leaking producer tasks), per-scan stage timers and
+the scan-executor LRU cap.
 """
 
 import gc
@@ -184,9 +184,3 @@ def test_scan_results_unchanged_by_staging_pipeline(tmp_path):
     got = {int(k): int(n) for k, n in zip(out.column("a"),
                                           out.column("n"))}
     assert got == expect
-
-
-def test_kernelbench_smoke():
-    from ydb_tpu.obs import kernelbench
-
-    assert kernelbench.main(["--smoke", "--json"]) == 0

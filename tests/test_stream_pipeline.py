@@ -1,5 +1,5 @@
 """Morsel-driven streaming pipeline (engine.stream_sched): bit-identity
-against the serialized path for plain and upsert-merge scans, chaos
+against the serialized chain for plain and upsert-merge scans, chaos
 blob faults healing without a consumer stall, mid-scan deadline and
 abandoned-stream drain to zero under leaksan, and consumer work
 stealing when the dedicated stream pool is saturated."""
@@ -13,9 +13,9 @@ import pytest
 from ydb_tpu import chaos, dtypes
 from ydb_tpu.analysis import leaksan
 from ydb_tpu.chaos.deadline import Deadline, StatementCancelled, activate
-from ydb_tpu.engine import stream_sched
 from ydb_tpu.engine.blobs import MemBlobStore
-from ydb_tpu.engine.reader import PortionStreamSource
+from ydb_tpu.engine.reader import (PortionStreamSource, plan_clusters,
+                                   stream_blocks)
 from ydb_tpu.engine.shard import ColumnShard, ShardConfig
 from ydb_tpu.kqp.session import Cluster
 from ydb_tpu.runtime.conveyor import shared_conveyor, stream_conveyor
@@ -31,10 +31,8 @@ AGG_SQL = ("SELECT k % 5 AS g, SUM(v) AS sv, COUNT(*) AS n "
 
 @pytest.fixture(autouse=True)
 def _clean():
-    """Every test leaves the pipeline gate on the environment and the
-    chaos subsystem disarmed."""
+    """Every test leaves the chaos subsystem disarmed."""
     yield
-    stream_sched.PIPELINE_FORCE = None
     chaos.clear()
     chaos.CHAOS_FORCE = None
 
@@ -52,16 +50,32 @@ def _put(shard, ids, vals):
     return shard.commit([wid])
 
 
-def _scan(shard, cap=64):
-    """Full scan; returns (source, per-block (ids, vals) lists) so
-    identity checks cover block boundaries, not just totals."""
-    src = PortionStreamSource(shard, shard.visible_portions(None))
-    blocks = []
-    for blk in src.blocks(cap):
+def _rows(blocks):
+    """Per-block (ids, vals) lists, so identity checks cover block
+    boundaries, not just totals."""
+    out = []
+    for blk in blocks:
         data = blk.to_numpy()
         n = int(blk.length)
-        blocks.append((data["id"][:n].tolist(), data["v"][:n].tolist()))
-    return src, blocks
+        out.append((data["id"][:n].tolist(), data["v"][:n].tolist()))
+    return out
+
+
+def _scan(shard, cap=64):
+    """Full scan through the pipeline; returns (source, block rows)."""
+    src = PortionStreamSource(shard, shard.visible_portions(None))
+    return src, _rows(src.blocks(cap))
+
+
+def _scan_serialized(shard, cap=64):
+    """The same scan through the serialized chain, called directly (a
+    count-based resume takes it: reader.py ``blocks``)."""
+    src = PortionStreamSource(shard, shard.visible_portions(None))
+    names = src.columns_read
+    return _rows(stream_blocks(
+        src.payload_stream(plan_clusters(src.metas, src.dedup), names),
+        names, shard.schema.select(names),
+        min(cap, max(src.num_rows, 1))))
 
 
 def _kv_cluster(n=300):
@@ -89,7 +103,7 @@ def _same_result(a, b):
                                       err_msg=f"{name} validity")
 
 
-# ---------------- bit-identity: pipeline on == pipeline off ----------
+# ---------------- bit-identity: pipeline == serialized chain ---------
 
 
 def test_bit_identity_plain_scan():
@@ -99,9 +113,7 @@ def test_bit_identity_plain_scan():
         _put(shard, range(base, base + 100),
              (i * 3 for i in range(base, base + 100)))
 
-    stream_sched.PIPELINE_FORCE = False
-    _, serialized = _scan(shard)
-    stream_sched.PIPELINE_FORCE = True
+    serialized = _scan_serialized(shard)
     src, pipelined = _scan(shard)
 
     assert pipelined == serialized  # same blocks, same order, same rows
@@ -118,9 +130,7 @@ def test_bit_identity_upsert_merge():
     _put(shard, range(50, 150), (i * 9 for i in range(50, 150)))
     _put(shard, range(1000, 1200), (i for i in range(1000, 1200)))
 
-    stream_sched.PIPELINE_FORCE = False
-    _, serialized = _scan(shard)
-    stream_sched.PIPELINE_FORCE = True
+    serialized = _scan_serialized(shard)
     src, pipelined = _scan(shard)
 
     assert pipelined == serialized
@@ -133,7 +143,6 @@ def test_bit_identity_upsert_merge():
 
 
 def test_chaos_blob_io_error_heals_under_pipeline():
-    stream_sched.PIPELINE_FORCE = True
     c, s = _kv_cluster()
     want = s.execute(AGG_SQL)
     chaos.CHAOS_FORCE = True
@@ -151,7 +160,6 @@ def test_chaos_blob_io_error_heals_under_pipeline():
 def test_chaos_blob_latency_does_not_stall_consumer():
     # pure-delay faults on every blob read: flights just take longer,
     # the consumer keeps draining in order and the result is identical
-    stream_sched.PIPELINE_FORCE = True
     c, s = _kv_cluster()
     want = s.execute(AGG_SQL)
     chaos.CHAOS_FORCE = True
@@ -170,7 +178,6 @@ def test_chaos_blob_latency_does_not_stall_consumer():
 def test_chaos_torn_read_heals_under_pipeline():
     # a torn read truncates the payload mid-chunk: the zero-copy
     # decode raises a transient kind and the flight re-fetches
-    stream_sched.PIPELINE_FORCE = True
     c, s = _kv_cluster()
     want = s.execute(AGG_SQL)
     chaos.CHAOS_FORCE = True
@@ -187,7 +194,6 @@ def test_chaos_torn_read_heals_under_pipeline():
 
 
 def test_mid_scan_deadline_drains_morsel_flights():
-    stream_sched.PIPELINE_FORCE = True
     shard = _shard(upsert=False)
     for off in range(8):
         base = off * 200
@@ -216,7 +222,6 @@ def test_mid_scan_deadline_drains_morsel_flights():
 
 
 def test_abandoned_stream_drains_morsel_flights():
-    stream_sched.PIPELINE_FORCE = True
     shard = _shard(upsert=False)
     for off in range(8):
         base = off * 200
@@ -245,15 +250,12 @@ def test_abandoned_stream_drains_morsel_flights():
 
 
 def test_consumer_steals_when_stream_pool_saturated():
-    stream_sched.PIPELINE_FORCE = True
     shard = _shard(upsert=False)
     for off in range(6):
         base = off * 100
         _put(shard, range(base, base + 100),
              (i * 3 for i in range(base, base + 100)))
-    stream_sched.PIPELINE_FORCE = False
-    _, serialized = _scan(shard)
-    stream_sched.PIPELINE_FORCE = True
+    serialized = _scan_serialized(shard)
 
     gate = threading.Event()
     cv = stream_conveyor()

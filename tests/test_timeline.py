@@ -122,6 +122,29 @@ def test_disabled_ring_records_nothing(monkeypatch):
         timeline.TIMELINE_FORCE = prev
 
 
+def test_profiled_statement_records_nothing_while_disabled(cluster):
+    """A whole profiled statement crosses every record site (blob read,
+    decode, the stage timers): with the timeline off the ring must not
+    move, and the same statement with it on does record, so the sites
+    are on its path."""
+    s = cluster.session()
+    q = "SELECT id, sum(v) AS sv FROM ev GROUP BY id ORDER BY id"
+    prev = timeline.TIMELINE_FORCE
+    timeline.TIMELINE_FORCE = False
+    try:
+        s.execute(q)  # warm: compile + cache fill
+        before = timeline.RING.recorded
+        s.execute(q)
+        assert s.last_profile is not None  # it was profiled
+        assert timeline.RING.recorded == before
+        timeline.TIMELINE_FORCE = True
+        s.execute(q)
+        assert timeline.RING.recorded > before
+    finally:
+        timeline.TIMELINE_FORCE = prev
+        timeline.RING.clear()
+
+
 def test_env_enables(monkeypatch):
     prev = timeline.TIMELINE_FORCE
     timeline.TIMELINE_FORCE = None

@@ -73,13 +73,6 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
 
-def _compile_grouped_sum(dtype, groups, one_chip):
-    n = DEFAULT_BLOCK_ROWS
-    return pallas_kernels.grouped_sum.lower(
-        _shape((n,), dtype, one_chip), _shape((n,), "int32", one_chip),
-        num_groups=groups).compile()
-
-
 def _compile_grouped_sum_multi(dtype, groups, one_chip):
     n = DEFAULT_BLOCK_ROWS
     return pallas_kernels.grouped_sum_multi.lower(
@@ -89,15 +82,6 @@ def _compile_grouped_sum_multi(dtype, groups, one_chip):
 
 def test_block_size_is_the_one_the_path_uses():
     assert ShardConfig().scan_block_rows == DEFAULT_BLOCK_ROWS == 1 << 20
-
-
-@pytest.mark.parametrize("groups", GROUPS)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_grouped_sum_compiles(dtype, groups, one_chip,
-                              no_persistent_cache):
-    assert pallas_kernels.supported(dtype, groups)
-    compiled = _compile_grouped_sum(dtype, groups, one_chip)
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("groups", GROUPS)
@@ -149,22 +133,13 @@ def _pushed_down(sql_file: str, data):
     return pushed.program, dict(plan.dict_aliases)
 
 
-@pytest.mark.parametrize("gemm", (False, True), ids=("vpu", "gemm"))
 @pytest.mark.parametrize("query", ("q1", "q6", "q1.sql", "q6.sql"))
 def test_scan_partial_fits_beside_a_resident_table(
-        query, gemm, one_chip, no_persistent_cache, monkeypatch):
+        query, one_chip, no_persistent_cache):
     """The pushdown partial program of Q1/Q6 over one scan block (the
     engine tier's hand-made programs, and what the walk composes from
-    the benchmark's SQL text), in both one-hot tiers: "vpu" is what a
-    TPU traces (kernels._gemm_is_exact() is false there; steered here
-    because the backend of this process is the CPU), "gemm" what any
-    other backend does.
-    The GEMM tier's temporaries scale with the block (Q1: 1.9 GB at
-    2^20 rows, 8.6 GB at 2^22), so at ``scan_block_rows`` both stay
-    under TEMP_SHARE of the chip."""
-    from ydb_tpu.ssa import kernels
-
-    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: gemm)
+    the benchmark's SQL text): at ``scan_block_rows`` its temporaries
+    stay under TEMP_SHARE of the chip."""
     cap = 1 << 12
     data = tpch.TpchData(sf=0.001, seed=5)
     src = ColumnSource(columns=data.tables["lineitem"],
@@ -188,21 +163,19 @@ def test_scan_partial_fits_beside_a_resident_table(
         described, (block, dict(ex.partial.aux)))
     compiled = jax.jit(ex.partial.run).lower(*args).compile()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, gemm, temp)
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, temp)
     # the block is aggregated under its filter mask: nothing sorts,
     # gathers or scatters over its rows (kernels.compact would)
     moved = [ln.strip()[:120] for ln in compiled.as_text().splitlines()
              if re.search(r"\b(sort|gather|scatter)\(", ln)
              and f"[{rows}]" in ln]
     assert not moved, (query, moved[:3])
-    if gemm and query.startswith("q1"):
-        assert temp > 1e9, "the GEMM tier's hit matrix went somewhere?"
 
 
 @pytest.mark.parametrize("which", ("first_block", "later_block"))
 @pytest.mark.parametrize("query", ("q1.sql", "q6.sql"))
 def test_mesh_walk_block_program_moves_no_rows(
-        query, which, one_chip, no_persistent_cache, monkeypatch):
+        query, which, one_chip, no_persistent_cache):
     """What the mesh walk's aggregate pushdown enqueues a resident
     block on the block's own chip (parallel/dist.py, MeshScan): the
     slot-aligned partial of the benchmark's statement, and for every
@@ -211,9 +184,7 @@ def test_mesh_walk_block_program_moves_no_rows(
     its filter mask: nothing sorts, gathers or scatters over its rows,
     and the temporaries fit beside a resident slice."""
     from ydb_tpu.parallel.dist import MeshScan
-    from ydb_tpu.ssa import kernels
 
-    monkeypatch.setattr(kernels, "_gemm_is_exact", lambda: False)
     cap = 1 << 12
     data = tpch.TpchData(sf=0.001, seed=5)
     src = ColumnSource(columns=data.tables["lineitem"],

@@ -26,8 +26,8 @@ every injected fault + cancellation must still drain to zero.
 
 Disabled (the default), every :func:`track` site costs one module-global
 bool check returning ``None`` and every :func:`close` a ``None`` test —
-safe to leave compiled into hot paths. ``kernelbench
---leaksan-overhead`` holds that budget. Like ``sanitizer``, this module
+safe to leave compiled into hot paths (tests/test_lifecycle.py holds
+the zero count over a whole scan). Like ``sanitizer``, this module
 keeps a bare dependency set (os + threading + traceback) so the
 low-level runtime modules can import it unconditionally.
 """

@@ -273,9 +273,8 @@ class PortionChunkReader:
         """One chunk's (columns, validity). ``zero_copy`` decodes to
         read-only views into the fetched buffer (the morsel pipeline's
         decode discipline — see ``_unpack_chunk_view``); the default
-        copies via ``np.load`` (the legacy serialized-path decode,
-        kept bit-for-bit as the ``YDB_TPU_STREAM_PIPELINE=0``
-        reference)."""
+        copies via ``np.load`` (the serialized chain's and the
+        whole-portion readers' decode)."""
         from ydb_tpu.obs import timeline
 
         unpack = _unpack_chunk_view if zero_copy else _unpack_chunk

@@ -512,33 +512,19 @@ class PortionStreamSource:
         cap = min(block_rows, max(self.num_rows, 1))
         clusters = plan_clusters(self.metas, self.dedup)
         if start_block == 0:
+            # morsel-driven pipeline: out-of-order IO/decode on the
+            # stream conveyor, in-order assembly, double-buffered
+            # slabs, resident-tier placement folded in
             from ydb_tpu.engine import stream_sched
 
-            if stream_sched.pipeline_enabled():
-                # morsel-driven pipeline: out-of-order IO/decode on the
-                # stream conveyor, in-order assembly, double-buffered
-                # slabs — resident-tier placement folded in. Count-based
-                # resume (start_block) keeps the serialized path: its
-                # block arithmetic must not depend on pipeline state.
-                yield from stream_sched.stream_pipeline(
-                    [(self, clusters)], names, sch, cap,
-                    timer=self.timer, prefetch=self.prefetch,
-                    owner=self)
-                return
-        res = getattr(self.shard, "resident", None)
-        if start_block == 0 and res is not None and res.enabled():
-            # HBM-resident fast path: portions with pinned decoded
-            # columns assemble blocks device-side; the rest stage
-            # through the host path mid-stream. Count-based resume
-            # (start_block) stays on the host path — its block
-            # boundaries must not depend on what happens to be
-            # resident at resume time.
-            from ydb_tpu.engine import resident as resident_mod
-
-            yield from resident_mod.stream_resident(
-                self, clusters, names, sch, cap,
-                timer=self.timer, prefetch=self.prefetch)
+            yield from stream_sched.stream_pipeline(
+                [(self, clusters)], names, sch, cap,
+                timer=self.timer, prefetch=self.prefetch,
+                owner=self)
             return
+        # count-based resume (a DQ checkpoint seek) takes the serialized
+        # chain: its block arithmetic must depend neither on pipeline
+        # state nor on what happens to be resident at resume time
         yield from stream_blocks(
             self.payload_stream(clusters, names), names, sch, cap,
             start_block=start_block, prefetch=self.prefetch,
@@ -550,7 +536,7 @@ class PortionStreamSource:
     # (DQ checkpoint seek) must count actual emissions, not estimate.
 
 
-#: test/bench override for the staging lookahead: an int forces the
+#: test override for the staging lookahead: an int forces the
 #: depth, None reads the (cached) environment — the FUSE_FORCE pattern
 PREFETCH_DEPTH_FORCE: "int | None" = None
 
@@ -870,35 +856,15 @@ class MultiShardStreamSource:
         sch = self._base_schema.select(names)
         cap = min(block_rows, max(self.num_rows, 1))
         if start_block == 0:
+            # one scheduler spans ALL shards: IO morsels of shard k+1
+            # fly while shard k's blocks are consumed, under a single
+            # byte budget and one block capacity (one compiled program)
             from ydb_tpu.engine import stream_sched
 
-            if stream_sched.pipeline_enabled():
-                # one scheduler spans ALL shards: IO morsels of shard
-                # k+1 fly while shard k's blocks are consumed, under a
-                # single byte budget and one block capacity (one
-                # compiled program)
-                yield from stream_sched.stream_pipeline(
-                    [(sub, plan_clusters(sub.metas, sub.dedup))
-                     for sub in self.subs],
-                    names, sch, cap, timer=self.timer, owner=self)
-                return
-        if start_block == 0 and any(
-                getattr(sub.shard, "resident", None) is not None
-                and sub.shard.resident.enabled() for sub in self.subs):
-            # resident-aware SQL path: one mixed item stream across all
-            # shards keeps a single block capacity (one compiled
-            # program), while each shard's portions serve from its own
-            # resident store or stage through the host path
-            from ydb_tpu.engine import resident as resident_mod
-
-            def items():
-                for sub in self.subs:
-                    clusters = plan_clusters(sub.metas, sub.dedup)
-                    yield from resident_mod.scan_items(sub, clusters,
-                                                       names)
-
-            yield from pump_blocks(resident_mod.mixed_blocks(
-                items(), names, sch, cap, timer=self.timer))
+            yield from stream_sched.stream_pipeline(
+                [(sub, plan_clusters(sub.metas, sub.dedup))
+                 for sub in self.subs],
+                names, sch, cap, timer=self.timer, owner=self)
             return
 
         def payloads():

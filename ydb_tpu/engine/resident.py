@@ -30,11 +30,11 @@ then cold-by-access portions (LRU heat). Invalidation is by immutable
 portion id: compaction/TTL rewrites mint NEW ids, old ids keep serving
 readers at old snapshots until GC drops them from the portion map.
 
-Gates: ``YDB_TPU_RESIDENT=0`` disables the tier everywhere (scans take
-exactly the pre-tier path — the A/B bit-identity switch); ``=1`` forces
-it on even on CPU backends (where the default budget is 0 because
-"device" memory is host RSS). ``RESIDENT_FORCE`` is the in-process
-override for tests/bench A/B without environment mutation.
+Gates: ``YDB_TPU_RESIDENT=0`` disables the tier everywhere (every
+portion stages through the host path, with bit-identical answers);
+``=1`` forces it on even on CPU backends (where the default budget is 0
+because "device" memory is host RSS). ``RESIDENT_FORCE`` is the
+in-process override for tests, without environment mutation.
 """
 
 from __future__ import annotations
@@ -56,11 +56,11 @@ from ydb_tpu.obs.probes import probe
 _P_PROMOTE = probe("resident.promote")
 _P_EVICT = probe("resident.evict")
 
-#: test/bench override: True/False forces the gate, None = environment
+#: test override: True/False forces the gate, None = environment
 RESIDENT_FORCE: "bool | None" = None
 
 #: budget when the gate is FORCED on where the device reports no HBM
-#: (CPU tests and kernelbench A/Bs: the bytes are host memory)
+#: (CPU tests: the bytes are host memory)
 FORCED_BYTES = 4 << 30
 
 #: every live store: ResidentStore.budget() reads the others' bytes
@@ -555,45 +555,6 @@ def portion_loader(shard, meta):
     return load
 
 
-def scan_items(source, clusters, names):
-    """One shard's scan stream as ('dev', entries, rows) /
-    ('host', cols, valid) items, preserving global row order.
-
-    Resident portions serve decoded device arrays; everything else
-    (K-way dedup merges, cold portions, disabled stores) falls through
-    to the existing host payload path mid-stream. Host-path portions
-    count heat; crossing the threshold queues an async promotion so the
-    NEXT scan finds them resident."""
-    shard = source.shard
-    store = getattr(shard, "resident", None)
-    on = store is not None and store.enabled()
-    pk = shard.pk_column
-    for cl in clusters:
-        if source.dedup and pk is not None and len(cl) > 1:
-            # a K-way newest-wins merge rewrites rows; its output is
-            # not any single portion's columns — host path only
-            for cols, valid in source._iter_merged(cl, names):
-                yield ("host", cols, valid)
-            continue
-        for m in cl:
-            if on:
-                ent = store.lookup(m.portion_id, names)
-                if ent is not None:
-                    source.resident_hits += 1
-                    source.resident_rows += m.num_rows
-                    # bytes served straight from HBM — the movement the
-                    # resident tier SAVED the staged pipeline
-                    timeline.add_bytes("resident_bytes", sum(
-                        e.nbytes for e in ent.values()))
-                    yield ("dev", ent, m.num_rows)
-                    continue
-                if store.record_miss(m.portion_id):
-                    store.promote_async(m.portion_id, m.num_rows,
-                                        portion_loader(shard, m))
-            for cols, valid in source._iter_plain([m], names):
-                yield ("host", cols, valid)
-
-
 def _device_blocks(run, names, sch, cap, timer):
     """Cut a RUN of consecutive resident portions into
     capacity-``cap`` TableBlocks by device-side slice + concat.
@@ -713,16 +674,3 @@ def mixed_blocks(items, names, sch, cap, timer=None):
             {m: np.empty(0, dtype=sch.field(m).type.physical)
              for m in names},
             {m: np.empty(0, dtype=bool) for m in names})
-
-
-def stream_resident(source, clusters, names, sch, cap,
-                    timer=None, prefetch=True):
-    """Resident-aware block stream for one PortionStreamSource, with the
-    same conveyor-prefetch shape as ``reader.stream_blocks``: blob IO,
-    host staging AND device assembly all run on a worker ahead of the
-    consumer's compute."""
-    from ydb_tpu.engine.reader import pump_blocks
-
-    gen = mixed_blocks(scan_items(source, clusters, names), names, sch,
-                       cap, timer=timer)
-    return pump_blocks(gen, prefetch=prefetch)

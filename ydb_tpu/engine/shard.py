@@ -177,7 +177,7 @@ class ColumnShard:
         # compute seconds) — obs surface for bench + the viewer
         self.last_scan_stages: dict = {}
         # morsel-pipeline stat snapshot of the most recent scan
-        # (engine.stream_sched); None when the serialized path ran
+        # (engine.stream_sched); None on a cache replay
         self.last_scan_pipeline: "dict | None" = None
         # pruning effectiveness of the most recent scan plus cumulative
         # totals (obs: columnshard.scan.pruning probe, sys_scan_pruning
@@ -684,8 +684,7 @@ class ColumnShard:
         # per-scan stage attribution (read/merge/stage/compute seconds)
         self.last_scan_stages = timer.snapshot()
         # morsel-pipeline attribution (engine.stream_sched): stats are
-        # set when the pipelined stream finishes; None on the
-        # serialized path (YDB_TPU_STREAM_PIPELINE=0) and cache replays
+        # set when the pipelined stream finishes; None on cache replays
         self.last_scan_pipeline = src.last_pipeline
         pruning = {
             "portions_total": len(visible),

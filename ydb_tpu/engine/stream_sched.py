@@ -22,10 +22,9 @@ ColumnShard scan:
     ``reader.pump_blocks``: the depth-bounded block queue IS the
     double-buffered device slab — H2D transfer of block k+1 overlaps
     compute on block k;
-  * placement is resident-tier-aware: HBM-resident portions yield
-    device items (zero movement) while cold portions stream behind
-    them, with the same heat/promotion bookkeeping as
-    ``resident.scan_items``;
+  * placement is resident-tier-aware (``plan_morsels``): HBM-resident
+    portions yield device items (zero movement) while cold portions
+    stream behind them and count heat towards an async promotion;
   * admission back-pressures on a byte budget (``YDB_TPU_STREAM_BYTES``
     of estimated decoded bytes in flight), so peak host memory stays
     inside the OOC valve no matter how many portions survive pruning.
@@ -39,13 +38,13 @@ a task that cannot run. K-way dedup merges stay inline in the assembly
 stage (their cursors are inherently sequential); their chunk reads
 still ride the retry policy.
 
-Gates: ``YDB_TPU_STREAM_PIPELINE=0`` is the escape hatch back to the
-serialized path (the A/B bit-identity switch); ``PIPELINE_FORCE`` is
-the in-process override for tests/bench, same contract as
-``FUSE_FORCE``/``RESIDENT_FORCE``. Results are bit-identical either
-way: the pipeline reuses the serialized path's chunk reader, payload
-boundaries, ``rechunk`` re-cutting and block assembly, only the
-threads change.
+Every first-pass scan streams through here; only a count-based resume
+(``start_block > 0``, a DQ checkpoint seek) takes the serialized chain
+(``reader.stream_blocks`` over ``payload_stream``), whose block
+arithmetic must not depend on pipeline or residency state. Rows are
+bit-identical between the two: the pipeline reuses the serialized
+chain's chunk reader, payload boundaries, ``rechunk`` re-cutting and
+block assembly, only the threads change.
 """
 
 from __future__ import annotations
@@ -60,19 +59,6 @@ from ydb_tpu.chaos import deadline as statement_deadline
 from ydb_tpu.engine.portion import (_TRANSIENT_READ, PortionChunkReader,
                                     project_chunk)
 from ydb_tpu.obs import timeline
-
-#: test/bench override: True/False forces the gate, None = environment
-PIPELINE_FORCE: "bool | None" = None
-
-
-def pipeline_enabled() -> bool:
-    """Morsel-pipeline gate, default ON (YDB_TPU_STREAM_PIPELINE=0 is
-    the serialized-path escape hatch for A/B and emergencies)."""
-    if PIPELINE_FORCE is not None:
-        return PIPELINE_FORCE
-    return os.environ.get("YDB_TPU_STREAM_PIPELINE", "1") \
-        not in ("0", "", "off")
-
 
 def morsel_bytes() -> int:
     """Decoded-byte budget of ONE morsel: big enough that per-task
@@ -161,10 +147,16 @@ def plan_morsels(parts, names):
 
     Pulled incrementally by the scheduler's admission loop, so header
     reads and resident lookups happen only as far ahead as the byte
-    budget allows. Chunk pruning (PK range + zone predicates) and the
-    resident-tier heat/promotion bookkeeping happen here, identical to
-    ``_iter_plain`` / ``resident.scan_items`` — pruned chunks never
-    become flights."""
+    budget allows. Chunk pruning (PK range + zone predicates, as
+    ``_iter_plain``) happens here: pruned chunks never become flights.
+
+    This is THE placement rule of a scan. A resident portion serves its
+    decoded device arrays. Everything else (cold portions, disabled
+    stores) stages through the host path mid-stream; a host-path read
+    counts heat, and crossing the threshold queues an async promotion
+    so the NEXT scan finds the portion resident. A K-way newest-wins
+    merge rewrites rows, so its output is no single portion's columns:
+    host path only."""
     from ydb_tpu.engine import resident as resident_mod
     from ydb_tpu.engine.reader import _chunk_selected
 
@@ -433,9 +425,9 @@ class StreamScheduler:
 
     def items(self):
         """The in-order ('dev'/'host') item stream for
-        ``resident.mixed_blocks`` — identical item order and payload
-        boundaries to ``resident.scan_items`` over the same clusters
-        (and, with no resident store, to ``payload_stream``)."""
+        ``resident.mixed_blocks`` — with no resident store, identical
+        item order and payload boundaries to ``payload_stream`` over
+        the same clusters."""
         try:
             while True:
                 self._admit()

@@ -29,8 +29,8 @@ the background cadence (rates fall out of the Prometheus scrape).
 Gating: the ring is OFF by default (``YDB_TPU_TIMELINE=1`` enables;
 ``TIMELINE_FORCE`` is the in-process override, same contract as
 ``tracing.PROFILE_FORCE``). Disabled, every record site is one flag
-check + one environment lookup — kernelbench's ``--profile-overhead``
-A/B asserts the disabled path stays inside the profiling budget.
+check + one environment lookup, and a whole profiled statement leaves
+the ring untouched (tests/test_timeline.py).
 """
 
 from __future__ import annotations
@@ -45,7 +45,7 @@ import time
 
 from ydb_tpu.analysis import sanitizer
 
-#: test/bench override: True/False forces the timeline regardless of
+#: test override: True/False forces the timeline regardless of
 #: the environment (same contract as tracing.PROFILE_FORCE).
 TIMELINE_FORCE: "bool | None" = None
 

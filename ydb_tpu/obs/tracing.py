@@ -58,8 +58,8 @@ from ydb_tpu.obs import timeline
 
 _ids = itertools.count(1)
 
-#: test/bench override: True/False forces profiling regardless of the
-#: environment (same contract as kernels.FUSED_FORCE).
+#: test override: True/False forces profiling regardless of the
+#: environment (same contract as plan_fuse.FUSE_FORCE).
 PROFILE_FORCE: bool | None = None
 
 

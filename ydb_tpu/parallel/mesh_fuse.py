@@ -73,7 +73,8 @@ from ydb_tpu.ssa.plan_fuse import (
 )
 from ydb_tpu.ssa.program import SortStep, WindowStep
 
-#: in-process override (bench/test A/B seam); None defers to the env
+#: in-process override (tests pick the executor with it); None defers
+#: to the env
 MESH_FUSE_FORCE: "bool | None" = None
 
 

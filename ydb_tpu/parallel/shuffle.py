@@ -31,8 +31,9 @@ import jax.numpy as jnp
 from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.parallel.mesh import SHARD_AXIS
 
-#: in-process override for stats-sized buckets (bench A/B seam); None
-#: defers to the YDB_TPU_SHUFFLE_STATS environment gate
+#: in-process override for stats-sized buckets (tests compare both
+#: sizings with it); None defers to the YDB_TPU_SHUFFLE_STATS
+#: environment gate
 SHUFFLE_STATS_FORCE: "bool | None" = None
 
 #: headroom over the mean per-destination load: absorbs ordinary hash

@@ -92,9 +92,8 @@ class Database:
     table_stats: dict | None = None
     # rows per scan block, for every scan this Database's statements
     # run (the cluster hands down its shards' scan_block_rows): the
-    # pushdown program's device temporaries scale with it — in the
-    # GEMM one-hot tier Q1's partial takes 1.9 GB at 2^20 rows and
-    # 8.6 GB at 2^22, compiled for a v5e
+    # pushdown program's device temporaries scale with it (compiled
+    # for a v5e, Q1's partial keeps 68 MB of them at 2^22 rows)
     scan_block_rows: int = DEFAULT_BLOCK_ROWS
 
     def invalidate_compile_cache(self):

@@ -1080,19 +1080,16 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
     }
 
     def trace_fused(env, aux, live, gid, ng, kcols, capacity):
-        """Fused lowering: ONE shared hit expansion per GroupByStep.
+        """ONE shared hit expansion per GroupByStep.
 
         All linear aggregates (COUNT/SUM/AVG/VAR/STDDEV states) stack
-        into per-accumulator-dtype banks and reduce with one
-        ``hits.T @ stacked`` contraction each
-        (kernels.fused_group_reduce); MIN/MAX and the key columns reuse
-        the same bool hit matrix — where the per-aggregate path expands
-        (rows x groups) once per aggregate AND once per key.
+        into per-accumulator-dtype banks, each reduced by one
+        kernels.fused_group_reduce; MIN/MAX and the key columns reuse
+        the same bool hit matrix.
         """
         onehot = ng <= kernels.ONEHOT_GROUP_LIMIT
-        # counts ride the f64 GEMM bank in the one-hot tier (exact below
-        # 2^53, merges with the AVG/VAR sums into one matmul); the
-        # large-group tier keeps them int32 so they stay Pallas-eligible
+        # counts share the f64 bank of the AVG/VAR sums in the one-hot
+        # tier (exact below 2^53); the large-group tier keeps them int32
         count_dt = jnp.float64 if onehot else jnp.int32
 
         bank_vecs: dict = {}   # accumulator dtype -> list of row vectors
@@ -1152,11 +1149,12 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
                 slot(("vsq", spec.column), jnp.float64,
                      lambda _mk=mk_vals: _mk() ** 2)
 
-        results = kernels.fused_group_reduce_banks(
-            {dtype: (vecs[0][:, None] if len(vecs) == 1
-                     else jnp.stack(vecs, axis=1))
-             for dtype, vecs in bank_vecs.items()},
-            gid, ng)
+        banks = {dtype: (vecs[0][:, None] if len(vecs) == 1
+                         else jnp.stack(vecs, axis=1))
+                 for dtype, vecs in bank_vecs.items()}
+        results = {dtype: kernels.fused_group_reduce(
+                       stacked, gid, ng, dtype=dtype)
+                   for dtype, stacked in banks.items()}
 
         def state(key):
             dtype, i = slot_ix[key]
@@ -1279,102 +1277,6 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
             new_env[spec.out_name] = Column(data, valid)
         return new_env, group_live
 
-    def trace_peragg(env, aux, live, gid, ng, kcols):
-        """Reference lowering: one independent scatter/one-hot reduction
-        per aggregate (the pre-fusion path, kept as the A/B baseline —
-        kernels.fused_group_by_enabled() selects at trace time)."""
-        # counts accumulate in int32 per block (a block holds < 2^31
-        # rows) and widen after: int32 is what the Pallas one-hot
-        # reduction supports, so COUNT/AVG-count ride the MXU-friendly
-        # path on TPU instead of the serialized scatter
-        live_count = kernels.scatter_sum(
-            jnp.ones_like(gid, dtype=jnp.int32), live, gid, ng,
-            dtype=jnp.int32,
-        ).astype(jnp.int64)
-        group_live = live_count > 0
-
-        new_env: dict[str, Column] = {}
-        for k, c in zip(key_names, kcols):
-            kd = kernels.scatter_first(c.data, live, gid, ng)
-            kv = kernels.scatter_first(c.validity, live, gid, ng)
-            new_env[k] = Column(kd, kv & group_live)
-
-        for spec, t in specs:
-            if spec.func is Agg.COUNT_ALL:
-                data = live_count
-                # keyless COUNT over zero rows is 0, not NULL
-                valid = (
-                    jnp.ones_like(group_live) if not key_names else group_live
-                )
-            else:
-                c = env[spec.column]
-                vrow = live & c.validity
-                nn = kernels.scatter_sum(
-                    jnp.ones_like(gid, dtype=jnp.int32), vrow, gid, ng,
-                    dtype=jnp.int32,
-                ).astype(jnp.int64)
-                if spec.func is Agg.COUNT:
-                    data = nn
-                    valid = (
-                        jnp.ones_like(group_live)
-                        if not key_names
-                        else group_live
-                    )
-                elif spec.func is Agg.SUM:
-                    data = kernels.scatter_sum(
-                        c.data, vrow, gid, ng, dtype=t.physical
-                    )
-                    valid = nn > 0
-                elif spec.func in (Agg.MIN, Agg.MAX):
-                    vals = c.data
-                    packed = spec.column in str_rank_aux
-                    if packed:
-                        rank = kernels.dict_gather(
-                            aux[str_rank_aux[spec.column]], c
-                        ).data
-                        vals = (
-                            rank.astype(jnp.int64) << 32
-                        ) | c.data.astype(jnp.int64)
-                    if spec.func is Agg.MIN:
-                        data = kernels.scatter_min(vals, vrow, gid, ng)
-                    else:
-                        data = kernels.scatter_max(vals, vrow, gid, ng)
-                    if packed:
-                        data = (data & 0xFFFFFFFF).astype(jnp.int32)
-                    valid = nn > 0
-                elif spec.func is Agg.AVG:
-                    src_t = cur_types[spec.column]
-                    s = kernels.scatter_sum(
-                        c.data, vrow, gid, ng, dtype=jnp.float64
-                    )
-                    if src_t.is_decimal:
-                        s = s / (10.0 ** src_t.scale)
-                    data = s / jnp.maximum(nn, 1)
-                    valid = nn > 0
-                elif spec.func is Agg.SOME:
-                    data = kernels.scatter_first(c.data, vrow, gid, ng)
-                    valid = nn > 0
-                elif spec.func in (Agg.VAR_SAMP, Agg.STDDEV_SAMP):
-                    src_t = cur_types[spec.column]
-                    vals = c.data.astype(jnp.float64)
-                    if src_t.is_decimal:
-                        vals = vals / (10.0 ** src_t.scale)
-                    s = kernels.scatter_sum(
-                        vals, vrow, gid, ng, dtype=jnp.float64)
-                    q = kernels.scatter_sum(
-                        vals * vals, vrow, gid, ng, dtype=jnp.float64)
-                    nf = nn.astype(jnp.float64)
-                    var = (q - s * s / jnp.maximum(nf, 1.0)) \
-                        / jnp.maximum(nf - 1.0, 1.0)
-                    var = jnp.maximum(var, 0.0)  # fp cancellation
-                    data = (jnp.sqrt(var)
-                            if spec.func is Agg.STDDEV_SAMP else var)
-                    valid = nn > 1
-                else:
-                    raise NotImplementedError(spec.func)
-            new_env[spec.out_name] = Column(data, valid)
-        return new_env, group_live
-
     def lower(env, aux, live):
         kcols = [env[k] for k in key_names]
         capacity = next(iter(env.values())).data.shape[0]
@@ -1402,12 +1304,8 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
             gid = jnp.where(live, 0, 1).astype(jnp.int32)
             ng = 1
 
-        if kernels.fused_group_by_enabled():
-            new_env, group_live = trace_fused(
-                env, aux, live, gid, ng, kcols, capacity)
-        else:
-            new_env, group_live = trace_peragg(
-                env, aux, live, gid, ng, kcols)
+        new_env, group_live = trace_fused(
+            env, aux, live, gid, ng, kcols, capacity)
 
         if key_names and keep_slots:
             # mesh-mergeable layout: every slot stays in place; dead slots

@@ -20,8 +20,6 @@ traced int32 scalar, never a shape.
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -227,33 +225,14 @@ def group_ids_sorted(
 #: becomes "lane-broadcast compare + reduce".
 ONEHOT_GROUP_LIMIT = 512
 
-#: test/bench override for the fused multi-aggregate group-by lowering
-#: (compiler._resolve_group_by): True/False forces the decision
-#: regardless of the environment. Consulted at TRACE time — rebuild
-#: executors to switch (same contract as pallas_kernels.FORCE).
-FUSED_FORCE: bool | None = None
-
-
-def fused_group_by_enabled() -> bool:
-    """Whether GroupByStep lowers through the fused single-contraction
-    path (one shared hit matrix + one ``hits.T @ stacked`` matmul per
-    accumulator dtype) instead of one independent one-hot reduction per
-    aggregate. Default on; YDB_TPU_FUSED_GROUPBY=0 restores the
-    per-aggregate path (the A/B baseline)."""
-    if FUSED_FORCE is not None:
-        return FUSED_FORCE
-    return os.environ.get("YDB_TPU_FUSED_GROUPBY", "1") not in (
-        "0", "", "off")
-
 
 def group_hits(gid: jax.Array, num_groups: int) -> jax.Array:
     """bool (rows x groups) one-hot hit matrix from drop-encoded group
     ids (dead/invalid rows carry gid >= num_groups and match no group).
 
-    This is THE shared expansion of the fused group-by: built once per
+    This is THE shared expansion of the group-by: built once per
     GroupByStep and reused by every linear bank, MIN/MAX reduction and
-    the per-group first-row index — where the per-aggregate path
-    re-expanded (rows x groups) once per aggregate."""
+    the per-group first-row index."""
     groups = jnp.arange(num_groups, dtype=jnp.int32)
     return gid[:, None] == groups[None, :]
 
@@ -274,39 +253,34 @@ def first_live_index(hits: jax.Array) -> tuple[jax.Array, jax.Array]:
 @jax.named_scope("ydb.fused_group_reduce")
 def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
                        num_groups: int, dtype=None) -> jax.Array:
-    """All linear aggregates in one contraction: (rows x slots) stacked
-    inputs -> (groups x slots) per-group sums.
+    """All linear aggregates of one accumulator dtype in one reduction:
+    (rows x slots) stacked inputs -> (groups x slots) per-group sums.
 
     ``stacked`` columns are pre-masked (invalid contributions already
-    zero); ``gid`` is drop-encoded (dead rows >= num_groups). Tiers:
+    zero); ``gid`` is drop-encoded (dead rows >= num_groups). The tier
+    follows from the group count alone, the same on every backend:
 
-      * groups <= ONEHOT_GROUP_LIMIT — ONE dense matmul
-        ``hits.T @ stacked``: the hit matrix materializes once and the
-        contraction rides the platform GEMM (MXU on TPU, vendor BLAS on
-        CPU) — the TQP move of expressing group-by as matrix algebra.
-      * larger, Pallas-eligible dtype — the fused multi-column one-hot
+      * groups <= ONEHOT_GROUP_LIMIT: one masked where+sum per slot
+        over the shared hit matrix, on the vector unit. Exact in every
+        dtype (int64 decimal sums are integer adds). Not a GEMM: a TPU
+        has no f64 and no s64 dot, XLA splits an f64 dot into f32 MXU
+        passes whose accumulators round integer sums (TPC-H Q1 came
+        back with wrong decimal sums from a [72x12]^T [72x23] dot on a
+        v5e), and the emulated f64 GEMM measured 145 ms against 1.9 ms
+        for 2^20 rows x 11 int64 slots there.
+      * larger, Pallas-eligible dtype: the fused multi-column one-hot
         tile kernel (pallas_kernels.grouped_sum_multi).
-      * otherwise — one 2D scatter-add (still one pass for all slots,
-        vs one scatter per aggregate on the per-agg path).
-
-    Integer banks contract in integer dtype, so int64 decimal sums stay
-    exact — only the summation ORDER differs from the scatter path,
-    which for ints is no difference at all.
+      * otherwise: one 2D scatter-add, one pass for all slots.
     """
     dtype = jnp.dtype(dtype or stacked.dtype)
     stacked = stacked.astype(dtype)
     if num_groups <= ONEHOT_GROUP_LIMIT:
-        if not _gemm_is_exact():
-            return _masked_group_sums(stacked, gid, num_groups)
-        if stacked.shape[0] < _INT_LIMB_MAX_ROWS:
-            # f64 GEMM via the bank encoder (exact for ints through
-            # 24-bit limbs — XLA's CPU integer dot is a naive loop)
-            return fused_group_reduce_banks(
-                {dtype: stacked}, gid, num_groups)[dtype]
-        hits = group_hits(gid, num_groups).astype(dtype)
-        return jax.lax.dot_general(
-            hits, stacked, (((0,), (0,)), ((), ())),
-            preferred_element_type=dtype)
+        hits = group_hits(gid, num_groups)
+        zero = jnp.zeros((), dtype=dtype)
+        return jnp.stack(
+            [jnp.sum(jnp.where(hits, stacked[:, j][:, None], zero), axis=0,
+                     dtype=dtype)
+             for j in range(stacked.shape[1])], axis=1)
     from ydb_tpu.ssa import pallas_kernels
 
     if pallas_kernels.enabled() and pallas_kernels.supported_fused(
@@ -314,102 +288,6 @@ def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
         return pallas_kernels.grouped_sum_multi(stacked, gid, num_groups)
     out = jnp.zeros((num_groups, stacked.shape[1]), dtype=dtype)
     return out.at[gid].add(stacked, mode="drop")
-
-
-def _gemm_is_exact() -> bool:
-    """Whether the one-hot tier may ride the platform GEMM. Consulted at
-    TRACE time, like ``pallas_kernels.enabled()``.
-
-    Not on a TPU: it has no f64, so XLA splits an f64 dot into f32 MXU
-    passes whose f32 accumulators round the integer limb sums (measured
-    on a v5e: TPC-H Q1 at SF 1 came back with wrong decimal sums from
-    the final program's [72x12]^T [72x23] dot, while a 2^20-row dot
-    happened to be lowered exactly), and it has no s64 dot at all
-    (``UNIMPLEMENTED ... rewriting computation to not contain X64``).
-    There the tier reduces on the vector unit (_masked_group_sums),
-    which on the same chip is also ~80x faster than the emulated f64
-    GEMM (1.9 ms against 145 ms for 2^20 rows x 11 int64 slots)."""
-    return jax.default_backend() != "tpu"
-
-
-def _masked_group_sums(stacked: jax.Array, gid: jax.Array,
-                       num_groups: int) -> jax.Array:
-    """(rows x slots) -> (groups x slots) sums as one masked where+sum
-    per slot over the shared hit matrix: exact in every dtype (int64
-    adds on the vector unit), no GEMM."""
-    hits = group_hits(gid, num_groups)
-    zero = jnp.zeros((), dtype=stacked.dtype)
-    return jnp.stack(
-        [jnp.sum(jnp.where(hits, stacked[:, j][:, None], zero), axis=0)
-         for j in range(stacked.shape[1])], axis=1)
-
-
-#: 24-bit-limb exactness bound: each limb column sums < 2^24 * rows, so
-#: rows below this keep every limb sum inside f64's 2^53 integer range.
-_INT_LIMB_MAX_ROWS = 1 << 29
-#: up to here TWO 32-bit limbs suffice ((2^32-1) * 2^21 < 2^53) — one
-#: fewer encoded column per integer slot; typical block capacities
-#: (<= 2^21) all take this path.
-_INT_LIMB2_MAX_ROWS = 1 << 21
-
-
-@jax.named_scope("ydb.fused_group_reduce_banks")
-def fused_group_reduce_banks(banks: dict, gid: jax.Array,
-                             num_groups: int) -> dict:
-    """All of a GroupByStep's linear banks in ONE contraction.
-
-    ``banks`` maps accumulator dtype -> (rows x slots) pre-masked
-    values. In the one-hot tier every bank encodes into a single f64
-    matrix — float banks as-is, integer banks as three 24-bit limb
-    columns (v = c2*2^48 + c1*2^24 + c0; each limb sum stays an exact
-    f64 integer below _INT_LIMB_MAX_ROWS rows, so the recombined int64
-    is bit-exact) — and contracts against ONE materialized f64 hit
-    matrix via the platform GEMM. XLA's CPU s64 dot is a naive loop
-    (~4x slower than per-aggregate reductions); the limb trick keeps
-    integer exactness while riding BLAS/MXU. The large-group tier
-    reduces each bank via fused_group_reduce (Pallas / 2D scatter).
-    """
-    rows = next(iter(banks.values())).shape[0] if banks else 0
-    if num_groups > ONEHOT_GROUP_LIMIT or rows >= _INT_LIMB_MAX_ROWS \
-            or not _gemm_is_exact():
-        return {dt: fused_group_reduce(st, gid, num_groups, dtype=dt)
-                for dt, st in banks.items()}
-    if rows <= _INT_LIMB2_MAX_ROWS:
-        shifts, mask = (0, 32), 0xFFFFFFFF
-    else:
-        shifts, mask = (0, 24, 48), 0xFFFFFF
-    enc = []
-    plan = []
-    for dt, st in banks.items():
-        dt = jnp.dtype(dt)
-        n_slots = st.shape[1]
-        if jnp.issubdtype(dt, jnp.integer):
-            v = st.astype(jnp.int64)
-            for s in shifts[:-1]:
-                enc.append(((v >> s) & mask).astype(jnp.float64))
-            enc.append((v >> shifts[-1]).astype(jnp.float64))
-            plan.append((dt, n_slots, True))
-        else:
-            enc.append(st.astype(jnp.float64))
-            plan.append((dt, n_slots, False))
-    mat = jnp.concatenate(enc, axis=1) if len(enc) > 1 else enc[0]
-    hits = group_hits(gid, num_groups).astype(jnp.float64)
-    res = jax.lax.dot_general(hits, mat, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float64)
-    out = {}
-    off = 0
-    for dt, n_slots, is_int in plan:
-        if is_int:
-            tot = jnp.zeros((num_groups, n_slots), dtype=jnp.int64)
-            for s in shifts:
-                tot = tot + (
-                    res[:, off:off + n_slots].astype(jnp.int64) << s)
-                off += n_slots
-            out[dt] = tot.astype(dt)
-        else:
-            out[dt] = res[:, off:off + n_slots].astype(dt)
-            off += n_slots
-    return out
 
 
 def _onehot_hits(valid_row, gid, num_groups: int):
@@ -439,25 +317,6 @@ def scatter_first(values: jax.Array, valid_row, gid, num_groups: int):
     idx = jnp.where(valid_row, gid, num_groups)
     out = jnp.zeros((num_groups,) + values.shape[1:], dtype=values.dtype)
     return out.at[idx].set(values, mode="drop")
-
-
-@jax.named_scope("ydb.scatter_sum")
-def scatter_sum(values, valid_row, gid, num_groups: int, dtype=None):
-    dtype = dtype or values.dtype
-    if num_groups <= ONEHOT_GROUP_LIMIT:
-        return _onehot_reduce(values.astype(dtype), valid_row, gid,
-                              num_groups, 0, jnp.sum)
-    # larger group counts: one-hot tile kernel when eligible
-    # (ydb_tpu/ssa/pallas_kernels.py), else the XLA scatter
-    from ydb_tpu.ssa import pallas_kernels
-
-    if pallas_kernels.enabled() and pallas_kernels.supported(
-            dtype, num_groups):
-        return pallas_kernels.scatter_sum_pallas(
-            values, valid_row, gid, num_groups, dtype)
-    idx = jnp.where(valid_row, gid, num_groups)
-    out = jnp.zeros((num_groups,), dtype=dtype)
-    return out.at[idx].add(values.astype(dtype), mode="drop")
 
 
 @jax.named_scope("ydb.scatter_min")

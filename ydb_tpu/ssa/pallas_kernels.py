@@ -1,23 +1,22 @@
-"""Pallas TPU kernels for the hot group-by reduction.
+"""Pallas TPU kernel for the group-by reduction above the one-hot tier.
 
-The device group-by core is scatter_sum-by-group-id
-(ydb_tpu/ssa/kernels.py:210, the BlockCombineHashed analog,
+The device group-by core is a sum by group id
+(kernels.fused_group_reduce, the BlockCombineHashed analog,
 mkql_block_agg.cpp:1637). XLA lowers `.at[idx].add` to a serialized
 scatter on TPU; this module provides the classic TPU-native alternative
 — tile the rows, expand each tile to a one-hot (rows x groups) matrix
-in VMEM and reduce with a vectorized multiply-accumulate — which keeps
-the VPU busy instead of round-tripping a scatter.
+in VMEM and contract it against every slot column with one MXU dot —
+instead of round-tripping a scatter.
 
 Numerics: float32 accumulates exactly what the scatter path would
 (same adds, different order — fp addition reorders are inherent to any
-parallel reduction); int32 accumulates in int32. Other dtypes (int64
-decimals, float64) fall back to the scatter path, so results never
-silently lose precision. Group counts <= MAX_GROUPS keep the one-hot
-tile in VMEM.
+parallel reduction). Other dtypes (int32, int64 decimals, float64) take
+the scatter path, so results never silently lose precision. Group
+counts <= MAX_GROUPS keep the one-hot tile in VMEM.
 
 On by default on a TPU backend; YDB_TPU_PALLAS=0|1 overrides
-(kernels.scatter_sum consults ``enabled()``). Tests run the same kernel
-in interpreter mode on CPU and compile it for a described v5e
+(kernels.fused_group_reduce consults ``enabled()``). Tests run the same
+kernel in interpreter mode on CPU and compile it for a described v5e
 (tests/test_tpu_compile.py).
 """
 
@@ -33,7 +32,7 @@ ROW_TILE = 1024
 MAX_GROUPS = 2048
 
 
-#: test/bench override: True/False forces the decision regardless of
+#: test override: True/False forces the decision regardless of
 #: env/backend (consulted at TRACE time — rebuild executors to switch)
 FORCE: bool | None = None
 
@@ -45,11 +44,6 @@ def enabled() -> bool:
     if v is not None:
         return v not in ("0", "", "off")
     return jax.default_backend() == "tpu"
-
-
-def supported(dtype, num_groups: int) -> bool:
-    return (jnp.dtype(dtype) in (jnp.float32, jnp.int32)
-            and num_groups <= MAX_GROUPS)
 
 
 #: fused multi-column kernel slot cap: the out tile is (groups x slots)
@@ -78,75 +72,15 @@ def _pad_rows(a: jax.Array, n: int, fill):
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
-def grouped_sum(values: jax.Array, gid: jax.Array, num_groups: int,
-                interpret: bool = False) -> jax.Array:
-    """sum of ``values`` per group id; rows with gid >= num_groups are
-    dropped (callers encode invalid rows that way, kernels.py:212)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    k_pad = max(128, -(-num_groups // 128) * 128)
-    vals = _pad_rows(values, ROW_TILE, 0)
-    gids = _pad_rows(gid.astype(jnp.int32), ROW_TILE, k_pad)
-    tiles = vals.shape[0] // ROW_TILE
-    # host-side layout: rows on the sublane axis with a unit lane, so
-    # the kernel only ever LANE-BROADCASTS (row, 1) against (row, K) —
-    # no in-kernel reshape (Mosaic rejects cross-lane shape casts)
-    vals3 = vals.reshape(tiles, ROW_TILE, 1)
-    gids3 = gids.reshape(tiles, ROW_TILE, 1)
-
-    def kernel(gid_ref, val_ref, out_ref):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():
-            out_ref[:, :] = jnp.zeros_like(out_ref)
-
-        g = gid_ref[0, :, :]          # (ROW_TILE, 1)
-        v = val_ref[0, :, :]          # (ROW_TILE, 1)
-        groups = jax.lax.broadcasted_iota(
-            jnp.int32, (ROW_TILE, k_pad), 1)
-        onehot = (g == groups).astype(val_ref.dtype)
-        # [ROW_TILE, K] * [ROW_TILE, 1] summed over rows -> [1, K]
-        out_ref[:, :] += jnp.sum(onehot * v, axis=0, keepdims=True)
-
-    # the engine runs with jax_enable_x64; Mosaic cannot legalize the
-    # implicit i64 index/constant types that mode introduces, and
-    # nothing in this kernel needs 64 bits — trace it in 32-bit mode
-    with jax.enable_x64(False):
-        out = pl.pallas_call(
-            kernel,
-            grid=(tiles,),
-            in_specs=[
-                pl.BlockSpec((1, ROW_TILE, 1), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, ROW_TILE, 1), lambda i: (i, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, k_pad), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((1, k_pad), values.dtype),
-            interpret=interpret,
-        )(gids3, vals3)
-    return out[0, :num_groups]
-
-
-def scatter_sum_pallas(values, valid_row, gid, num_groups: int,
-                       dtype=None, interpret: bool = False):
-    """Drop-in twin of kernels.scatter_sum for supported dtypes."""
-    dtype = jnp.dtype(dtype or values.dtype)
-    idx = jnp.where(valid_row, gid, num_groups)
-    return grouped_sum(values.astype(dtype), idx, num_groups,
-                       interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
 def grouped_sum_multi(values: jax.Array, gid: jax.Array, num_groups: int,
                       interpret: bool = False) -> jax.Array:
     """Fused multi-column grouped sum: (rows x slots) values ->
     (num_groups x slots) per-group sums in ONE kernel.
 
-    The fused group-by's >ONEHOT_GROUP_LIMIT tier: each row tile expands
-    to a (ROW_TILE x groups) one-hot once and contracts against ALL slot
-    columns with a single MXU dot — where ``grouped_sum`` would run the
-    expansion once per aggregate. Rows with gid >= num_groups drop.
+    The group-by's >ONEHOT_GROUP_LIMIT tier: each row tile expands to a
+    (ROW_TILE x groups) one-hot once and contracts against ALL slot
+    columns with a single MXU dot. Rows with gid >= num_groups drop
+    (callers encode dead and invalid rows that way).
     """
     from jax.experimental import pallas as pl
 
@@ -181,7 +115,9 @@ def grouped_sum_multi(values: jax.Array, gid: jax.Array, num_groups: int,
             onehot, v, (((0,), (0,)), ((), ())),
             preferred_element_type=out_ref.dtype)
 
-    # 32-bit trace for the same Mosaic i64 reason as grouped_sum
+    # the engine runs with jax_enable_x64; Mosaic cannot legalize the
+    # implicit i64 index/constant types that mode introduces, and
+    # nothing in this kernel needs 64 bits — trace it in 32-bit mode
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kernel,

@@ -24,8 +24,8 @@ The jitted function retraces per (plan fingerprint, shape-class vector)
 cache, so re-running a plan over different data of the same class reuses
 the compiled computation. Capacities only move dead padding around: the
 join/group-by kernels mask padding by liveness, so fused results are
-bit-identical to the per-fragment walk (asserted by tests and the
-kernelbench --fusion A/B).
+bit-identical to the per-fragment walk (asserted by
+tests/test_plan_fuse.py).
 
 Fusibility (``plan_signature`` returns None otherwise; the executor
 falls back to the per-node walk):
@@ -93,7 +93,8 @@ from ydb_tpu.plan.nodes import (
 )
 
 #: in-process override: True/False forces fusion on/off regardless of the
-#: environment (bench A/B seam); None defers to YDB_TPU_FUSE_PLAN
+#: environment (tests pick the executor with it); None defers to
+#: YDB_TPU_FUSE_PLAN
 FUSE_FORCE: bool | None = None
 
 #: tables above this row count keep the streaming walk. Two reasons the

@@ -30,8 +30,8 @@ from __future__ import annotations
 
 import os
 
-#: test/bench override: True/False forces stats consumption regardless
-#: of the environment (same contract as kernels.FUSED_FORCE).
+#: test override: True/False forces stats consumption regardless
+#: of the environment (same contract as plan_fuse.FUSE_FORCE).
 STATS_FORCE: bool | None = None
 
 
