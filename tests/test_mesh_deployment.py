@@ -273,6 +273,46 @@ def test_every_shard_aggregates_its_blocks_on_its_own_device(
     assert line.endswith(f"agg_pushdown={DEVICES}")
 
 
+@pytest.mark.parametrize("sid", STATEMENTS)
+def test_every_shard_assembles_its_blocks_on_its_own_device(
+        deployment, several_blocks, monkeypatch, sid):
+    """The resident tier cuts a shard's blocks by one program each
+    (``resident._assemble``) that runs where the shard's columns lie:
+    every output of every call is on the device of its pieces, the four
+    shards use the four devices, and each shard's ``scan`` span counts
+    its own calls."""
+    _, statements, sharded, _ = deployment
+    calls = collections.Counter()
+    real = resident_mod._assemble
+
+    def placed(datas, valids, bounds, *, cap):
+        out_d, out_v, length = real(datas, valids, bounds, cap=cap)
+        (dev,) = datas[0][0].devices()
+        assert all(a.devices() == {dev} for piece in datas + valids
+                   for a in piece)
+        assert all(a.devices() == {dev}
+                   for a in out_d + out_v + (length,))
+        assert cap == several_blocks
+        calls[dev] += 1
+        return out_d, out_v, length
+
+    monkeypatch.setattr(resident_mod, "_assemble", placed)
+    s = sharded.session()
+    s.execute(statements[sid]["sql"])
+    calls.clear()
+    s.execute(statements[sid]["sql"])
+    scans = by_name(s.last_profile, "scan")
+    assert len(scans) == DEVICES
+    devices = jax.devices()[:DEVICES]
+    assert set(calls) == set(devices)
+    for sp, report in zip(scans, sharded.mesh_report()):
+        blocks = -(-report["tables"]["lineitem"]["rows"] // several_blocks)
+        attrs = sp["attrs"]
+        assert attrs["resident_blocks_assembled"] == blocks
+        assert attrs["resident_blocks_whole"] == 0
+        assert calls[devices[attrs["device"]]] == blocks
+
+
 AGGS = ("sum(l_extendedprice) AS sv, count(l_tax) AS cn, count(*) AS c, "
         "avg(l_discount) AS av, min(l_quantity) AS mn, "
         "max(l_shipdate) AS mx")

@@ -429,24 +429,26 @@ def test_auto_budget_is_device_wide_not_per_store(monkeypatch):
     import weakref
 
     resident_mod.RESIDENT_FORCE = True
-    monkeypatch.setattr(resident_mod, "FORCED_BYTES", 1000)
+    monkeypatch.setattr(resident_mod, "FORCED_BYTES", 1300)
     # a ledger of its own: stores of earlier tests may still be alive
     monkeypatch.setattr(resident_mod, "_STORES", weakref.WeakSet())
     a, b = ResidentStore("dw_a"), ResidentStore("dw_b")
     fixed = ResidentStore("dw_fixed", budget=600)
-    assert a.budget() == b.budget() == 1000
-    cols = {"v": np.arange(50, dtype=np.int64)}   # 400 B + 50 B validity
+    assert a.budget() == b.budget() == 1300
+    # 50 rows are held at 64 (resident_rows), and the ledger counts the
+    # padded bytes: 512 B + 64 B validity
+    cols = {"v": np.arange(50, dtype=np.int64)}
     assert a.promote(1, 50, cols, None)
-    assert a.nbytes == 450
-    assert a.budget() == 1000            # its own bytes do not count
-    assert b.budget() == 550             # what a leaves
+    assert a.nbytes == 576
+    assert a.budget() == 1300            # its own bytes do not count
+    assert b.budget() == 724             # what a leaves
     assert fixed.budget() == 600
-    assert b.promote(1, 50, cols, None) and b.nbytes == 450
-    # b is full at 550: a second portion evicts its first, never a's
+    assert b.promote(1, 50, cols, None) and b.nbytes == 576
+    # b is full at 724: a second portion evicts its first, never a's
     assert b.promote(2, 50, cols, None)
-    assert b.nbytes == 450 and b.evictions == 1 and a.nbytes == 450
+    assert b.nbytes == 576 and b.evictions == 1 and a.nbytes == 576
     a.clear()
-    assert b.budget() == 1000
+    assert b.budget() == 1300
 
 
 def test_budgets_derive_from_the_device_report(monkeypatch):
@@ -483,3 +485,201 @@ def test_device_slice_binding_moves_resident_columns():
     st.set_device_slice(1, devs[1], 1 << 20)
     assert ent.data.devices() == ent.validity.devices() == {devs[1]}
     np.testing.assert_array_equal(np.asarray(ent.data), np.arange(8))
+
+
+# ---------------- block assembly (resident._assemble) ----------------
+
+ASM_CAP = 1 << 14
+ASM_SCHEMA = dtypes.schema(
+    ("i4", dtypes.INT32), ("i8", dtypes.INT64), ("u4", dtypes.UINT32),
+    ("f8", dtypes.DOUBLE), ("b", dtypes.BOOL),
+)
+ASM_NAMES = ASM_SCHEMA.names
+
+#: portion lengths of one resident run against ASM_CAP = 2^14 and the
+#: granule (2^13): below, on and across both, one to four portions
+ASM_RUNS = {
+    "one_portion_on_cap": (ASM_CAP,),
+    "two_portions_on_cap": (ASM_CAP, ASM_CAP),
+    "two_on_the_granule": (1 << 13, 1 << 13),
+    "tail_below_the_granule": (100,),
+    "one_row": (1,),
+    "portion_across_cap": (20_000,),
+    "across_the_granule": ((1 << 13) + 1, (1 << 13) - 1, 5),
+    "three_across_cap": (16_000, 9_000, 7_777),
+    "whole_between_pieces": (5, ASM_CAP, 3),
+    "cap_rows_off_the_block_grid": (5, ASM_CAP),
+    "four_small_in_one_block": (10, 20, 30, 40),
+    "four_mixed": (ASM_CAP, 12_345, ASM_CAP + 1, 4_000),
+}
+
+
+def _asm_portion(rng, rows):
+    cols = {
+        "i4": rng.integers(-2 ** 31, 2 ** 31, rows).astype(np.int32),
+        "i8": rng.integers(-2 ** 62, 2 ** 62, rows).astype(np.int64),
+        "u4": rng.integers(0, 2 ** 32, rows).astype(np.uint32),
+        "f8": rng.standard_normal(rows),
+        "b": rng.random(rows) < 0.5,
+    }
+    cols["f8"][::7] = -0.0
+    cols["f8"][3::11] = np.nan
+    valid = {n: rng.random(rows) < 0.8 for n in cols}
+    return cols, valid
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.uint64) if a.dtype == np.float64 else a
+
+
+class _Counts:
+    resident_blocks_whole = 0
+    resident_blocks_assembled = 0
+
+
+@pytest.mark.parametrize("case", sorted(ASM_RUNS))
+def test_assembled_blocks_equal_the_numpy_reference(case):
+    """Every block cut from a run of resident portions equals the plain
+    reference (the run's true rows concatenated, cut at ``cap``, padded
+    with 0 / False) in data, validity and ``length``, bit for bit; a
+    portion that fills a block hands over the entry's own arrays."""
+    lens = ASM_RUNS[case]
+    rng = np.random.default_rng(len(case) * 1000 + sum(lens))
+    store = ResidentStore("asm_" + case, budget=1 << 30)
+    run, host = [], []
+    src = _Counts()
+    for pid, rows in enumerate(lens):
+        cols, valid = _asm_portion(rng, rows)
+        assert store.promote(pid, rows, cols, valid)
+        ent = store.lookup(pid, ASM_NAMES)
+        held = resident_mod.resident_rows(rows)
+        assert all(e.data.shape == e.validity.shape == (held,)
+                   for e in ent.values())
+        run.append((ent, rows, src))
+        host.append((cols, valid))
+    total = sum(lens)
+    assert store.snapshot()["rows"] == total
+    assert store.nbytes == sum(
+        resident_mod.resident_rows(r) for r in lens) * (4 + 8 + 4 + 8 + 1
+                                                        + 5)
+    blocks = list(resident_mod._device_blocks(
+        run, ASM_NAMES, ASM_SCHEMA, ASM_CAP, None))
+    assert len(blocks) == -(-total // ASM_CAP)
+    starts = np.cumsum((0,) + lens)
+    whole = 0
+    for b, blk in enumerate(blocks):
+        lo, hi = b * ASM_CAP, min((b + 1) * ASM_CAP, total)
+        assert blk.length.dtype == np.int32 and int(blk.length) == hi - lo
+        for n in ASM_NAMES:
+            want_d = np.concatenate([c[n] for c, _ in host])[lo:hi]
+            want_v = np.concatenate([v[n] for _, v in host])[lo:hi]
+            pad = ASM_CAP - (hi - lo)
+            want_d = np.concatenate([want_d, np.zeros(pad, want_d.dtype)])
+            want_v = np.concatenate([want_v, np.zeros(pad, bool)])
+            col = blk.columns[n]
+            assert col.data.dtype == want_d.dtype
+            assert col.data.shape == col.validity.shape == (ASM_CAP,)
+            assert np.array_equal(_bits(col.data), _bits(want_d)), (b, n)
+            assert np.array_equal(np.asarray(col.validity), want_v), (b, n)
+        own = [p for p in range(len(lens))
+               if starts[p] == lo and lens[p] == ASM_CAP]
+        if own:
+            whole += 1
+            for n in ASM_NAMES:
+                assert blk.columns[n].data is run[own[0]][0][n].data
+                assert blk.columns[n].validity is \
+                    run[own[0]][0][n].validity
+    assert src.resident_blocks_whole == whole
+    assert src.resident_blocks_assembled == len(blocks) - whole
+
+
+def test_whole_blocks_share_one_length_scalar():
+    """The ``length`` of a block one portion fills is made once a
+    (device, value), not once a block."""
+    store = ResidentStore("asm_len", budget=1 << 30)
+    src = _Counts()
+    run = []
+    for pid in range(3):
+        assert store.promote(
+            pid, 256, {"v": np.arange(256, dtype=np.int64)}, None)
+        run.append((store.lookup(pid, ("v",)), 256, src))
+    sch = dtypes.schema(("v", dtypes.INT64))
+    blocks = list(resident_mod._device_blocks(run, ("v",), sch, 256, None))
+    assert len(blocks) == 3 and src.resident_blocks_whole == 3
+    assert blocks[0].length is blocks[1].length is blocks[2].length
+    assert int(blocks[0].length) == 256
+
+
+def _straddling_table(c, name, lens):
+    """A one-shard column table whose portions are ``lens`` rows long;
+    returns the sum of its ``v``."""
+    s = c.session()
+    s.execute(f"CREATE TABLE {name} (k int64 NOT NULL, v int64 NOT NULL, "
+              "PRIMARY KEY (k)) WITH (store = column, shards = 1)")
+    k0 = 0
+    for rows in lens:
+        k = np.arange(k0, k0 + rows, dtype=np.int64)
+        assert c.tables[name].insert({"k": k, "v": k * 3}).committed
+        k0 += rows
+    for sh in c.tables[name].shards:
+        sh.resident.drain()
+        assert sh.resident.snapshot()["portions"] == len(lens)
+    return int(np.arange(k0, dtype=np.int64).sum()) * 3
+
+
+def test_assembly_is_one_program_a_straddling_block(monkeypatch):
+    """A statement over portions that straddle blocks enters the
+    assembly once a straddling block and never for a whole one, says so
+    on its ``scan`` span, and a second table whose other portion
+    lengths fall into the same length classes builds no program."""
+    from ydb_tpu.config import AppConfig
+    from ydb_tpu.kqp.session import Cluster
+    from ydb_tpu.obs import tracing
+    from ydb_tpu.ssa import plan_fuse
+
+    monkeypatch.setattr(plan_fuse, "FUSE_MAX_ROWS", 100)
+    resident_mod.RESIDENT_FORCE = True
+    calls = []
+    real = resident_mod._assemble
+
+    def counted(datas, valids, bounds, *, cap):
+        calls.append(len(datas))
+        return real(datas, valids, bounds, cap=cap)
+
+    monkeypatch.setattr(resident_mod, "_assemble", counted)
+    c = Cluster(config=AppConfig(scan_block_rows=1024,
+                                 compact_portion_threshold=10 ** 9))
+    try:
+        # blocks of 1,024 rows: the first portion fills one; the second
+        # and third straddle the next two; the fourth is a short tail
+        want = _straddling_table(c, "t1", (1024, 1000, 1048, 500))
+        s = c.session()
+        for _ in range(2):
+            calls.clear()
+            r = s.execute("SELECT sum(v) AS x, count(*) AS n FROM t1")
+            assert int(np.asarray(r.cols["x"][0])[0]) == want
+            assert int(np.asarray(r.cols["n"][0])[0]) == 3572
+            scans = [sp for sp in s.last_profile.spans
+                     if sp["name"] == "scan"]
+            assert len(scans) == 1
+            attrs = scans[0]["attrs"]
+            assert attrs["resident_blocks_whole"] == 1
+            assert attrs["resident_blocks_assembled"] == 3
+            assert attrs["resident_portions"] == 4
+            assert calls == [2, 1, 1]        # pieces a straddling block
+        # other lengths, the same classes (1,024 / 2,048 / 512 rows
+        # held) and the same cuts: every assembly program is there
+        want = _straddling_table(c, "t2", (1024, 990, 1058, 400))
+        src = c.snapshot_db().sources["t2"]
+        built = tracing.compile_counts()["built"]
+        calls.clear()
+        blocks = list(src.blocks(1024, ("v",)))
+        assert calls == [2, 1, 1] and len(blocks) == 4
+        assert tracing.compile_counts()["built"] == built
+        assert sum(int(np.asarray(b.columns["v"].data, dtype=np.int64).sum())
+                   for b in blocks) == want
+        assert src.resident_blocks_whole == 1
+        assert src.resident_blocks_assembled == 3
+    finally:
+        c.stop()

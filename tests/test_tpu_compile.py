@@ -219,3 +219,46 @@ def test_mesh_walk_block_program_moves_no_rows(
     # the state the next block's program takes is a handful of slots
     assert all(leaf.shape[0] == 1 and leaf.size <= 1024
                for leaf in jax.tree_util.tree_leaves(state))
+
+
+@pytest.mark.parametrize("query", ("q1.sql", "q6.sql"))
+def test_block_assembly_moves_no_rows(query, one_chip,
+                                      no_persistent_cache):
+    """What the resident tier enqueues for a block that is not one whole
+    portion (engine/resident.py, ``_assemble``): at ``scan_block_rows``,
+    over the columns the benchmark's statement reads (Q1 seven, Q6
+    four), cut from two pieces as on the four-chip deployment (a
+    portion just over a block held at the next granule, and a short
+    tail). Each piece is read as one window: nothing sorts, gathers or
+    scatters over the block's rows, and the temporaries fit beside a
+    resident slice."""
+    from ydb_tpu.engine import resident
+
+    data = tpch.TpchData(sf=0.001, seed=5)
+    program, aliases = _pushed_down(query, data)
+    src = ColumnSource(columns=data.tables["lineitem"],
+                       schema=tpch.LINEITEM_SCHEMA, dicts=data.dicts)
+    names = ScanExecutor(program, src, block_rows=1 << 12,
+                         dict_aliases=aliases).read_cols
+    assert len(names) == {"q1.sql": 7, "q6.sql": 4}[query]
+    dtypes = [tpch.LINEITEM_SCHEMA.field(n).type.physical for n in names]
+    rows = ShardConfig().scan_block_rows
+    held = (resident.resident_rows(rows + 1600),
+            resident.resident_rows(305_500))
+    assert held[0] == rows + resident.GRANULE
+    assert rows % resident.GRANULE == 0
+    datas = tuple(tuple(_shape((n,), dt, one_chip) for dt in dtypes)
+                  for n in held)
+    valids = tuple(tuple(_shape((n,), "bool", one_chip) for _ in dtypes)
+                   for n in held)
+    compiled = resident._assemble.lower(
+        datas, valids, _shape((2, 3), "int32", one_chip),
+        cap=rows).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (query, temp)
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"\b(sort|gather|scatter)\(", ln)
+             and f"[{rows}]" in ln]
+    assert not moved, (query, moved[:3])
+    assert "ydb.device_blocks" in text

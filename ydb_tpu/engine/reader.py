@@ -313,6 +313,10 @@ class PortionStreamSource:
         # (engine.resident; sys_resident_store + shard.scan spans)
         self.resident_hits = 0
         self.resident_rows = 0
+        # blocks cut from resident portions: handed over as they lie
+        # (no enqueue) / assembled by one program (resident._assemble)
+        self.resident_blocks_whole = 0
+        self.resident_blocks_assembled = 0
         # morsel-pipeline attribution (engine.stream_sched): the live
         # scheduler while a pipelined stream runs, kept after it ends
         # for the stat snapshot (shard.scan spans / bench extras)
@@ -845,6 +849,14 @@ class MultiShardStreamSource:
     @property
     def resident_rows(self) -> int:
         return sum(sub.resident_rows for sub in self.subs)
+
+    @property
+    def resident_blocks_whole(self) -> int:
+        return sum(sub.resident_blocks_whole for sub in self.subs)
+
+    @property
+    def resident_blocks_assembled(self) -> int:
+        return sum(sub.resident_blocks_assembled for sub in self.subs)
 
     def blocks(
         self,

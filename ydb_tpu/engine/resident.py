@@ -14,10 +14,20 @@ read columns + geometry + predicate fingerprint — any new column subset
 or predicate rebuilds from host bytes), the resident store pins
 per-(portion, column) decoded device arrays. Portions are immutable, so
 one promoted portion serves EVERY scan shape: scans assemble
-fixed-capacity ``TableBlock``s directly from resident arrays
-(device-side slice + pad, zero host decode or transfer), and portions
-not yet resident fall through to the staged host path mid-stream — a
-partially resident table still wins on its resident fraction.
+fixed-capacity ``TableBlock``s directly from resident arrays (zero host
+decode or transfer), and portions not yet resident fall through to the
+staged host path mid-stream — a partially resident table still wins on
+its resident fraction.
+
+Block assembly costs the host one enqueue or none. A portion that
+fills a block exactly hands over its own arrays. Every other block is
+cut by ONE compiled program (``_assemble``) over all the columns read,
+whose bounds are runtime arguments; its identity is the block capacity,
+the column dtypes and the pieces' array lengths. So that those lengths
+do not follow the data, a resident array is held at its portion length
+rounded up (``resident_rows``: to a multiple of ``GRANULE``, below it
+to a power of two), the padding data 0 / validity False, and the budget
+counts the padded bytes.
 
 Promotion is asynchronous on the shared conveyor ("resident_promote"
 queue): eager at portion write/compaction output (the columns are
@@ -40,11 +50,15 @@ in-process override for tests, without environment mutation.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import threading
 import weakref
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ydb_tpu import chaos
 from ydb_tpu.analysis import leaksan, memsan, sanitizer
@@ -75,6 +89,17 @@ PROMOTE_HEAT = 2
 #: producers away whenever the heap is non-empty)
 MAX_INFLIGHT = 4
 
+#: a resident array's length is its portion's row count rounded up to a
+#: multiple of this (``resident_rows``), so the assembly program, keyed
+#: by its arguments' shapes, is the same program for every portion
+#: length inside one granule. A power of two that divides
+#: ``scan_block_rows``, so a block-filling portion pads nothing. 2^13:
+#: the benchmark's hash-sharded portions (2^20 +- 2K rows, a 305-307K
+#: tail) fall into the same three lengths at every granule from 2^13 to
+#: 2^16, and 2^13 adds 0.5% to the resident bytes where 2^16 adds 3.4%
+#: (PERF.md section 4)
+GRANULE = 1 << 13
+
 
 def _gate() -> "bool | None":
     """Tri-state tier gate: False = off, True = forced on, None = auto
@@ -96,9 +121,28 @@ def default_budget() -> int:
     return hbm.resident_budget()
 
 
+def resident_rows(rows: int) -> int:
+    """The length a portion of ``rows`` rows is held at: the next
+    multiple of GRANULE, or below GRANULE the next power of two (a
+    trickle of small portions must not cost a granule each)."""
+    if rows >= GRANULE:
+        return -(-rows // GRANULE) * GRANULE
+    return 1 << max(rows - 1, 0).bit_length()
+
+
+def _padded(a: np.ndarray, rows: int) -> np.ndarray:
+    """``a`` at ``rows`` rows, zeros (False) past its own."""
+    if len(a) == rows:
+        return a
+    out = np.zeros(rows, dtype=a.dtype)
+    out[:len(a)] = a
+    return out
+
+
 class _Entry:
     """One resident column of one portion: decoded device arrays at
-    portion length (un-padded; scans slice/pad to block capacity)."""
+    ``resident_rows`` of the portion's length, data 0 / validity False
+    past its rows (the portion's true count rides the scan's run)."""
 
     __slots__ = ("data", "validity", "nbytes")
 
@@ -211,10 +255,8 @@ class ResidentStore:
         if entries:
             # columns promoted before the binding sit on the default
             # device: move them (outside the lock; a concurrent scan
-            # reads either copy), or every mesh scan computes there
-            import jax
-
-            # the same bytes, already charged at promotion
+            # reads either copy), or every mesh scan computes there.
+            # The same bytes, already charged at promotion
             with memsan.seam("resident"):
                 for e in entries:
                     e.data = jax.device_put(e.data, device)
@@ -294,8 +336,6 @@ class ResidentStore:
         accounting and budget eviction inside it."""
         if not self.enabled():
             return False
-        import jax.numpy as jnp
-
         budget = self.budget()
         dev = self._slice_device
         entries = {}
@@ -306,15 +346,14 @@ class ResidentStore:
                 v = valid.get(n)
                 if v is None:
                     v = np.ones(len(a), dtype=np.bool_)
+                held = resident_rows(len(a))
+                a = _padded(np.asarray(a), held)
+                v = _padded(np.asarray(v, dtype=np.bool_), held)
                 if dev is not None:
-                    import jax
-
-                    e = _Entry(jax.device_put(np.asarray(a), dev),
-                               jax.device_put(
-                                   np.asarray(v, dtype=np.bool_), dev))
+                    e = _Entry(jax.device_put(a, dev),
+                               jax.device_put(v, dev))
                 else:
-                    e = _Entry(jnp.asarray(a),
-                               jnp.asarray(v, dtype=jnp.bool_))
+                    e = _Entry(jnp.asarray(a), jnp.asarray(v))
                 entries[n] = e
                 total += e.nbytes
         if total > budget:
@@ -555,65 +594,121 @@ def portion_loader(shard, meta):
     return load
 
 
+@functools.partial(jax.jit, static_argnames=("cap",))
+def _assemble(datas, valids, bounds, *, cap):
+    """One ``cap``-row block of every column read, cut from K resident
+    pieces: ``datas[k][c]`` / ``valids[k][c]`` are piece k's arrays of
+    column c, ``bounds[k]`` its (first row taken, offset in the block,
+    rows taken) as int32. Row ``i`` of the block is row
+    ``i - offset + first`` of the piece whose ``[offset, offset + rows)``
+    holds ``i``; rows no piece holds are data 0 / validity False.
+    Returns (datas, valids, length). The bounds are runtime values, so
+    the program is one for every cut of the same array lengths, and it
+    moves no row by index: each piece is read as ONE window of ``cap``
+    rows (a dynamic slice of the piece padded by ``cap`` either side, so
+    the window never clamps) and the pieces are selected on an iota."""
+    with jax.named_scope("ydb.device_blocks"):
+        rows = lax.iota(jnp.int32, cap)
+        out_d = out_v = None
+        for k, (ds, vs) in enumerate(zip(datas, valids)):
+            first, offset, n = bounds[k, 0], bounds[k, 1], bounds[k, 2]
+            inside = (rows >= offset) & (rows < offset + n)
+            start = first - offset + cap
+
+            def window(a):
+                return lax.dynamic_slice(jnp.pad(a, (cap, cap)),
+                                         (start,), (cap,))
+
+            d = [jnp.where(inside, window(a), jnp.zeros((), a.dtype))
+                 for a in ds]
+            v = [inside & window(a) for a in vs]
+            if out_d is None:
+                out_d, out_v = d, v
+            else:
+                out_d = [jnp.where(inside, x, y)
+                         for x, y in zip(d, out_d)]
+                out_v = [x | y for x, y in zip(v, out_v)]
+        return (tuple(out_d), tuple(out_v),
+                jnp.sum(bounds[:, 2], dtype=jnp.int32))
+
+
+@functools.lru_cache(maxsize=256)
+def _whole_length(device, rows: int):
+    """The int32 ``length`` of a block one portion fills, on the
+    portion's device (None: the default one, uncommitted as the arrays
+    of an unbound store are): made once, not once a block."""
+    with memsan.seam("resident"):
+        if device is None:
+            return jnp.asarray(rows, dtype=jnp.int32)
+        return jax.device_put(np.int32(rows), device)
+
+
 def _device_blocks(run, names, sch, cap, timer):
-    """Cut a RUN of consecutive resident portions into
-    capacity-``cap`` TableBlocks by device-side slice + concat.
+    """Cut a RUN of consecutive resident portions, ``(entries, rows,
+    source)`` each, into capacity-``cap`` TableBlocks.
 
     Coalescing across portion boundaries matters as much as skipping
     the host stage: emitting one padded block per small portion would
     hand the executor mostly-padding blocks and multiply compute by
-    the portion count. The aligned case (one portion exactly filling a
-    block) reuses the resident arrays as-is — zero device work."""
-    import jax
-    import jax.numpy as jnp
-
+    the portion count. A portion that fills a block exactly hands over
+    its resident arrays, no device work and no enqueue; every other
+    block is one ``_assemble`` call over all the columns. Either way
+    the block counts on the source its first row came from
+    (``resident_blocks_whole`` / ``resident_blocks_assembled``)."""
     stage = (timer.stage if timer is not None else None)
     starts = []
     total = 0
-    for _, rows in run:
+    for _, rows, _ in run:
         starts.append(total)
         total += rows
     for off in range(0, total, cap):
         take = min(cap, total - off)
-        # resident pieces overlapping [off, off+take), local coords
+        # resident pieces overlapping [off, off+take): (entries, first
+        # row taken, offset in the block, rows taken)
         parts = []
-        for (entries, rows), s in zip(run, starts):
+        for (entries, rows, source), s in zip(run, starts):
             lo = max(off, s) - s
             hi = min(off + take, s + rows) - s
             if lo < hi:
-                parts.append((entries, lo, hi, rows))
+                if not parts:
+                    owner = source
+                parts.append((entries, lo, s + lo - off, hi - lo))
         ctx = stage("stage") if stage is not None \
             else contextlib.nullcontext()
-        with ctx, jax.named_scope("ydb.device_blocks"):
-            whole = (len(parts) == 1 and parts[0][1] == 0
-                     and parts[0][2] == parts[0][3] == cap)
-            cols = {}
-            for n in names:
-                if whole:
-                    e = parts[0][0][n]
-                    d, v = e.data, e.validity
-                else:
-                    ds, vs = [], []
-                    for entries, lo, hi, _rows in parts:
-                        e = entries[n]
-                        ds.append(e.data[lo:hi])
-                        vs.append(e.validity[lo:hi])
-                    if take < cap:
-                        # tail-only pad; padding validity stays False
-                        ds.append(jnp.zeros(cap - take,
-                                            dtype=ds[0].dtype))
-                        vs.append(jnp.zeros(cap - take,
-                                            dtype=jnp.bool_))
-                    d = ds[0] if len(ds) == 1 else jnp.concatenate(ds)
-                    v = vs[0] if len(vs) == 1 else jnp.concatenate(vs)
-                cols[n] = Column(d, v)
-            blk = TableBlock(cols, jnp.asarray(take, dtype=jnp.int32),
-                             sch)
+        with ctx:
+            first = parts[0][0][names[0]].data
+            whole = (len(parts) == 1 and parts[0][1:] == (0, 0, cap)
+                     and first.shape[0] == cap)
+            if whole:
+                ents = parts[0][0]
+                datas = [ents[n].data for n in names]
+                valids = [ents[n].validity for n in names]
+                length = _whole_length(
+                    next(iter(first.devices())) if first.committed
+                    else None, cap)
+                owner.resident_blocks_whole += 1
+            else:
+                # the pieces carry their own bounds, so their order is
+                # free: by array length, one program a SET of lengths
+                parts.sort(key=lambda p: p[0][names[0]].data.shape[0])
+                datas, valids, length = _assemble(
+                    tuple(tuple(p[0][n].data for n in names)
+                          for p in parts),
+                    tuple(tuple(p[0][n].validity for n in names)
+                          for p in parts),
+                    np.array([p[1:4] for p in parts], dtype=np.int32),
+                    cap=cap)
+                owner.resident_blocks_assembled += 1
+            blk = TableBlock(
+                {n: Column(d, v)
+                 for n, d, v in zip(names, datas, valids)},
+                length, sch)
         yield blk
 
 
 def mixed_blocks(items, names, sch, cap, timer=None):
-    """('dev'/'host') item stream -> fixed-capacity TableBlocks.
+    """('dev', entries, rows, source) / ('host', cols, valid) item
+    stream -> fixed-capacity TableBlocks.
 
     Host runs pack through ``reader.rechunk`` (the same low-copy
     re-cutting as the pure host path); a device item flushes the
@@ -646,12 +741,12 @@ def mixed_blocks(items, names, sch, cap, timer=None):
         if item[0] == "dev":
             # absorb the whole consecutive resident run so blocks
             # coalesce across portion boundaries
-            dev_run = [(item[1], item[2])]
+            dev_run = [item[1:]]
             for nxt in it:
                 if nxt[0] != "dev":
                     pending = nxt
                     break
-                dev_run.append((nxt[1], nxt[2]))
+                dev_run.append(nxt[1:])
             for blk in _device_blocks(dev_run, names, sch, cap, timer):
                 emitted += 1
                 yield blk

@@ -722,6 +722,8 @@ class ColumnShard:
                    compile_cache=("miss" if fresh else "hit"),
                    resident_portions=src.resident_hits,
                    resident_rows=src.resident_rows,
+                   resident_blocks_whole=src.resident_blocks_whole,
+                   resident_blocks_assembled=src.resident_blocks_assembled,
                    **{f"stage_{k}": v
                       for k, v in self.last_scan_stages.items()},
                    **pruning)

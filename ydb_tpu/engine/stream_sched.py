@@ -90,11 +90,12 @@ def stream_budget() -> int:
 class _DevMorsel:
     """An HBM-resident portion: ready instantly, zero movement."""
 
-    __slots__ = ("entries", "rows")
+    __slots__ = ("entries", "rows", "source")
 
-    def __init__(self, entries, rows):
+    def __init__(self, entries, rows, source):
         self.entries = entries
         self.rows = rows
+        self.source = source
 
 
 class _MergeMorsel:
@@ -179,7 +180,7 @@ def plan_morsels(parts, names):
                         source.resident_rows += m.num_rows
                         timeline.add_bytes("resident_bytes", sum(
                             e.nbytes for e in ent.values()))
-                        yield _DevMorsel(ent, m.num_rows)
+                        yield _DevMorsel(ent, m.num_rows, source)
                         continue
                     if store.record_miss(m.portion_id):
                         store.promote_async(
@@ -442,7 +443,7 @@ class StreamScheduler:
                     for cols, valid in payloads:
                         yield ("host", cols, valid)
                 elif isinstance(m, _DevMorsel):
-                    yield ("dev", m.entries, m.rows)
+                    yield ("dev", m.entries, m.rows, m.source)
                 else:
                     # inline K-way merge: its blob reads/merge charge
                     # the usual stages; cold portions AFTER it (already

@@ -232,7 +232,8 @@ def source_counters(src) -> dict:
     span reports a run's DELTA (pruned views are fresh per run)."""
     return {k: int(getattr(src, k, 0))
             for k in ("chunks_read", "chunks_skipped", "resident_hits",
-                      "resident_rows")}
+                      "resident_rows", "resident_blocks_whole",
+                      "resident_blocks_assembled")}
 
 
 def pruning_since(src, before: dict) -> dict:
