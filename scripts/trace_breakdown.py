@@ -28,7 +28,12 @@ PREFIX = "ydb."
 #: spans that cover everything beneath them: a gap put down to one of
 #: these is not explained
 COVERING = {"ydb.query", "ydb.execute", "ydb.dq", "ydb.scan",
-            "ydb.transform", "ydb.analyze", "ydb.mesh"}
+            "ydb.transform", "ydb.analyze", "ydb.mesh",
+            "ydb.mesh.shuffle", "ydb.mesh.join"}
+#: spans of the newest statement's profile reported with their attrs
+#: (an exchange's bucket sizes, worst count, attempts and bytes; a local
+#: join's capacities and attempts)
+REPORTED_SPANS = ("mesh.shuffle", "mesh.join")
 #: a device operation that crosses devices, by its HLO name
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter")
@@ -286,6 +291,13 @@ def main(argv=None) -> int:
         report = getattr(cluster, "mesh_report", lambda: [])()
         if report:
             found["mesh_report"] = report
+        newest = cluster.profiles.recent()[-1:]
+        spans = [dict(sp["attrs"], name=sp["name"], seconds=sp["seconds"])
+                 for p in newest for sp in p.spans
+                 if sp["name"] in REPORTED_SPANS]
+        if spans:
+            found["newest_statement"] = {"stages": dict(newest[0].stages),
+                                         "spans": spans}
         return totals(cluster)
 
     run.deploy.resident_totals = totals_and_mesh_report

@@ -221,3 +221,172 @@ def test_lookup_join_int64_max_key_matches():
     )
     assert int(total) == 1
     assert TableBlock.to_numpy(out)["k"].tolist() == [big]
+
+
+# ---- the sorts a cold start pays for (ROADMAP S10) -------------------
+
+
+@pytest.mark.parametrize("rows", (1, 7, 1000, 5000))
+@pytest.mark.parametrize("classes", (2, 5))
+def test_stable_partition_is_the_stable_argsort(rows, classes):
+    """``kernels.stable_partition`` answers ``argsort(stable=True)`` of a
+    flag or a small class number: rows of one class keep their order."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(rows * classes)
+    last = (rng.random(rows) < 0.4 if classes == 2
+            else rng.integers(0, classes, rows).astype(np.int32))
+    got = np.asarray(kernels.stable_partition(jnp.asarray(last), classes))
+    assert got.dtype == np.int32
+    assert (got == np.argsort(last, kind="stable")).all()
+
+
+def test_stable_partition_falls_back_where_a_row_needs_the_class_bits():
+    """A row number and its class share 32 bits: past ``2^32 / classes``
+    rows the (class, row) pair sorts instead. Held on shapes alone."""
+    import jax
+
+    few = jax.ShapeDtypeStruct((1 << 12,), np.dtype("int32"))
+    many = jax.ShapeDtypeStruct(((1 << 29) + 1,), np.dtype("int32"))
+    part = lambda x: kernels.stable_partition(x, classes=5)
+    assert "ui32" in jax.jit(part).lower(few).as_text()
+    assert jax.eval_shape(part, many).shape == many.shape
+    assert "ui32" not in jax.jit(part).lower(many).as_text()
+
+
+@pytest.mark.parametrize("keys", ("small", "wide", "extremes"))
+@pytest.mark.parametrize("rows", (1, 7, 1000, 5000))
+def test_sorted_build_is_the_two_key_lexsort(rows, keys):
+    """Three stable 32-bit passes order the build side as
+    ``lexsort((key, dead))`` does: negative keys, keys that differ in
+    the high word only, INT64_MIN / INT64_MAX among the live rows."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(rows)
+    if keys == "small":
+        bk = rng.integers(-5, 5, rows, dtype=np.int64)
+    elif keys == "wide":
+        bk = rng.integers(-2**40, 2**40, rows, dtype=np.int64)
+        bk[::3] &= ~np.int64(0xFFFFFFFF)        # equal low words
+    else:
+        bk = rng.integers(-2**63, 2**63 - 1, rows, dtype=np.int64)
+        bk[rng.random(rows) < 0.3] = np.iinfo(np.int64).max
+        bk[rng.random(rows) < 0.1] = np.iinfo(np.int64).min
+    live = rng.random(rows) < 0.7
+    order, bk_sorted, n_live = jk._sorted_build(jnp.asarray(bk),
+                                               jnp.asarray(live))
+    want = np.lexsort((bk, ~live))
+    assert (np.asarray(order) == want).all()
+    assert int(n_live) == live.sum()
+    assert (np.asarray(bk_sorted)[:live.sum()] == bk[want][:live.sum()]).all()
+    assert (np.diff(np.asarray(bk_sorted)) >= 0).all()
+
+
+_KEY_KINDS = ("bool", "i64", "u64", "i32", "u32", "i8", "u16")
+
+
+def _key(kind, n, rng):
+    if kind == "bool":
+        return rng.random(n) < 0.5
+    if kind == "i64":
+        x = rng.integers(-2**63, 2**63 - 1, n, dtype=np.int64)
+        x[::3] = rng.integers(-3, 3, len(x[::3]))      # ties, both signs
+        return x
+    if kind == "u64":
+        return rng.integers(0, 2**64 - 1, n, dtype=np.uint64)
+    if kind == "i32":
+        return (rng.integers(-2**31, 2**31 - 1, n)
+                >> int(rng.integers(0, 30))).astype(np.int32)
+    if kind == "u32":
+        return (rng.integers(0, 2**32 - 1, n)
+                >> int(rng.integers(0, 30))).astype(np.uint32)
+    if kind == "i8":
+        return rng.integers(-128, 127, n).astype(np.int8)
+    return rng.integers(0, 65535, n).astype(np.uint16)
+
+
+@pytest.mark.parametrize("rows", (1, 2, 50, 3000))
+@pytest.mark.parametrize("seed", range(4))
+def test_stable_lexsort_is_numpys(rows, seed):
+    """One stable pass a 32-bit word of a key orders rows as
+    ``np.lexsort`` does (the last key primary, ties in their order):
+    flags, signed and unsigned keys of 8 to 64 bits, mixed."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(100 * rows + seed)
+    for _ in range(5):
+        keys = [_key(k, rows, rng)
+                for k in rng.choice(_KEY_KINDS, size=rng.integers(1, 6))]
+        got = np.asarray(kernels.stable_lexsort(
+            [jnp.asarray(k) for k in keys]))
+        assert got.dtype == np.int32
+        assert (got == np.lexsort(tuple(keys))).all()
+
+
+def test_stable_lexsort_keeps_the_comparator_sort_for_a_float_key():
+    """NaNs and signed zeros have their place in XLA's comparator: a
+    floating key leaves the whole sort to ``jnp.lexsort``."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(7)
+    f = np.where(rng.random(300) < 0.1, np.nan,
+                 rng.integers(-3, 3, 300).astype(np.float64))
+    f[::17] = -0.0
+    keys = [jnp.asarray(f), jnp.asarray(_key("i8", 300, rng)),
+            jnp.asarray(_key("bool", 300, rng))]
+    assert (np.asarray(kernels.stable_lexsort(keys))
+            == np.asarray(jnp.lexsort(tuple(keys)))).all()
+
+
+@pytest.mark.parametrize("program", ("compact", "sorted_build",
+                                     "repartition", "group_ids_sorted",
+                                     "sort_perm"))
+def test_no_program_sorts_a_64_bit_or_a_two_key_operand(program):
+    """What XLA's TPU sort costs to compile follows its operands: an
+    (int64, bool) pair with its row numbers took 208-239 s for a
+    described v5e at 917,504 rows, a ``uint32`` alone 26 s (PERF.md
+    section 6, PR 35). The join's and the exchange's programs hold
+    only sorts of one 32-bit key, alone or with its row numbers."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ydb_tpu.parallel import shuffle
+    from ydb_tpu.parallel.mesh import SHARD_AXIS, make_mesh, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    rows = 1 << 12
+    blk = _block(k=(np.arange(rows), dtypes.INT64),
+                 v=(np.arange(rows), dtypes.INT64))
+    if program == "compact":
+        text = jax.jit(kernels.compact).lower(
+            blk, jnp.zeros(rows, bool)).as_text()
+    elif program == "sorted_build":
+        text = jax.jit(jk._sorted_build).lower(
+            jnp.zeros(rows, jnp.int64), jnp.zeros(rows, bool)).as_text()
+    elif program in ("group_ids_sorted", "sort_perm"):
+        # Q3's group-by and its ORDER BY: int64 and int32 keys, their
+        # validities, the live flag
+        cols = [blk.columns["k"], blk.columns["v"]]
+        fn = ((lambda: kernels.group_ids_sorted(cols, blk.row_mask(), rows))
+              if program == "group_ids_sorted" else
+              (lambda: kernels.sort_perm(cols, [True, False],
+                                         blk.row_mask())))
+        text = jax.jit(fn).lower().as_text()
+    else:
+        mesh = make_mesh(1, devices=jax.devices()[:1])
+        text = jax.jit(shard_map(
+            lambda b: shuffle.repartition(b, ["k"], 1, bucket_rows=rows),
+            mesh=mesh, in_specs=P(), out_specs=P(),
+            check_vma=False)).lower(blk).as_text()
+    # a sort's comparator takes two scalars an operand, keys first
+    sorts = re.findall(r'"stablehlo\.sort"\([^\n]*\n\s*\^bb0\(([^\n]*)\):',
+                       text)
+    assert sorts, text[:400]
+    for args in sorts:
+        kinds = re.findall(r"tensor<(\w+)>", args)[::2]
+        assert len(kinds) <= 2 and kinds[0] in ("ui32", "i32"), args
+    if program == "compact":
+        assert [re.findall(r"tensor<(\w+)>", a) for a in sorts] == [
+            ["ui32", "ui32"]]

@@ -34,6 +34,20 @@ def catalog(data):
                    dicts=data.dicts)
 
 
+@pytest.fixture(autouse=True)
+def release_programs():
+    """A compiled CPU program holds its memory maps for as long as JAX
+    caches it, ~900 a query here, and a process may hold 65,530
+    (``vm.max_map_count``): this file's 72 queries in one process ended
+    just below that, and three sorts for one in every join build put
+    them above it (the compiler then dies in ``mmap``). No query shares
+    a program with the next, so each gives its programs back."""
+    yield
+    import jax
+
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("name", sorted(tpcds.QUERIES))
 def test_query(name, data, db, catalog):
     from ydb_tpu.workload.runner import scalar_exec_for

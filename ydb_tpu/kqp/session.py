@@ -1200,11 +1200,21 @@ class Cluster:
         """What each mesh device holds resident: one entry a device
         with its index, the shard stores bound to it and their resident
         bytes, rows and portions (the stores' own ``snapshot()``s),
-        over all tables and for each table. ``[]`` with the mesh off."""
+        over all tables and for each table; and what it has exchanged:
+        ``shuffle_bytes``, the bytes it sent in the process's mesh
+        exchanges, and ``shuffle_grows``, the exchanges made again at a
+        grown bucket size (every device takes part in each; both from
+        ``obs.timeline``'s process counters). ``[]`` with the mesh
+        off."""
         if self._mesh_exec is None:
             return []
+        from ydb_tpu.obs import timeline as _tl
+
+        moved = _tl.movement_snapshot()
         report = [{"device": d, "stores": 0, "bytes": 0, "rows": 0,
-                   "portions": 0, "tables": {}}
+                   "portions": 0, "tables": {},
+                   "shuffle_bytes": moved.get(f"shuffle_bytes_dev{d}", 0),
+                   "shuffle_grows": moved.get("shuffle_grows", 0)}
                   for d in range(self._mesh_exec.n)]
         for name, t in self.tables.items():
             for sh in getattr(t, "shards", ()):

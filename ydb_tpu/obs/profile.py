@@ -52,6 +52,18 @@ STATEMENT_KEYS = ("plan", "pull", "dispatch", "device_wait", "fetch",
 #: ``dispatch`` and ``device_wait``, which keep the shard scans' time,
 #: so the seven keys sum to ``seconds`` as the six do.
 MESH_KEY = "mesh"
+#: two more, carved out of ``mesh`` by the same rule and only of a
+#: statement that has such a span (a join ran over the mesh): the self
+#: time of the ``dispatch`` / ``device.wait`` / ``device.get`` spans
+#: whose nearest enclosing span of these kinds is a ``mesh.shuffle``
+#: (one side's hash-repartition: each ``all_to_all`` exchange, the wait
+#: for its worst bucket count, the wait that tightens the output) or a
+#: ``mesh.join`` (one device-local join under ``shard_map``, the wait
+#: for an expanding join's total). ``mesh`` keeps the placement, the
+#: collective step and the answer's copy out; the keys still sum to
+#: ``seconds``, and a statement without such a span has neither key.
+MESH_SPAN_KEYS = {"mesh": MESH_KEY, "mesh.shuffle": "mesh_shuffle",
+                  "mesh.join": "mesh_join"}
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
@@ -180,31 +192,33 @@ def statement_stages(spans, seconds: float) -> dict:
     by_id = {s.span_id: s for s in spans}
     thread = next((s for s in spans if s.parent_id not in by_id),
                   spans[0]).thread
-    if any(s.name == MESH_KEY for s in spans):
-        out[MESH_KEY] = 0.0
+    names = {s.name for s in spans}
+    mesh_keys = [k for n, k in MESH_SPAN_KEYS.items() if n in names]
+    out.update(dict.fromkeys(mesh_keys, 0.0))
     selfs = self_seconds(spans)
     for s in spans:
         stage = SPAN_STAGE.get(s.name)
         if stage is not None and s.thread == thread and s.annotated:
-            if MESH_KEY in out and stage in ("dispatch", "device_wait") \
-                    and _mesh_own(s, by_id):
-                stage = MESH_KEY
+            if mesh_keys and stage in ("dispatch", "device_wait"):
+                stage = _mesh_key(s, by_id) or stage
             out[stage] += selfs[s.span_id]
     out["unattributed"] = max(0.0, seconds - sum(out.values()))
     return out
 
 
-def _mesh_own(span, by_id: dict) -> bool:
-    """Whether the nearest ``mesh`` or scan span above ``span`` is the
-    ``mesh`` span: the mesh executor's own work, not a shard scan's."""
+def _mesh_key(span, by_id: dict) -> str | None:
+    """The mesh executor's statement key for ``span``, by the nearest
+    ``mesh``, ``mesh.shuffle``, ``mesh.join`` or scan span above it:
+    None beneath a scan span (a shard scan's work, not the mesh's) or
+    outside the ``mesh`` span."""
     parent = by_id.get(span.parent_id)
     while parent is not None:
         if parent.name in SCAN_SPANS:
-            return False
-        if parent.name == MESH_KEY:
-            return True
+            return None
+        if parent.name in MESH_SPAN_KEYS:
+            return MESH_SPAN_KEYS[parent.name]
         parent = by_id.get(parent.parent_id)
-    return False
+    return None
 
 
 def subtree(spans, root_span_id: int) -> list:
@@ -420,7 +434,8 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
     st = profile.stages
     lines.append("stages: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in STAGE_KEYS))
-    keys = STATEMENT_KEYS + ((MESH_KEY,) if MESH_KEY in st else ())
+    keys = STATEMENT_KEYS + tuple(k for k in MESH_SPAN_KEYS.values()
+                                  if k in st)
     lines.append("statement: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in keys))
     pr = profile.pruning
@@ -435,14 +450,16 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
             bits.append(f"{pair}={coeff:.4f}")
         lines.append("occupancy: " + " ".join(bits))
     for s in profile.spans:
-        if s["name"] not in SCAN_SPANS and s["name"] != MESH_KEY:
+        if s["name"] not in SCAN_SPANS and s["name"] not in MESH_SPAN_KEYS:
             continue
         a = s["attrs"]
         bits = [f"seconds={s['seconds']:.6f}"]
-        for k in ("table", "shard", "device", "devices", "answered",
-                  "rows", "compile_cache", "agg_pushdown"):
-            if k in a:
-                bits.append(f"{k}={a[k]}")
+        # an exchange or a local join of the mesh: all it carries
+        # (bucket sizes, worst count, attempts, bytes; capacities)
+        shown = a if s["name"] in MESH_SPAN_KEYS and s["name"] != MESH_KEY \
+            else ("table", "shard", "device", "devices", "answered",
+                  "rows", "compile_cache", "agg_pushdown")
+        bits += [f"{k}={a[k]}" for k in shown if k in a]
         lines.append(f"  {s['name']}: " + " ".join(bits))
     return "\n".join(lines)
 

@@ -195,6 +195,13 @@ def add_bytes(key: str, n: int) -> None:
         _movement[key] = _movement.get(key, 0) + int(n)
 
 
+def add_count(key: str, n: int = 1) -> None:
+    """Count an event of the data movement beside its bytes
+    (``shuffle_grows``: a mesh exchange made again at a grown bucket
+    size); the same totals, the same snapshot."""
+    add_bytes(key, n)
+
+
 def movement_snapshot() -> dict:
     """Lifetime byte totals; consumers (run_background, bench) diff
     snapshots for rates."""

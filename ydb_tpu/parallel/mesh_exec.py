@@ -14,8 +14,8 @@ mesh collectives:
     with ``jax.lax.all_to_all`` (parallel/shuffle.py) so matching keys
     land on the same device, then joins device-locally with the
     sort/searchsorted kernels (ssa/join.py) — the grace-join shape with
-    ICI as the spill fabric; bucket overflow retries with doubled
-    capacity (the respill protocol),
+    ICI as the spill fabric; a bucket that overflows is exchanged again
+    at the observed size (the respill protocol),
   * the final Transform (aggregate/HAVING/ORDER BY) reuses the MeshScan
     two-phase machinery: per-device partial states, psum/pmin/pmax or
     all_gather merge, replicated finalization.
@@ -63,6 +63,11 @@ from ydb_tpu.ssa import join as join_kernels
 from ydb_tpu.ssa import kernels
 from ydb_tpu.ssa.plan_fuse import shape_class
 from ydb_tpu.ssa.program import SortStep, WindowStep
+
+
+#: the device-local joins' operations outside ssa/join.py's own scopes
+#: (``ydb.sorted_build``, ``ydb.expand_match``, ...) in a device trace
+JOIN_SCOPE = "ydb.mesh_join"
 
 
 def _round_up(n: int) -> int:
@@ -345,49 +350,67 @@ class MeshPlanExecutor:
     # -- repartition with overflow retry --
 
     def _repartition(self, stacked: TableBlock, keys: list[str]):
-        cap = stacked.capacity
+        """One side of a join exchanged over the mesh, under a
+        ``mesh.shuffle`` span: every attempt's dispatch, the wait for
+        its worst bucket count and the wait that tightens the output
+        lie beneath it (the ``mesh_shuffle`` statement key)."""
+        # the rows a device holds: a stacked block's ``capacity`` is its
+        # leading axis, the devices
+        cap = _device_rows(stacked)
         # stats-sized first attempt (mean load × margin + the count-min
         # heavy-hitter bound) instead of the old blind 2/n-of-capacity;
         # overflow grows to the shape class of the OBSERVED worst count
         # — one exact retry, not a doubling ladder
-        B = size_buckets(cap, self.n,
-                         heavy=heavy_bound(self.db.table_stats, keys))
-        while True:
-            key = ("repart", stacked.schema, tuple(keys), cap, B)
-            step = self._jit_cache.get(key)
-            if step is None:
-                n = self.n
+        B = first = size_buckets(
+            cap, self.n, heavy=heavy_bound(self.db.table_stats, keys))
+        attempts = sent = 0
+        with tracing.span("mesh.shuffle") as sp:
+            while True:
+                key = ("repart", stacked.schema, tuple(keys), cap, B)
+                step = self._jit_cache.get(key)
+                if step is None:
+                    n = self.n
 
-                def go(st, _B=B):
-                    blk, worst = repartition(
-                        _local(st), keys, n, bucket_rows=_B,
-                        with_counts=True)
-                    return _relocal(blk), worst
+                    def go(st, _B=B):
+                        blk, worst = repartition(
+                            _local(st), keys, n, bucket_rows=_B,
+                            with_counts=True)
+                        return _relocal(blk), worst
 
-                step = jax.jit(shard_map(
-                    go, mesh=self.mesh, in_specs=P(SHARD_AXIS),
-                    out_specs=(P(SHARD_AXIS), P()),
-                    check_vma=False,
-                ))
-                self._jit_cache[key] = step
-            with tracing.span("dispatch", program="mesh_repartition"):
-                out, worst = step(stacked)
-            # every attempt (including an overflow retry) was a real
-            # mesh exchange — account its per-device bytes, and charge
-            # the send/recv bucket capacity to the shuffle budget (an
-            # overflow retry re-allocates GROWN buckets: each attempt
-            # charges its own footprint)
-            per_dev = exchange_bytes_per_device(stacked.schema, self.n, B)
-            for d in range(self.n):
-                timeline.add_bytes(f"shuffle_bytes_dev{d}", per_dev)
-            if memsan.armed():
-                memsan.charge(per_dev * self.n, "shuffle",
-                              owner="repartition")
-            with tracing.span("device.wait"):
-                w = int(np.asarray(worst))
-            if w <= B:
-                return self._tighten(out)
-            B = shape_class(w)  # grace respill, sized by the observation
+                    step = jax.jit(shard_map(
+                        go, mesh=self.mesh, in_specs=P(SHARD_AXIS),
+                        out_specs=(P(SHARD_AXIS), P()),
+                        check_vma=False,
+                    ))
+                    self._jit_cache[key] = step
+                with tracing.span("dispatch", program="mesh_repartition"):
+                    out, worst = step(stacked)
+                # every attempt (including an overflow retry) was a real
+                # mesh exchange — account its per-device bytes, and charge
+                # the send/recv bucket capacity to the shuffle budget (an
+                # overflow retry re-allocates GROWN buckets: each attempt
+                # charges its own footprint)
+                per_dev = exchange_bytes_per_device(stacked.schema, self.n,
+                                                    B)
+                attempts += 1
+                sent += per_dev
+                for d in range(self.n):
+                    timeline.add_bytes(f"shuffle_bytes_dev{d}", per_dev)
+                if memsan.armed():
+                    memsan.charge(per_dev * self.n, "shuffle",
+                                  owner="repartition")
+                with tracing.span("device.wait"):
+                    w = int(np.asarray(worst))
+                if w <= B:
+                    out = self._tighten(out)
+                    sp.set(keys=",".join(keys), capacity=cap,
+                           bucket_rows=first, bucket_rows_final=B,
+                           worst=w, attempts=attempts,
+                           bytes_per_device=sent)
+                    return out
+                # grace respill, sized by the observation
+                B = shape_class(w)
+                timeline.add_count("shuffle_grows")
 
     def _tighten(self, stacked: TableBlock) -> TableBlock:
         """Slice a front-packed stacked block down to a tight capacity so
@@ -395,13 +418,18 @@ class MeshPlanExecutor:
         with tracing.span("device.wait"):
             max_len = int(np.asarray(stacked.length).max())
         cap = _round_up(max_len)
-        if cap >= stacked.capacity:
+        if cap >= _device_rows(stacked):
             return stacked
-        cols = {
-            n: Column(c.data[:, :cap], c.validity[:, :cap])
-            for n, c in stacked.columns.items()
-        }
-        return TableBlock(cols, stacked.length, stacked.schema)
+        key = ("tighten", stacked.schema, _device_rows(stacked), cap)
+        step = self._jit_cache.get(key)
+        if step is None:
+            # one program for all the columns; each device slices its own
+            step = jax.jit(lambda st: TableBlock(
+                {n: Column(c.data[:, :cap], c.validity[:, :cap])
+                 for n, c in st.columns.items()}, st.length, st.schema))
+            self._jit_cache[key] = step
+        with tracing.span("dispatch", program="mesh_tighten"):
+            return step(stacked)
 
     # -- local joins --
 
@@ -411,6 +439,7 @@ class MeshPlanExecutor:
                probe.capacity, build.capacity)
         step = self._jit_cache.get(key)
         if step is None:
+            @jax.named_scope(JOIN_SCOPE)
             def go(pst, bst):
                 # shared dispatch with the single-chip executor/DQ path
                 # (lookup joins are jit-safe; no host retry involved)
@@ -426,42 +455,71 @@ class MeshPlanExecutor:
                 out_specs=P(SHARD_AXIS), check_vma=False,
             ))
             self._jit_cache[key] = step
-        with tracing.span("dispatch", program="mesh_lookup"):
-            out = step(probe, build)
-        return self._tighten(out)
+        with tracing.span("mesh.join", kind=plan.kind, expand=0,
+                          probe_capacity=_device_rows(probe),
+                          build_capacity=_device_rows(build)) as sp:
+            with tracing.span("dispatch", program="mesh_lookup"):
+                out = step(probe, build)
+            out = self._tighten(out)
+            sp.set(out_capacity=_device_rows(out), attempts=1)
+        return out
 
     def _local_expand(self, plan: ExpandJoin, probe, build):
-        cap = _round_up(max(int(probe.capacity * plan.fanout_hint), 1024))
-        while True:
-            key = ("expand", plan.probe_keys, plan.build_keys,
-                   plan.probe_payload, plan.build_payload, plan.kind,
-                   plan.build_suffix, probe.schema, build.schema,
-                   probe.capacity, build.capacity, cap)
-            step = self._jit_cache.get(key)
-            if step is None:
-                def go(pst, bst):
-                    out, total = join_kernels.expand_join(
-                        _local(pst), _local(bst),
-                        list(plan.probe_keys), list(plan.build_keys),
-                        list(plan.probe_payload), list(plan.build_payload),
-                        out_capacity=cap, build_suffix=plan.build_suffix,
-                        kind=plan.kind)
-                    return _relocal(out), total[None]
+        """An expanding join in two programs, as ``run_equi_join`` makes
+        it on one chip: the sort-bearing match, which does not depend on
+        the output's size, then, with the devices' exact totals read
+        back, the emit at the largest total's shape class. No guessed
+        capacity, so no overflow that would compile and run the sort
+        again."""
+        shapes = (plan.probe_keys, plan.build_keys, plan.kind,
+                  probe.schema, build.schema, _device_rows(probe),
+                  _device_rows(build))
+        sides = (P(SHARD_AXIS), P(SHARD_AXIS))
+        with tracing.span("mesh.join", kind=plan.kind, expand=1,
+                          probe_capacity=_device_rows(probe),
+                          build_capacity=_device_rows(build)) as sp:
+            key = ("match",) + shapes
+            match = self._jit_cache.get(key)
+            if match is None:
+                @jax.named_scope(JOIN_SCOPE)
+                def find(pst, bst):
+                    found = join_kernels._expand_match(
+                        _local(pst), _local(bst), list(plan.probe_keys),
+                        list(plan.build_keys), plan.kind)
+                    return tuple(x[None] for x in found)
 
-                step = jax.jit(shard_map(
-                    go, mesh=self.mesh,
-                    in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-                    out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-                    check_vma=False,
+                match = jax.jit(shard_map(
+                    find, mesh=self.mesh, in_specs=sides,
+                    out_specs=P(SHARD_AXIS), check_vma=False,
                 ))
-                self._jit_cache[key] = step
-            with tracing.span("dispatch", program="mesh_expand"):
-                out, totals = step(probe, build)
+                self._jit_cache[key] = match
+            with tracing.span("dispatch", program="mesh_match"):
+                found = match(probe, build)
             with tracing.span("device.wait"):
-                worst = int(np.asarray(totals).max())
-            if worst <= cap:
-                return self._tighten(out)
-            cap = _round_up(worst)
+                cap = _round_up(int(np.asarray(found[-1]).max()))
+            key = ("emit", plan.probe_payload, plan.build_payload,
+                   plan.build_suffix, cap) + shapes
+            emit = self._jit_cache.get(key)
+            if emit is None:
+                @jax.named_scope(JOIN_SCOPE)
+                def go(pst, bst, mst, _cap=cap):
+                    out, _ = join_kernels._expand_emit(
+                        _local(pst), _local(bst),
+                        tuple(x[0] for x in mst),
+                        list(plan.probe_payload),
+                        list(plan.build_payload), _cap,
+                        plan.build_suffix, plan.kind)
+                    return _relocal(out)
+
+                emit = jax.jit(shard_map(
+                    go, mesh=self.mesh, in_specs=sides + (P(SHARD_AXIS),),
+                    out_specs=P(SHARD_AXIS), check_vma=False,
+                ))
+                self._jit_cache[key] = emit
+            with tracing.span("dispatch", program="mesh_expand"):
+                out = emit(probe, build, found)
+            sp.set(out_capacity=cap, attempts=1)
+        return out
 
     # -- final transform (two-phase over the mesh) --
 
@@ -560,6 +618,12 @@ class MeshPlanExecutor:
             self._jit_cache[key] = scan
         # MeshScan's step expects the partial program's read columns only
         return scan.run_stacked(stacked)
+
+
+def _device_rows(stacked: TableBlock) -> int:
+    """Rows a device holds of a stacked block (its ``capacity`` is the
+    leading axis, the devices)."""
+    return next(iter(stacked.columns.values())).data.shape[1]
 
 
 def _concat(blocks: list[TableBlock]) -> TableBlock:

@@ -130,6 +130,7 @@ def hash_rows(cols: list[Column]) -> jax.Array:
     return h
 
 
+@jax.named_scope("ydb.shuffle")
 def repartition(
     block: TableBlock,
     key_names: list[str],
@@ -155,7 +156,9 @@ def repartition(
     dest = jnp.where(live, dest, n_shards)  # dead rows -> drop bucket
 
     # stable-sort rows by destination => contiguous buckets
-    order = jnp.argsort(dest, stable=True)
+    from ydb_tpu.ssa import kernels
+
+    order = kernels.stable_partition(dest, classes=n_shards + 1)
     dest_s = dest[order]
     # position of each row within its bucket
     ones = jnp.ones_like(dest_s, dtype=jnp.int32)
@@ -200,8 +203,6 @@ def repartition(
     big = TableBlock(
         new_cols, jnp.int32(n_shards * B), block.schema
     )
-    from ydb_tpu.ssa import kernels
-
     out = kernels.compact(big, mask)
     if not with_counts:
         return out

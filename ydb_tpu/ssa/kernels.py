@@ -128,6 +128,66 @@ def apply_filter(block: TableBlock, mask: jax.Array) -> TableBlock:
     return compact(block, mask)
 
 
+def stable_argsort(key: jax.Array) -> jax.Array:
+    """``argsort(key, stable=True)`` of a 32-bit key with int32 row
+    numbers: ``jnp.argsort`` carries an int64 iota through the sort
+    under x64, a 64-bit operand the TPU emulates."""
+    rows = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, rows), num_keys=1, is_stable=True)[1]
+
+
+def stable_partition(last: jax.Array, classes: int = 2) -> jax.Array:
+    """The permutation that orders rows by a small class number
+    (``last``: bool, or int in [0, classes)), rows of one class in
+    their own order: what ``argsort(last, stable=True)`` answers, as
+    ONE single-operand sort of ``class << shift | row``. The keys are
+    distinct, so the sort needs neither a second operand nor stability,
+    and XLA's TPU sort, which is what a cold start pays for (ROADMAP
+    S10), compiles it in a tenth of the time of the stable (class, row)
+    pair (for a described v5e at 2^20 rows: 5.8 s against 55 s)."""
+    rows = last.shape[0]
+    shift = 32 - (classes - 1).bit_length()
+    if rows > (1 << shift):
+        return stable_argsort(last.astype(jnp.int32))
+    key = (last.astype(jnp.uint32) << shift) | jnp.arange(
+        rows, dtype=jnp.uint32)
+    return (jax.lax.sort(key, is_stable=False)
+            & jnp.uint32((1 << shift) - 1)).astype(jnp.int32)
+
+
+def stable_lexsort(keys) -> jax.Array:
+    """``jnp.lexsort(keys)`` (the LAST key is primary, equal rows keep
+    their order) as one stable pass a 32-bit word of a key, from the
+    least significant: a flag by ``stable_partition``, a 64-bit integer
+    as its low then its high word. XLA's TPU sort compiles by its
+    operands: Q3's group-by over three keys, their validities and the
+    live flag (seven operands and the row numbers) took 26 s at 8,192
+    rows for a described v5e and minutes at 32,768, the passes 2 s and
+    16 s (PERF.md section 6, PR 35). A floating key keeps the one
+    comparator sort: its order has NaNs and signed zeros."""
+    if any(jnp.issubdtype(k.dtype, jnp.floating) for k in keys):
+        return jnp.lexsort(tuple(keys)).astype(jnp.int32)
+    order = None
+    for key in keys:
+        if key.dtype == jnp.bool_:
+            words = [key]
+        elif key.dtype.itemsize == 8:
+            signed = jnp.issubdtype(key.dtype, jnp.signedinteger)
+            words = [(key & 0xFFFFFFFF).astype(jnp.uint32),
+                     (key >> 32).astype(jnp.int32 if signed else jnp.uint32)]
+        elif jnp.issubdtype(key.dtype, jnp.signedinteger):
+            words = [key.astype(jnp.int32)]
+        else:
+            words = [key.astype(jnp.uint32)]
+        for word in words:
+            if order is not None:
+                word = word[order]
+            step = (stable_partition(word) if word.dtype == jnp.bool_
+                    else stable_argsort(word))
+            order = step if order is None else order[step]
+    return order
+
+
 @jax.named_scope("ydb.compact")
 def compact(block: TableBlock, selected: jax.Array) -> TableBlock:
     """Move selected live rows to the front (stable), update length.
@@ -136,8 +196,8 @@ def compact(block: TableBlock, selected: jax.Array) -> TableBlock:
     (callers AND with block.row_mask()).
     """
     keep = selected & block.row_mask()
-    # stable partition: sort by (not kept); ties keep original order
-    perm = jnp.argsort(~keep, stable=True)
+    # stable partition: the rows not kept go last, each part in its order
+    perm = stable_partition(~keep)
     cols = {
         n: Column(c.data[perm], c.validity[perm] & keep[perm])
         for n, c in block.columns.items()
@@ -187,7 +247,7 @@ def group_ids_sorted(
         sort_keys.append(k.data)
         sort_keys.append(~k.validity)
     sort_keys.append(~live)
-    perm = jnp.lexsort(tuple(sort_keys))  # last key is primary
+    perm = stable_lexsort(sort_keys)  # last key is primary
     # invert the permutation with one linear scatter (not a second sort)
     inv = jnp.zeros_like(perm).at[perm].set(
         jnp.arange(perm.shape[0], dtype=perm.dtype)
@@ -379,7 +439,7 @@ def sort_perm(
         sort_keys.append(d)
         sort_keys.append(~k.validity)
     sort_keys.append(~live)
-    return jnp.lexsort(tuple(sort_keys))
+    return stable_lexsort(sort_keys)
 
 
 @jax.named_scope("ydb.sort_block")
