@@ -262,3 +262,44 @@ def test_block_assembly_moves_no_rows(query, one_chip,
              and f"[{rows}]" in ln]
     assert not moved, (query, moved[:3])
     assert "ydb.device_blocks" in text
+
+
+#: the exchange's first bucket at SF 1's `lineitem` on four chips:
+#: 1.5 x the mean a device (parallel/mesh_exec.py)
+EXCHANGE_ROWS = 1_572_864
+
+
+@pytest.mark.parametrize("rows", (DEFAULT_BLOCK_ROWS, EXCHANGE_ROWS))
+def test_compact_moves_a_block_without_a_sort_or_a_gather(
+        rows, one_chip, no_persistent_cache, capsys):
+    """``kernels.compact`` over ``lineitem``'s Q3 columns (three int64,
+    one int32, their validities) at the scan's block and at the
+    exchange's: a prefix count and a round of shift-and-select a bit of
+    the capacity (PR 36). Nothing sorts, gathers or scatters over the
+    block's rows, and the temporaries fit beside a resident table."""
+    import time
+
+    from ydb_tpu.blocks.block import Column, TableBlock
+    from ydb_tpu.ssa import kernels
+
+    names = ("l_orderkey", "l_extendedprice", "l_discount", "l_shipdate")
+    schema = tpch.LINEITEM_SCHEMA.select(names)
+    block = TableBlock(
+        {n: Column(_shape((rows,), schema.field(n).type.physical, one_chip),
+                   _shape((rows,), "bool", one_chip)) for n in names},
+        _shape((), "int32", one_chip), schema)
+    assert [str(c.data.dtype) for c in block.columns.values()] == [
+        "int64", "int64", "int64", "int32"]
+    t0 = time.perf_counter()
+    compiled = jax.jit(kernels.compact).lower(
+        block, _shape((rows,), "bool", one_chip)).compile()
+    with capsys.disabled():
+        print(f"\ncompact at {rows} rows compiled for a described v5e in "
+              f"{time.perf_counter() - t0:.1f} s")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, (rows, temp)
+    text = compiled.as_text()
+    moved = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"\b(sort|gather|scatter)\(", ln)]
+    assert not moved, (rows, moved[:3])
+    assert "ydb.compact" in text

@@ -20,6 +20,8 @@ traced int32 scalar, never a shape.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -122,9 +124,9 @@ def days_from_civil(y, m, d):
 
 @jax.named_scope("ydb.apply_filter")
 def apply_filter(block: TableBlock, mask: jax.Array) -> TableBlock:
-    """Late-materialization filter: fold mask into live length accounting by
-    compacting. Cheap alternative when no compaction is needed: callers keep
-    the mask and pass it to aggregation/sort directly."""
+    """The rows ``mask`` keeps, moved to the front by ``compact`` (the
+    block's length becomes their count). A consumer that can work under
+    a mask (an aggregation, a sort) keeps the mask and skips this."""
     return compact(block, mask)
 
 
@@ -140,7 +142,10 @@ def stable_partition(last: jax.Array, classes: int = 2) -> jax.Array:
     """The permutation that orders rows by a small class number
     (``last``: bool, or int in [0, classes)), rows of one class in
     their own order: what ``argsort(last, stable=True)`` answers, as
-    ONE single-operand sort of ``class << shift | row``. The keys are
+    ONE single-operand sort of ``class << shift | row``. It serves the
+    exchange's bucket sort (parallel/shuffle.py) and a flag's pass of
+    ``stable_lexsort``; ``compact`` moves its rows without a
+    permutation since PR 36. The keys are
     distinct, so the sort needs neither a second operand nor stability,
     and XLA's TPU sort, which is what a cold start pays for (ROADMAP
     S10), compiles it in a tenth of the time of the stable (class, row)
@@ -188,22 +193,84 @@ def stable_lexsort(keys) -> jax.Array:
     return order
 
 
+#: the width of the prefix count's first level: a 1-D ``jnp.cumsum`` at
+#: 2^20 rows takes XLA's TPU compiler 22.7 s to build, rows of 1,024
+#: and then their totals 1.6 s (ROADMAP S10)
+PREFIX_BLOCK = 1024
+#: validities ride through ``compact`` as one bit each of a uint32 word
+VALIDITY_WORD_BITS = 32
+
+
+def _rejected_before(keep: jax.Array) -> jax.Array:
+    """int32[capacity]: the rows before each row that ``keep`` rejects,
+    by a two-level prefix sum (within rows of PREFIX_BLOCK, then over
+    the rows' totals), the capacity padded to a whole row."""
+    capacity = keep.shape[0]
+    padded = -(-capacity // PREFIX_BLOCK) * PREFIX_BLOCK
+    rejected = jnp.pad(~keep, (0, padded - capacity)).astype(
+        jnp.int32).reshape(-1, PREFIX_BLOCK)
+    within = jnp.cumsum(rejected, axis=1)
+    totals = within[:, -1]
+    before = jnp.cumsum(totals) - totals
+    return (within - rejected + before[:, None]).reshape(-1)[:capacity]
+
+
+def _shift_up(a: jax.Array, s: jax.Array) -> jax.Array:
+    """``a`` moved ``s`` rows towards row 0, zeros behind it."""
+    return jax.lax.dynamic_slice(jnp.pad(a, (0, a.shape[0])), (s,), a.shape)
+
+
 @jax.named_scope("ydb.compact")
 def compact(block: TableBlock, selected: jax.Array) -> TableBlock:
     """Move selected live rows to the front (stable), update length.
 
-    selected: bool[capacity]; rows outside the live range must be False
-    (callers AND with block.row_mask()).
+    selected: bool[capacity]; a row at or beyond ``length`` is dropped
+    whatever it says. Rows at and beyond the new length are padding:
+    validity False, data unspecified.
+
+    A stream compaction without a sort, a gather or a scatter (a 1-D
+    gather costs a TPU 7-27 ms per 2^20 indices whatever the bytes):
+    row ``i`` has to travel ``d[i]`` = the rejected rows before it, and
+    round ``b`` of ceil(log2(capacity)) moves the rows whose ``d`` has
+    bit ``b`` set by 2^b: a slice and a select over each column in its
+    own dtype (Hacker's Delight's "compress" in Steele's
+    parallel-prefix form). Two kept rows never meet: for ``i < j``,
+    ``(d[j] mod m) - (d[i] mod m) <= d[j] - d[i] < j - i``. The
+    validities ride as one uint32 word per 32 columns; a vacated or
+    rejected row carries ``d`` = 0 and never moves. The rounds are one
+    loop body, so a program holds it once whatever the capacity.
     """
     keep = selected & block.row_mask()
-    # stable partition: the rows not kept go last, each part in its order
-    perm = stable_partition(~keep)
-    cols = {
-        n: Column(c.data[perm], c.validity[perm] & keep[perm])
-        for n, c in block.columns.items()
-    }
     n = jnp.sum(keep).astype(jnp.int32)
-    return TableBlock(cols, n, block.schema)
+    cols = list(block.columns.values())
+    words = tuple(
+        functools.reduce(jnp.bitwise_or, (
+            c.validity.astype(jnp.uint32) << bit
+            for bit, c in enumerate(cols[at:at + VALIDITY_WORD_BITS])))
+        for at in range(0, len(cols), VALIDITY_WORD_BITS))
+
+    def one_round(b, state):
+        carried, d = state
+        s = jnp.int32(1) << b
+        moves = ((d >> b) & 1) != 0
+        arrives = _shift_up(moves, s)
+        carried = tuple(jnp.where(arrives, _shift_up(x, s), x)
+                        for x in carried)
+        return carried, jnp.where(arrives, _shift_up(d, s),
+                                  jnp.where(moves, 0, d))
+
+    carried, _ = jax.lax.fori_loop(
+        0, max(block.capacity - 1, 0).bit_length(), one_round,
+        (tuple(c.data for c in cols) + words,
+         jnp.where(keep, _rejected_before(keep), 0)))
+    live = jnp.arange(block.capacity, dtype=jnp.int32) < n
+    words = carried[len(cols):]
+    return TableBlock({
+        name: Column(
+            carried[i],
+            (((words[i // VALIDITY_WORD_BITS] >> (i % VALIDITY_WORD_BITS))
+              & 1) != 0) & live)
+        for i, name in enumerate(block.columns)}, n, block.schema)
 
 
 # ---------------- grouped aggregation ----------------
