@@ -32,8 +32,9 @@ COVERING = {"ydb.query", "ydb.execute", "ydb.dq", "ydb.scan",
             "ydb.mesh.shuffle", "ydb.mesh.join"}
 #: spans of the newest statement's profile reported with their attrs
 #: (an exchange's bucket sizes, worst count, attempts and bytes; a local
-#: join's capacities and attempts)
-REPORTED_SPANS = ("mesh.shuffle", "mesh.join")
+#: join's capacities and attempts; a host concatenation's blocks, rows
+#: and bytes; a Transform's capacity, group layout, key words and tier)
+REPORTED_SPANS = ("mesh.shuffle", "mesh.join", "host.concat", "transform")
 #: a device operation that crosses devices, by its HLO name
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter")
@@ -291,13 +292,18 @@ def main(argv=None) -> int:
         report = getattr(cluster, "mesh_report", lambda: [])()
         if report:
             found["mesh_report"] = report
-        newest = cluster.profiles.recent()[-1:]
-        spans = [dict(sp["attrs"], name=sp["name"], seconds=sp["seconds"])
-                 for p in newest for sp in p.spans
-                 if sp["name"] in REPORTED_SPANS]
-        if spans:
-            found["newest_statement"] = {"stages": dict(newest[0].stages),
-                                         "spans": spans}
+        # the newest execution of each statement text of the round
+        by_sql = {p.sql: p for p in cluster.profiles.recent()[-8:]}
+        reported = [
+            {"sql": p.sql, "seconds": p.seconds, "stages": dict(p.stages),
+             "spans": [dict(sp["attrs"], name=sp["name"],
+                            seconds=sp["seconds"])
+                       for sp in p.spans if sp["name"] in REPORTED_SPANS]}
+            for p in by_sql.values()]
+        reported = [r for r in reported if r["spans"]]
+        if reported:
+            found["newest_statement"] = reported[-1]
+            found["newest_statements"] = reported
         return totals(cluster)
 
     run.deploy.resident_totals = totals_and_mesh_report

@@ -297,8 +297,13 @@ def device_aux(aux: Mapping[str, object]) -> dict:
 
 @host_ok("host-side concat for readers/tests; the warm scan path"
          " merges on device (merge_blocks_device) instead")
-def concat_blocks(blocks: list[TableBlock], capacity: int | None = None) -> TableBlock:
-    """Host-side concat of live rows into one block (used by readers/tests)."""
+def concat_blocks(blocks: list[TableBlock], capacity=None) -> TableBlock:
+    """Host-side concat of live rows into one block (used by readers/tests).
+
+    ``capacity``: the result's, or a function of its live rows (the
+    walk passes ``plan_fuse.shape_class``, so that what it compiles over
+    the result is not shaped by the selected row count); by default the
+    rows rounded up to the capacity quantum."""
     if not blocks:
         raise ValueError("concat of no blocks")
     schema = blocks[0].schema
@@ -323,11 +328,16 @@ def concat_blocks(blocks: list[TableBlock], capacity: int | None = None) -> Tabl
 
     arrays: dict[str, np.ndarray] = {}
     validity: dict[str, np.ndarray] = {}
-    with tracing.span("host.concat", blocks=len(blocks)):
+    with tracing.span("host.concat", blocks=len(blocks)) as sp:
         for name in schema.names:
             arrays[name] = np.concatenate(
                 fetched(TableBlock.to_numpy, name))
             validity[name] = np.concatenate(
                 fetched(TableBlock.validity_numpy, name))
+        rows = len(next(iter(arrays.values()))) if arrays else 0
+        sp.set(rows=rows, bytes=sum(
+            a.nbytes for a in (*arrays.values(), *validity.values())))
+        if callable(capacity):
+            capacity = capacity(rows)
         return TableBlock.from_numpy(arrays, schema, validity,
                                      capacity=capacity)

@@ -344,8 +344,8 @@ class ScanExecutor:
                                  self._finalize_jit, tuple(partials),
                                  self._final_aux)
 
-    def run_stream(self, blocks, timer=None,
-                   consumed_cb=None) -> TableBlock:
+    def run_stream(self, blocks, timer=None, consumed_cb=None,
+                   concat_capacity=None) -> TableBlock:
         """Drive a block stream with bounded in-flight work; returns the
         result block (merged partials finalized, or concatenated rows).
 
@@ -356,6 +356,10 @@ class ScanExecutor:
         ``consumed_cb`` (called once per admitted block), returns the
         in-order consumption credit that lets the producer account its
         double-buffered slabs.
+
+        ``concat_capacity``: ``concat_blocks``' ``capacity``, for a
+        program without a final stage, whose block outputs the host
+        concatenates.
 
         ``timer`` (obs.probes.StageTimer) charges device dispatch +
         backpressure waits to the "compute" stage; time spent PULLING
@@ -408,7 +412,7 @@ class ScanExecutor:
             if self.final is None:
                 # pure filter/project program: block outputs concatenate
                 out = (partials[0] if len(partials) == 1
-                       else concat_blocks(partials))
+                       else concat_blocks(partials, concat_capacity))
             else:
                 out = self.finalize(partials)
             from ydb_tpu.obs import timeline

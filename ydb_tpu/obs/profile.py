@@ -64,6 +64,18 @@ MESH_KEY = "mesh"
 #: ``seconds``, and a statement without such a span has neither key.
 MESH_SPAN_KEYS = {"mesh": MESH_KEY, "mesh.shuffle": "mesh_shuffle",
                   "mesh.join": "mesh_join"}
+#: and two of the one-chip walk's path for an aggregate that does not
+#: push down into its scan (a sort-derived group layout), by the same
+#: rule and only of a statement that has such a span outside any mesh
+#: span: ``concat``, the self time of the ``host.concat`` span that
+#: turns the scan's block outputs into the one block a Transform reads
+#: and of the ``device.get`` / ``dispatch`` / ``device.wait`` spans
+#: beneath it; ``transform``, the same of the ``transform`` span (its
+#: program enqueued, the wait for it). They are taken out of
+#: ``dispatch``, ``device_wait`` and, the ``transform`` span's own time,
+#: ``unattributed``; a mesh statement's ``host.concat`` spans stay
+#: where they were (``mesh_join_scan_ms`` reads them there).
+WALK_SPAN_KEYS = {"host.concat": "concat", "transform": "transform"}
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
@@ -195,15 +207,34 @@ def statement_stages(spans, seconds: float) -> dict:
     names = {s.name for s in spans}
     mesh_keys = [k for n, k in MESH_SPAN_KEYS.items() if n in names]
     out.update(dict.fromkeys(mesh_keys, 0.0))
+    # a statement with neither kind of span (Q1, Q6 pushed down; Q3 on
+    # DQ) looks up no span's ancestors
+    walk = not mesh_keys and not names.isdisjoint(WALK_SPAN_KEYS)
     selfs = self_seconds(spans)
     for s in spans:
         stage = SPAN_STAGE.get(s.name)
-        if stage is not None and s.thread == thread and s.annotated:
-            if mesh_keys and stage in ("dispatch", "device_wait"):
+        if s.thread != thread or not s.annotated:
+            continue
+        carved = stage in ("dispatch", "device_wait")
+        if mesh_keys:
+            if carved:
                 stage = _mesh_key(s, by_id) or stage
-            out[stage] += selfs[s.span_id]
+        elif walk and (carved or s.name in WALK_SPAN_KEYS):
+            stage = _walk_key(s, by_id) or stage
+        if stage is not None:
+            out[stage] = out.get(stage, 0.0) + selfs[s.span_id]
     out["unattributed"] = max(0.0, seconds - sum(out.values()))
     return out
+
+
+def _walk_key(span, by_id: dict) -> str | None:
+    """``concat`` or ``transform`` for a ``host.concat`` or
+    ``transform`` span and what is beneath one, by the nearest above."""
+    while span is not None:
+        if span.name in WALK_SPAN_KEYS:
+            return WALK_SPAN_KEYS[span.name]
+        span = by_id.get(span.parent_id)
+    return None
 
 
 def _mesh_key(span, by_id: dict) -> str | None:
@@ -434,8 +465,9 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
     st = profile.stages
     lines.append("stages: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in STAGE_KEYS))
-    keys = STATEMENT_KEYS + tuple(k for k in MESH_SPAN_KEYS.values()
-                                  if k in st)
+    keys = STATEMENT_KEYS + tuple(
+        k for k in (*MESH_SPAN_KEYS.values(), *WALK_SPAN_KEYS.values())
+        if k in st)
     lines.append("statement: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in keys))
     pr = profile.pruning
@@ -458,7 +490,8 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
         # (bucket sizes, worst count, attempts, bytes; capacities)
         shown = a if s["name"] in MESH_SPAN_KEYS and s["name"] != MESH_KEY \
             else ("table", "shard", "device", "devices", "answered",
-                  "rows", "compile_cache", "agg_pushdown")
+                  "rows", "compile_cache", "agg_pushdown",
+                  "pushdown_declined")
         bits += [f"{k}={a[k]}" for k in shown if k in a]
         lines.append(f"  {s['name']}: " + " ".join(bits))
     return "\n".join(lines)

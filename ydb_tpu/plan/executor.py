@@ -170,6 +170,9 @@ class _Memo(dict):
                 consumers[id(child)] += 1
                 stack.append(child)
         self.shared = {i for i, c in consumers.items() if c > 1}
+        #: id(TableScan) -> why the aggregate pushdown left the scan to
+        #: run alone (``_scan_aggregated``): its ``scan`` span says so
+        self.declined: dict[int, str] = {}
 
 
 def _partition_for_dq(src) -> list:
@@ -438,8 +441,8 @@ def _pushdown_scan(plan: Transform, shared: set) -> TableScan | None:
     return None
 
 
-def _scan_aggregated(plan: Transform, db: Database,
-                     shared: set) -> TableBlock | None:
+def _scan_aggregated(plan: Transform, db: Database, shared: set,
+                     declined: dict | None = None) -> TableBlock | None:
     """Run ``Transform(TableScan)`` as one scan that aggregates each
     block under its filter mask, folds the partial states on the device
     and finalizes in one dispatch: no block is compacted, nothing is
@@ -448,13 +451,17 @@ def _scan_aggregated(plan: Transform, db: Database,
     the Transform over the scan's output): ``_pushdown_scan``'s
     conditions, or a group layout whose partials are not shape-stable
     (sort-derived: per-block sorts and a merge of N-group partials are
-    another trade). The executor that says so stays cached, so the
-    next run of the statement asks a dict."""
+    another trade: ``declined``, the memo's, then says
+    ``layout=sorted`` for the scan). The executor that says so stays
+    cached, so the next run of the statement asks a dict."""
     pushed = _pushdown_scan(plan, shared)
     if pushed is None:
         return None
     ex, fresh = _scan_executor(pushed, db, plan.dict_aliases)
     if not ex.folds_partials:
+        if declined is not None:
+            declined[id(plan.input)] = "layout=" + _LAYOUT_NAMES.get(
+                ex.partial.group_layout[0], "none")
         return None
     with tracing.span("scan") as sp:
         sp.set(agg_pushdown=1)
@@ -464,6 +471,7 @@ def _scan_aggregated(plan: Transform, db: Database,
 def _scan_node(plan: TableScan, db: Database, sp, ex: ScanExecutor,
                fresh: bool) -> TableBlock:
     from ydb_tpu.obs.probes import StageTimer
+    from ydb_tpu.ssa import plan_fuse
 
     src = db.sources[plan.table]
     # stage accounting while a query trace records OR a probe session
@@ -511,7 +519,11 @@ def _scan_node(plan: TableScan, db: Database, sp, ex: ScanExecutor,
             stream = bc.stream(
                 key_of(ex.read_cols, db.scan_block_rows),
                 lambda: raw_stream)
-        out = ex.run_stream(stream, timer=timer)
+        # a scan with no final program ends in one host-concatenated
+        # block: at a shape class of its rows, so that the Transform
+        # over it compiles once a class and not once a selected count
+        out = ex.run_stream(stream, timer=timer,
+                            concat_capacity=plan_fuse.shape_class)
     finally:
         if timer is not None and hasattr(base_src, "attach_timer"):
             base_src.attach_timer(None)
@@ -730,16 +742,37 @@ def _compiled_transform(plan: Transform, schema, db: Database):
         plan.program, schema, db.dicts, db.key_spaces,
         dict_aliases=dict(plan.dict_aliases),
     )
-    return jax.jit(cp.run), device_aux(cp.aux)
+    # ``notes``: the group-by's layout, and (filled by the first trace
+    # of ``run``) its key words and reduce tier, for the span
+    notes = cp.notes
+    notes["group_layout"] = _LAYOUT_NAMES.get(cp.group_layout[0], "none")
+    return jax.jit(cp.run), device_aux(cp.aux), notes
+
+
+#: compiler.CompiledProgram.group_layout -> the ``group_layout`` the
+#: spans say: a sort-derived layout's groups come out compacted
+_LAYOUT_NAMES = {"keyless": "keyless", "dense": "dense",
+                 "dense_slots": "dense", "compact": "sorted"}
 
 
 def _transform_node(plan: Transform, block: TableBlock,
                     db: Database) -> TableBlock:
     """Run a Transform's program over its input's result block, in a
-    program compiled at that block's capacity."""
+    program compiled at a shape class of that block's capacity
+    (``plan_fuse.shape_class``, as ``ssa/join.py`` fits a join's sides):
+    the rows past the live length are dead as they are in any block, and
+    a new selected row count builds no program. The walk's scan already
+    concatenates to a class (``_scan_node``); a join's output is fitted
+    here."""
+    from ydb_tpu.ssa import plan_fuse
+
     key = (plan.program, plan.dict_aliases, block.schema)
     hit = db._compile_cache.get(key)
     with tracing.span("transform") as sp:
+        capacity = plan_fuse.shape_class(block.capacity)
+        if capacity != block.capacity:
+            with tracing.span("dispatch", program="transform_fit"):
+                block = plan_fuse.fit_blocks((block,), capacity)
         if hit is None:
             sp.set(compile_cache="miss")
             # mandatory precondition (ydb_tpu.analysis): surface
@@ -752,9 +785,16 @@ def _transform_node(plan: Transform, block: TableBlock,
             db._compile_cache[key] = hit
         else:
             sp.set(compile_cache="hit")
-        run, aux = hit
+        run, aux, notes = hit
         with tracing.span("dispatch", program="transform"):
-            return run(block, aux)
+            out = run(block, aux)
+        if sp.recording:
+            # the wait for the program belongs to this span (the
+            # statement key ``transform``), as a scan's row count does
+            # to its own: the session's fetch would wait a moment later
+            sp.set(capacity=capacity, rows_in=block.live_rows(),
+                   rows=out.live_rows(), **notes)
+        return out
 
 
 def _execute_node(plan: PlanNode, db: Database,
@@ -764,6 +804,9 @@ def _execute_node(plan: PlanNode, db: Database,
         if plan.program is None:
             return _materialize(src, plan.columns)
         with tracing.span("scan") as sp:
+            declined = _memo.declined.get(id(plan))
+            if declined is not None:
+                sp.set(agg_pushdown=0, pushdown_declined=declined)
             return _scan_node(plan, db, sp, *_scan_executor(plan, db))
     if isinstance(plan, LookupJoin):
         probe = execute_plan(plan.probe, db, _memo)
@@ -782,7 +825,7 @@ def _execute_node(plan: PlanNode, db: Database,
             build_payload=plan.build_payload,
         )
     if isinstance(plan, Transform):
-        out = _scan_aggregated(plan, db, _memo.shared)
+        out = _scan_aggregated(plan, db, _memo.shared, _memo.declined)
         if out is not None:
             return out
         return _transform_node(

@@ -65,6 +65,13 @@ class CompiledProgram:
     out_schema: dtypes.Schema
     in_schema: dtypes.Schema
     group_layout: tuple = (None, None)
+    #: what the newest trace of ``run`` settled about its group-by,
+    #: known only once the block's capacity is: ``groups`` (the slots
+    #: the states are sized to), ``key_words`` (32-bit words of the key
+    #: a sort-derived layout sorts by) and ``reduce_tier``
+    #: (kernels.reduce_tier of each accumulator bank); the executor's
+    #: ``transform`` span carries them
+    notes: dict = dataclasses.field(default_factory=dict, compare=False)
     # aux staged to the device once, on first dispatch — restaging the
     # whole dict per call cost an H2D transfer per statement. Staleness
     # is impossible: the compile caches key on the dict contents and
@@ -97,6 +104,7 @@ class _Lowering:
         # psum/pmin/pmax merging over the mesh
         self.partial_slots = partial_slots
         self.group_layout: tuple = (None, None)
+        self.notes: dict = {}
         # advisory NDV-based distinct-group estimate (compile_program)
         self.group_est: float | None = None
         self.types: dict[str, dtypes.LogicalType] = {
@@ -434,7 +442,8 @@ def _compile_program(
         return kernels.compact(blk, mask)
 
     return CompiledProgram(run=run, aux=ctx.aux, out_schema=out_schema,
-                           in_schema=schema, group_layout=ctx.group_layout)
+                           in_schema=schema, group_layout=ctx.group_layout,
+                           notes=ctx.notes)
 
 
 # ---------------- expression lowering helpers ----------------
@@ -1155,6 +1164,12 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
         results = {dtype: kernels.fused_group_reduce(
                        stacked, gid, ng, dtype=dtype)
                    for dtype, stacked in banks.items()}
+        ctx.notes.update(
+            groups=ng,
+            key_words=sum(-(-k.data.dtype.itemsize // 4) for k in kcols),
+            reduce_tier="+".join(sorted({
+                kernels.reduce_tier(ng, dtype, stacked.shape[1])
+                for dtype, stacked in banks.items()})))
 
         def state(key):
             dtype, i = slot_ix[key]

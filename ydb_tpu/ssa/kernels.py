@@ -377,6 +377,20 @@ def first_live_index(hits: jax.Array) -> tuple[jax.Array, jax.Array]:
     return jnp.minimum(first, max(n - 1, 0)), found
 
 
+def reduce_tier(num_groups: int, dtype, n_slots: int) -> str:
+    """The tier ``fused_group_reduce`` takes for a bank of ``n_slots``
+    accumulators of ``dtype`` over ``num_groups`` groups: ``onehot``,
+    ``pallas`` or ``scatter`` (the rule is in its docstring)."""
+    if num_groups <= ONEHOT_GROUP_LIMIT:
+        return "onehot"
+    from ydb_tpu.ssa import pallas_kernels
+
+    if pallas_kernels.enabled() and pallas_kernels.supported_fused(
+            dtype, num_groups, n_slots):
+        return "pallas"
+    return "scatter"
+
+
 @jax.named_scope("ydb.fused_group_reduce")
 def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
                        num_groups: int, dtype=None) -> jax.Array:
@@ -401,17 +415,17 @@ def fused_group_reduce(stacked: jax.Array, gid: jax.Array,
     """
     dtype = jnp.dtype(dtype or stacked.dtype)
     stacked = stacked.astype(dtype)
-    if num_groups <= ONEHOT_GROUP_LIMIT:
+    tier = reduce_tier(num_groups, dtype, stacked.shape[1])
+    if tier == "onehot":
         hits = group_hits(gid, num_groups)
         zero = jnp.zeros((), dtype=dtype)
         return jnp.stack(
             [jnp.sum(jnp.where(hits, stacked[:, j][:, None], zero), axis=0,
                      dtype=dtype)
              for j in range(stacked.shape[1])], axis=1)
-    from ydb_tpu.ssa import pallas_kernels
+    if tier == "pallas":
+        from ydb_tpu.ssa import pallas_kernels
 
-    if pallas_kernels.enabled() and pallas_kernels.supported_fused(
-            dtype, num_groups, stacked.shape[1]):
         return pallas_kernels.grouped_sum_multi(stacked, gid, num_groups)
     out = jnp.zeros((num_groups, stacked.shape[1]), dtype=dtype)
     return out.at[gid].add(stacked, mode="drop")
