@@ -20,7 +20,7 @@ from ydb_tpu.plan import execute_plan, to_host
 from ydb_tpu.plan.nodes import Concat, TableScan, Transform
 from ydb_tpu.sql.parser import parse
 from ydb_tpu.sql.planner import plan_select_full
-from ydb_tpu.ssa import plan_fuse
+from ydb_tpu.ssa import kernels, plan_fuse
 from ydb_tpu.ssa.ops import Agg
 from ydb_tpu.ssa.program import (
     AggSpec,
@@ -275,6 +275,21 @@ def test_other_shapes_keep_the_old_path(cluster, case):
     if case == "scan_read_twice":
         assert names["scan"] == 1    # the memo ran the shared scan once
     check(out, _ev_numpy())
+
+
+@pytest.mark.parametrize("case", ("tpch_q1", "order_limit"))
+def test_the_transform_span_says_which_way_its_sort_went(cluster, case):
+    """Q1 orders without a limit and keeps the whole sort; so does a
+    LIMIT over a block of a few thousand slots (``kernels.sort_tier``)."""
+    plan = _plan(cluster, PUSHED[case])
+    with profile_mod.profiled() as held:
+        out = _old_path(plan, cluster.snapshot_db())
+    (transform,) = [sp for sp in held.profile.spans
+                    if sp["name"] == "transform"]
+    attrs = transform["attrs"]
+    assert attrs["sort_tier"] == "whole"
+    assert attrs.get("sort_limit") == (3 if case == "order_limit" else None)
+    assert 3 * kernels.TOPK_ROOM > out.capacity
 
 
 def test_pushed_down_statement_profile(cluster):

@@ -132,6 +132,8 @@ def test_the_walk_answers_exactly_and_says_where_the_time_went(
     assert attrs["group_layout"] == "sorted"
     assert attrs["key_words"] == KEY_WORDS[sid]
     assert attrs["reduce_tier"] == "scatter"
+    # the top-10 selects its rows: nothing orders the capacity (PR 38)
+    assert attrs["sort_tier"] == "select" and attrs["sort_limit"] == 10
     assert attrs["compile_cache"] == "hit"
 
     keys = STATEMENT_KEYS + tuple(WALK_SPAN_KEYS.values())
@@ -139,8 +141,51 @@ def test_the_walk_answers_exactly_and_says_where_the_time_went(
     assert sum(p.stages[k] for k in keys) == pytest.approx(
         p.seconds, abs=max(0.01 * p.seconds, 2e-4))
     assert p.stages["concat"] > 0 and p.stages["transform"] > 0
-    # the program's wait is the transform's, not the fetch's
-    assert p.stages["transform"] > p.stages["device_wait"]
+    # the program's wait is the transform's, not the fetch's, however
+    # short the program: the wait for it is a span beneath `transform`,
+    # after its dispatch, and the two keys split the waits by where
+    # their spans stand, whatever their seconds (Q12's transform takes
+    # 4 ms here since its top-10 selects, no longer than its scan's
+    # wait for the filter's row count)
+    beneath = [sp for sp in p.spans
+               if sp["parent_id"] == transform["span_id"]]
+    kinds = [(sp["name"], sp["attrs"].get("program")) for sp in beneath]
+    # the counts of the rows in and out: the second waits for the program
+    assert kinds[kinds.index(("dispatch", "transform")):] == [
+        ("dispatch", "transform"), ("device.wait", None),
+        ("device.wait", None)]
+    assert all(sp["name"] in ("dispatch", "device.wait") for sp in beneath)
+    assert p.stages["transform"] == pytest.approx(
+        transform["seconds"], abs=1e-5)
+    assert p.stages["transform"] > sum(
+        sp["seconds"] for sp in beneath) - 1e-5       # each is rounded
+    by_id = {sp["span_id"]: sp for sp in p.spans}
+
+    def in_the_walk(sp):
+        while sp is not None and sp["name"] not in WALK_SPAN_KEYS:
+            sp = by_id.get(sp["parent_id"])
+        return sp is not None
+
+    waits = [sp for sp in p.spans
+             if sp["name"] in ("device.wait", "device.get")]
+    assert p.stages["device_wait"] == pytest.approx(
+        sum(sp["seconds"] for sp in waits if not in_the_walk(sp)),
+        abs=1e-6 * len(waits))
+
+
+def test_an_order_without_a_limit_keeps_the_whole_sort(deployment):
+    data, cluster = deployment
+    res, p = warm_profile(
+        cluster, "select UserID, count(*) as c from hits group by UserID "
+                 "order by c desc, UserID")
+    (transform,) = by_name(p, "transform")
+    assert transform["attrs"]["sort_tier"] == "whole"
+    assert "sort_limit" not in transform["attrs"]
+    users, counts = np.unique(data.tables["hits"]["UserID"],
+                              return_counts=True)
+    order = np.lexsort((users, -counts))
+    assert np.array_equal(np.asarray(res.cols["UserID"][0]), users[order])
+    assert np.array_equal(np.asarray(res.cols["c"][0]), counts[order])
 
 
 @pytest.mark.parametrize("sql", (

@@ -303,3 +303,50 @@ def test_compact_moves_a_block_without_a_sort_or_a_gather(
              if re.search(r"\b(sort|gather|scatter)\(", ln)]
     assert not moved, (rows, moved[:3])
     assert "ydb.compact" in text
+
+
+#: the Transform's capacity over ClickBench's `hits` on one chip: the
+#: shape class of 12.5M rows (PERF.md section 5)
+HITS_CAPACITY = 12_582_912
+
+
+def test_a_top_10_moves_ten_rows_of_a_hits_sized_block(
+        one_chip, no_persistent_cache, capsys):
+    """ClickBench Q15's ``order by c desc, UserID limit 10`` over the
+    group-by's 12.58M slots (two int64 keys, their validities, the live
+    flag): ``kernels.sort_block`` selects its ten rows (PR 38). No sort,
+    gather or scatter is as long as the block, and the selection's words
+    fit beside the resident table."""
+    import time
+
+    from ydb_tpu import dtypes
+    from ydb_tpu.blocks.block import Column, TableBlock
+    from ydb_tpu.ssa import kernels
+
+    rows = HITS_CAPACITY
+    schema = dtypes.schema(("UserID", dtypes.INT64), ("c", dtypes.INT64))
+    block = TableBlock(
+        {n: Column(_shape((rows,), "int64", one_chip),
+                   _shape((rows,), "bool", one_chip))
+         for n in schema.names},
+        _shape((), "int32", one_chip), schema)
+    assert kernels.sort_tier(10, rows, [jnp.int64, jnp.int64]) == "select"
+
+    def top10(block, live):
+        return kernels.sort_block(block, ["c", "UserID"], [True, False], 10,
+                                  live=live)
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(top10).lower(
+        block, _shape((rows,), "bool", one_chip)).compile()
+    with capsys.disabled():
+        print(f"\na top-10 over {rows} slots compiled for a described v5e "
+              f"in {time.perf_counter() - t0:.1f} s")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES / 8, temp
+    text = compiled.as_text()
+    assert "ydb.sort_block" in text
+    long = [ln.strip()[:140] for ln in text.splitlines()
+            if re.search(r"\b(sort|gather|scatter)\(", ln)
+            and str(rows) in ln.split(" gather(")[0].split(" scatter(")[0]]
+    assert not long, long[:3]

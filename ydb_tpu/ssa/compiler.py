@@ -65,12 +65,14 @@ class CompiledProgram:
     out_schema: dtypes.Schema
     in_schema: dtypes.Schema
     group_layout: tuple = (None, None)
-    #: what the newest trace of ``run`` settled about its group-by,
-    #: known only once the block's capacity is: ``groups`` (the slots
-    #: the states are sized to), ``key_words`` (32-bit words of the key
-    #: a sort-derived layout sorts by) and ``reduce_tier``
-    #: (kernels.reduce_tier of each accumulator bank); the executor's
-    #: ``transform`` span carries them
+    #: what the newest trace of ``run`` settled about its group-by and
+    #: its sort, known only once the block's capacity is: ``groups``
+    #: (the slots the states are sized to), ``key_words`` (32-bit words
+    #: of the key a sort-derived layout sorts by), ``reduce_tier``
+    #: (kernels.reduce_tier of each accumulator bank), ``sort_tier``
+    #: (kernels.sort_tier of its SortStep) and ``sort_limit`` (that
+    #: step's LIMIT, where it has one); the executor's ``transform``
+    #: span carries them
     notes: dict = dataclasses.field(default_factory=dict, compare=False)
     # aux staged to the device once, on first dispatch — restaging the
     # whole dict per call cost an H2D transfer per statement. Staleness
@@ -378,6 +380,10 @@ def _compile_program(
                         dtypes.Field(n, cur_types.get(n, dtypes.INT64))
                         for n in tmp_names)),
                 )
+                ctx.notes["sort_tier"] = kernels.sort_tier(
+                    limit, blk.capacity, (c.data.dtype for c in sort_cols))
+                if limit is not None:
+                    ctx.notes["sort_limit"] = limit
                 # single lexsort pass: the filter mask rides in as `live`
                 # (non-selected rows sink past the length cut)
                 blk = kernels.sort_block(

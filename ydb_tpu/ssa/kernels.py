@@ -12,7 +12,12 @@ TPU analog of the reference's block operators:
     sort-derived group ids + scatter-reduce with a *static* group capacity;
     invalid rows scatter to an out-of-bounds index in 'drop' mode instead
     of branching
-  * ``sort_block`` / top-k — WideTopSort / BlockTop (mkql_block_top.cpp)
+  * ``sort_block`` — WideTopSort / BlockTop (mkql_block_top.cpp): a
+    stable sort of the whole block, one pass a 32-bit word of the keys;
+    under a LIMIT far below the capacity (``sort_tier``:
+    ``limit * TOPK_ROOM <= capacity``, a key, none floating) a top-k:
+    an exact radix selection of the ``limit`` first rows of that order
+    (``_select_first``), then the sort of those rows alone
 
 All primitives keep static shapes; "how many" results there are is always a
 traced int32 scalar, never a shape.
@@ -160,6 +165,19 @@ def stable_partition(last: jax.Array, classes: int = 2) -> jax.Array:
             & jnp.uint32((1 << shift) - 1)).astype(jnp.int32)
 
 
+def _words32(key: jax.Array) -> list[jax.Array]:
+    """A sort key as the words one orders it by, least significant
+    first: a flag as it is, a 64-bit integer as its low (unsigned) then
+    its high word, anything narrower as one int32 or uint32."""
+    if key.dtype == jnp.bool_:
+        return [key]
+    signed = jnp.issubdtype(key.dtype, jnp.signedinteger)
+    if key.dtype.itemsize == 8:
+        return [(key & 0xFFFFFFFF).astype(jnp.uint32),
+                (key >> 32).astype(jnp.int32 if signed else jnp.uint32)]
+    return [key.astype(jnp.int32 if signed else jnp.uint32)]
+
+
 def stable_lexsort(keys) -> jax.Array:
     """``jnp.lexsort(keys)`` (the LAST key is primary, equal rows keep
     their order) as one stable pass a 32-bit word of a key, from the
@@ -174,17 +192,7 @@ def stable_lexsort(keys) -> jax.Array:
         return jnp.lexsort(tuple(keys)).astype(jnp.int32)
     order = None
     for key in keys:
-        if key.dtype == jnp.bool_:
-            words = [key]
-        elif key.dtype.itemsize == 8:
-            signed = jnp.issubdtype(key.dtype, jnp.signedinteger)
-            words = [(key & 0xFFFFFFFF).astype(jnp.uint32),
-                     (key >> 32).astype(jnp.int32 if signed else jnp.uint32)]
-        elif jnp.issubdtype(key.dtype, jnp.signedinteger):
-            words = [key.astype(jnp.int32)]
-        else:
-            words = [key.astype(jnp.uint32)]
-        for word in words:
+        for word in _words32(key):
             if order is not None:
                 word = word[order]
             step = (stable_partition(word) if word.dtype == jnp.bool_
@@ -494,17 +502,13 @@ def _extreme(dtype, maximum: bool):
 # ---------------- sort / top-k ----------------
 
 
-@jax.named_scope("ydb.sort_perm")
-def sort_perm(
-    keys: list[Column],
-    descending: list[bool],
-    live: jax.Array,
-) -> jax.Array:
-    """Stable multi-key sort permutation; dead rows sink to the end.
-
-    Descending numeric keys negate via bitwise complement on ints (exact,
-    overflow-free) and negation on floats; NULLS LAST within each key.
-    """
+def _sort_keys(keys: list[Column], descending: list[bool],
+               live: jax.Array) -> list[jax.Array]:
+    """The keys of the order as ``stable_lexsort`` takes them, the least
+    significant first: per ORDER BY key its data (a descending integer
+    complemented: exact, overflow-free; a float negated) and, above it,
+    its null flag (NULLS LAST in either direction); the dead flag above
+    all, so dead rows sink to the end."""
     sort_keys = []
     for k, desc in zip(reversed(keys), reversed(descending)):
         d = k.data
@@ -520,7 +524,103 @@ def sort_perm(
         sort_keys.append(d)
         sort_keys.append(~k.validity)
     sort_keys.append(~live)
-    return stable_lexsort(sort_keys)
+    return sort_keys
+
+
+@jax.named_scope("ydb.sort_perm")
+def sort_perm(
+    keys: list[Column],
+    descending: list[bool],
+    live: jax.Array,
+) -> jax.Array:
+    """Stable multi-key sort permutation; dead rows sink to the end.
+
+    Descending numeric keys negate via bitwise complement on ints (exact,
+    overflow-free) and negation on floats; NULLS LAST within each key.
+    """
+    return stable_lexsort(_sort_keys(keys, descending, live))
+
+
+#: a LIMIT engages the top-k where the capacity holds it this many
+#: times over (``sort_tier``). It guards two things, both read on a v5e
+#: (PERF.md section 6, PR 38): the selection's rounds cost ~1.2 ms
+#: whatever the capacity, which the whole sort beats below ~2,000 slots
+#: (a LIMIT 10 engages from 2,560), and the ``limit`` rows' own sort
+#: grows with the limit toward the whole block's
+TOPK_ROOM = 256
+
+
+def sort_tier(limit: int | None, capacity: int, key_dtypes) -> str:
+    """The way ``sort_block`` takes, settled at trace time from what it
+    sees of its input: ``select`` (a top-k: the ``limit`` first rows of
+    the order are found by an exact selection and only they are sorted)
+    where there is a key, none of them floating (a float's order has
+    NaNs and signed zeros: ``stable_lexsort`` leaves it to the
+    comparator sort) and ``0 < limit * TOPK_ROOM <= capacity``, so a
+    LIMIT near the capacity and a block of a few thousand slots keep the
+    ``whole`` sort, as every sort without a limit does."""
+    key_dtypes = list(key_dtypes)
+    if (limit and key_dtypes and limit * TOPK_ROOM <= capacity
+            and not any(jnp.issubdtype(d, jnp.floating)
+                        for d in key_dtypes)):
+        return "select"
+    return "whole"
+
+
+def _select_first(sort_keys: list[jax.Array], k: int) -> jax.Array:
+    """int32[k]: the rows that ``stable_lexsort(sort_keys)`` puts first,
+    in the order of their row numbers, found without ordering the rest.
+
+    The order's words are walked once, from the most significant (the
+    32-bit words of ``sort_keys`` from its last key, each mapped to an
+    unsigned word of the same order) down to the row number, which makes
+    the order total and is what a stable sort ties by. Per word a radix
+    select over the rows still tied with the k-th (``cand``): one round
+    a bit, each a compare and a count over the capacity, finds the
+    word's value ``t`` at the ``need``-th candidate; candidates below
+    ``t`` are taken, ``need`` falls by their count, and the candidates
+    become those equal to ``t``. After the row number one candidate is
+    left and ``need`` is 1: the taken rows and that one are the first
+    ``k``, exactly, whatever ties. The rounds of a word are ONE loop
+    body (an unrolled round costs XLA's CPU backend a kernel each).
+    The ``k`` row numbers come off the flags by ``compact``'s prefix
+    count (``_rejected_before``) and ``k`` binary searches in it."""
+    capacity = sort_keys[0].shape[0]
+    words = []
+    for key in reversed(sort_keys):
+        for word in reversed(_words32(key)):
+            bits = 1 if word.dtype == jnp.bool_ else 32
+            signed = word.dtype == jnp.int32
+            word = word.astype(jnp.uint32)
+            if signed:      # the sign bit flipped: the unsigned order
+                word = word ^ jnp.uint32(1 << 31)   # is the signed one
+            words.append((word, bits))
+    words.append((jnp.arange(capacity, dtype=jnp.uint32),
+                  max(capacity - 1, 1).bit_length()))
+
+    cand = jnp.ones((capacity,), dtype=bool)
+    taken = jnp.zeros((capacity,), dtype=bool)
+    need = jnp.int32(k)
+    for word, bits in words:
+
+        def one_round(i, t):    # traced at once: this word's closure
+            bit = jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32)
+            enough = jnp.sum(cand & (word <= (t | (bit - 1))),
+                             dtype=jnp.int32) >= need
+            return jnp.where(enough, t, t | bit)
+
+        t = jax.lax.fori_loop(0, bits, one_round, jnp.uint32(0))
+        below = cand & (word < t)
+        taken = taken | below
+        need = need - jnp.sum(below, dtype=jnp.int32)
+        cand = cand & (word == t)
+    first = taken | cand
+    # the first rows up to and with each row: the n-th is where that
+    # count first reads n
+    upto = (jnp.arange(capacity, dtype=jnp.int32) + first
+            - _rejected_before(first))
+    return jnp.searchsorted(
+        upto, jnp.arange(1, k + 1, dtype=jnp.int32)).astype(jnp.int32)
 
 
 @jax.named_scope("ydb.sort_block")
@@ -532,20 +632,42 @@ def sort_block(
     live: jax.Array | None = None,
 ) -> TableBlock:
     """Sort live (optionally pre-masked) rows; one lexsort pass does both
-    the selection compaction (non-live rows sink) and the ordering."""
+    the selection compaction (non-live rows sink) and the ordering.
+
+    Under a ``limit`` far below the capacity (``sort_tier`` says
+    ``select``) it is a top-k: ``_select_first`` finds the ``limit``
+    first rows of that same order, only those rows are gathered and
+    sorted, and the result is padded back to the capacity, so no sort
+    and no gather has the capacity's length. The rows, their order
+    (ties, NULLS LAST, masked and dead rows) and the length are the
+    whole sort's, row for row."""
     if live is None:
         live = block.row_mask()
     else:
         live = live & block.row_mask()
-    perm = sort_perm([block.columns[k] for k in keys], descending, live)
+    key_cols = [block.columns[k] for k in keys]
+    columns = block.columns
+    selects = sort_tier(limit, block.capacity,
+                        (c.data.dtype for c in key_cols)) == "select"
+    if selects:
+        first = _select_first(_sort_keys(key_cols, descending, live), limit)
+        columns = {n: Column(c.data[first], c.validity[first])
+                   for n, c in columns.items()}
+        key_cols = [columns[k] for k in keys]
+        live = live[first]
+    perm = sort_perm(key_cols, descending, live)
     cols = {
         n: Column(c.data[perm], c.validity[perm] & live[perm])
-        for n, c in block.columns.items()
+        for n, c in columns.items()
     }
     length = jnp.sum(live).astype(jnp.int32)
     if limit is not None:
         length = jnp.minimum(length, jnp.int32(limit))
     # zero validity past the length so padding never leaks
-    cut = jnp.arange(block.capacity, dtype=jnp.int32) < length
+    cut = jnp.arange(live.shape[0], dtype=jnp.int32) < length
     cols = {n: Column(c.data, c.validity & cut) for n, c in cols.items()}
+    if selects:
+        rest = (0, block.capacity - limit)
+        cols = {n: Column(jnp.pad(c.data, rest), jnp.pad(c.validity, rest))
+                for n, c in cols.items()}
     return TableBlock(cols, length, block.schema)

@@ -156,7 +156,12 @@ class ProjectStep:
 
 @dataclasses.dataclass(frozen=True)
 class SortStep:
-    """ORDER BY [+ LIMIT] — lowers to device argsort / top-k."""
+    """ORDER BY [+ LIMIT]: lowers to ``kernels.sort_block``, a stable
+    device sort of the whole block, or, under a ``limit`` that leaves
+    each of its rows ``kernels.TOPK_ROOM`` slots of the block's
+    capacity, with a key and no floating one (``kernels.sort_tier``), a
+    top-k: the ``limit`` first rows of that same order are found by an
+    exact radix selection and only they are sorted."""
 
     keys: tuple[str, ...]
     descending: tuple[bool, ...] = ()
