@@ -4,6 +4,13 @@ reduction: device-idle seconds by the innermost ``ydb.*`` host span
 that covers each gap, and device time by ``ydb.*`` named scope.
 
     python scripts/trace_breakdown.py --workload tpch-sf1.join --seed 7
+    python scripts/trace_breakdown.py --workload tpch-sf3.scan --seed 7 \
+        --background
+
+``--background`` also asks the other host threads (conveyor workers: a
+staging producer, a ``ydb.resident.promote``; a ``ydb.compact``) what
+they were doing during an idle gap that the statement's thread spent
+under ``dispatch`` / ``device.wait`` / ``device.get``.
 
 It is ``bench/run.py --trace 1`` with one more reader on the same
 ``.xplane.pb`` (the benchmark deletes the trace once it has reduced
@@ -30,6 +37,10 @@ PREFIX = "ydb."
 COVERING = {"ydb.query", "ydb.execute", "ydb.dq", "ydb.scan",
             "ydb.transform", "ydb.analyze", "ydb.mesh",
             "ydb.mesh.shuffle", "ydb.mesh.join"}
+#: spans in which the statement's thread enqueues a program or waits
+#: for the device: with ``--background`` an idle gap under one of them
+#: also names what any other host thread was doing
+WAITING = {"ydb.dispatch", "ydb.device.wait", "ydb.device.get"}
 #: spans of the newest statement's profile reported with their attrs
 #: (an exchange's bucket sizes, worst count, attempts and bytes; a local
 #: join's capacities and attempts; a host concatenation's blocks, rows
@@ -54,11 +65,33 @@ def host_lines(profile) -> list:
     return out
 
 
-def idle_by_span(busy, lines, lo, hi) -> dict:
+def _innermost(events, bounds) -> list:
+    """For each piece ``[bounds[i], bounds[i + 1])`` the name of the
+    shortest of ``events`` (sorted) open over it, ``(none)`` where none
+    is: one sweep, a heap by duration, ended events dropped as they
+    surface. ``bounds`` holds every start and end of ``events``."""
+    out, heap, nxt = [], [], 0
+    for x in bounds[:-1]:
+        while nxt < len(events) and events[nxt][0] <= x:
+            s, e, n = events[nxt]
+            heapq.heappush(heap, (e - s, n, e))
+            nxt += 1
+        while heap and heap[0][2] <= x:
+            heapq.heappop(heap)
+        out.append(heap[0][1] if heap else "(none)")
+    return out
+
+
+def idle_by_span(busy, lines, lo, hi, background: bool = False) -> dict:
     """Idle nanoseconds of [lo, hi) outside ``busy`` (sorted disjoint
     intervals), by the innermost event of the statement threads' lines
     (those that hold a ``ydb.query``) covering each instant; ``(none)``
-    where no ``ydb.*`` event covers it."""
+    where no ``ydb.*`` event covers it. With ``background``, an instant
+    the statement's thread spends in one of ``WAITING`` is put down to
+    that span AND the innermost event of any other host thread then
+    (``ydb.dispatch + ydb.resident.promote.put``): a promotion, a
+    compaction or a staging producer that held the interpreter while
+    the statement's thread was to enqueue or to be woken."""
     gaps, at = [], lo
     for s, e in busy:
         if at < hi and s > at:
@@ -66,25 +99,24 @@ def idle_by_span(busy, lines, lo, hi) -> dict:
         at = max(at, e)
     if hi > at:
         gaps.append((at, hi))
-    events = sorted(ev for evs in lines
-                    if any(n == "ydb.query" for _, _, n in evs)
+    statement = [any(n == "ydb.query" for _, _, n in evs) for evs in lines]
+    events = sorted(ev for evs, st in zip(lines, statement) if st
                     for ev in evs)
-    # one sweep over [lo, hi) cut at every event boundary: the shortest
-    # event open over a piece is its innermost (a heap by duration,
-    # ended events dropped as they surface), and each piece meets the
-    # gaps it overlaps. A window of hundreds of statements holds 10^5
-    # gaps and 10^4 events; gap by gap over all events is 10^9 steps.
-    bounds = sorted({lo, hi} | {t for s, e, _ in events for t in (s, e)
-                                if lo < t < hi})
-    out, heap, nxt, g = {}, [], 0, 0
-    for x, y in zip(bounds, bounds[1:]):
-        while nxt < len(events) and events[nxt][0] <= x:
-            s, e, n = events[nxt]
-            heapq.heappush(heap, (e - s, n, e))
-            nxt += 1
-        while heap and heap[0][2] <= x:
-            heapq.heappop(heap)
-        name = heap[0][1] if heap else "(none)"
+    others = sorted(ev for evs, st in zip(lines, statement)
+                    if background and not st for ev in evs)
+    # one sweep over [lo, hi) cut at every event boundary, and each
+    # piece meets the gaps it overlaps. A window of hundreds of
+    # statements holds 10^5 gaps and 10^4 events; gap by gap over all
+    # events is 10^9 steps.
+    bounds = sorted({lo, hi} | {t for s, e, _ in events + others
+                                for t in (s, e) if lo < t < hi})
+    names = _innermost(events, bounds)
+    behind = _innermost(others, bounds) if others else None
+    out, g = {}, 0
+    for i, (x, y) in enumerate(zip(bounds, bounds[1:])):
+        name = names[i]
+        if behind and name in WAITING and behind[i] != "(none)":
+            name = f"{name} + {behind[i]}"
         while g < len(gaps) and gaps[g][1] <= x:
             g += 1
         k = g
@@ -186,7 +218,7 @@ def overlap_ns(a, b) -> float:
     return total
 
 
-def breakdown(path: str) -> dict:
+def breakdown(path: str, background: bool = False) -> dict:
     from jax.profiler import ProfileData
 
     import trace_reduce
@@ -207,7 +239,8 @@ def breakdown(path: str) -> dict:
                     for e in ln.events
                     if e.start_ns + e.duration_ns > lo and e.start_ns < hi]
             busy_of.append(trace_reduce.union(busy))
-            for k, v in idle_by_span(busy_of[-1], lines, lo, hi).items():
+            for k, v in idle_by_span(busy_of[-1], lines, lo, hi,
+                                     background).items():
                 idle[k] = idle.get(k, 0.0) + v
     scopes = device_time_by_scope(path, lo, hi)
 
@@ -218,6 +251,12 @@ def breakdown(path: str) -> dict:
     total_idle = sum(idle.values())
     named = sum(v for k, v in idle.items()
                 if k != "(none)" and k not in COVERING)
+    threads = {}
+    for evs in lines:
+        if not any(n == "ydb.query" for _, _, n in evs):
+            for s, e, n in evs:
+                if e > lo and s < hi:
+                    threads[n] = threads.get(n, 0.0) + min(e, hi) - max(s, lo)
     per_statement = {}
     for evs in lines:
         for _, _, n in evs:
@@ -236,6 +275,10 @@ def breakdown(path: str) -> dict:
         "idle_s": total_idle / 1e9,
         "idle_named_leaf_share": named / total_idle if total_idle else None,
         "idle_by_span": top(idle, 20),
+        # what the other host threads (conveyor workers, a background
+        # compaction) spent inside the window, by event name: seconds of
+        # events, nested ones counted in their parents' too
+        "background_s_by_span": top(threads, 12),
         "device_s_by_scope": top(scopes.get("by_scope", {}), 20),
         "device_s_top_ops": top(scopes.get("by_op", {}), 10),
         "scope_stat": scopes.get("scope_stat", {}),
@@ -256,6 +299,10 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=40.0)
+    ap.add_argument("--background", action="store_true",
+                    help="put an idle gap under dispatch / device.wait / "
+                         "device.get down to the innermost ydb.* event of "
+                         "any other host thread too")
     args = ap.parse_args(argv)
 
     import run
@@ -266,7 +313,7 @@ def main(argv=None) -> int:
 
     def newest_and_read(trace_dir):
         path = newest(trace_dir)
-        found.update(breakdown(path))
+        found.update(breakdown(path, args.background))
         return path
 
     trace_reduce.newest_trace = newest_and_read

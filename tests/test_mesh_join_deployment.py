@@ -131,9 +131,14 @@ def test_the_cells_files_load_and_differ_from_the_scan_mesh_cells_in_scale(
     traffic Q3 alone in a closed loop, held to the mesh walk."""
     cell = run.load_cell("tpch-sf1-4chip.join-mesh")
     assert cell["chips"] == DEVICES
+    # its own four, then the nine of the write path that every cell
+    # lists since PR 39 (they read the set-up's writes)
     assert [m["name"] for m in cell["per_layer"]] == [
         "mesh_shuffle_ms", "mesh_join_ms", "mesh_join_scan_ms",
-        "mesh_join_roofline_share"]
+        "mesh_join_roofline_share", "write_rows_per_s", "write_sort_ms",
+        "write_blob_ms", "write_index_ms", "write_route_ms",
+        "promote_gb_per_s", "resident_lag_ms", "promote_declined",
+        "compile_built_s"]
     assert {m["name"] for m in cell["end_to_end"]} == {
         "rows_per_s", "query_geomean_ms", "setup_s"}
     assert cell["traffic"] == {

@@ -174,8 +174,9 @@ def write_portion_blob(
     chunk_rows: int = DEFAULT_CHUNK_ROWS,
     pk_column: str | None = None,
     stats: bool = True,
-) -> None:
-    """Serialize columns as a chunk-indexed blob.
+) -> tuple[int, int]:
+    """Serialize columns as a chunk-indexed blob; returns the bytes put
+    and the chunks they hold.
 
     Layout: MAGIC | u64 header_len | header JSON | chunk payloads.
     Chunks are consecutive row slices; when ``pk_column`` is given (and
@@ -222,6 +223,7 @@ def write_portion_blob(
     blob = b"".join([PORTION_MAGIC, struct.pack("<Q", len(header)),
                      header] + payloads)
     store.put(blob_id, blob)
+    return len(blob), len(chunks)
 
 
 class PortionChunkReader:

@@ -53,6 +53,7 @@ import contextlib
 import functools
 import os
 import threading
+import time
 import weakref
 
 import jax
@@ -64,11 +65,8 @@ from ydb_tpu import chaos
 from ydb_tpu.analysis import leaksan, memsan, sanitizer
 from ydb_tpu.blocks.block import Column, TableBlock
 from ydb_tpu.chaos import deadline as statement_deadline
-from ydb_tpu.obs import timeline
-from ydb_tpu.obs.probes import probe
-
-_P_PROMOTE = probe("resident.promote")
-_P_EVICT = probe("resident.evict")
+from ydb_tpu.obs import timeline, tracing
+from ydb_tpu.obs.counters import root_counters
 
 #: test override: True/False forces the gate, None = environment
 RESIDENT_FORCE: "bool | None" = None
@@ -137,6 +135,23 @@ def _padded(a: np.ndarray, rows: int) -> np.ndarray:
     out = np.zeros(rows, dtype=a.dtype)
     out[:len(a)] = a
     return out
+
+
+def _counters():
+    """The process's ``component=resident`` counters: every store's
+    promotions summed, and still there once the stores are freed."""
+    return root_counters().group(component="resident")
+
+
+@contextlib.contextmanager
+def _promote_stage(stage: str):
+    """One stage of a promotion as a leaf ``resident.promote.<stage>``;
+    its duration, once it has one, into ``promote_seconds{stage=}``."""
+    with tracing.leaf("resident.promote." + stage) as sp:
+        yield sp
+    if sp.recording:
+        _counters().group(stage=stage).counter(
+            "promote_seconds").inc(sp.seconds)
 
 
 class _Entry:
@@ -331,17 +346,49 @@ class ResidentStore:
 
     def promote(self, portion_id: int, rows: int, cols: dict,
                 valid: "dict | None") -> bool:
-        """Synchronous promote: decode-free device put of host arrays.
-        Device array construction runs OUTSIDE the lock; insertion,
-        accounting and budget eviction inside it."""
+        """Synchronous promote: decode-free device put of host arrays."""
+        return self._promote(portion_id, rows, lambda: (cols, valid),
+                             "memory")
+
+    def _promote(self, portion_id: int, rows: int, loader, source: str,
+                 committed_at: "float | None" = None) -> bool:
+        """``loader()``'s host arrays onto the device, under one
+        ``resident.promote`` span with a leaf a stage: ``load`` (the
+        loader: nothing for a fresh write, the blob read and decode on
+        the heat path), ``put`` (the pads and the device put of every
+        column, OUTSIDE the lock), ``admit`` (insertion, accounting and
+        the eviction to the budget inside it). ``committed_at`` is the
+        ``time.perf_counter`` reading at which the portion's commit was
+        logged: admitted, the promotion samples ``resident_lag_seconds``,
+        a write's time to be scannable from HBM."""
         if not self.enabled():
             return False
+        with tracing.span("resident.promote", store=self.name,
+                          portion=portion_id, rows=rows,
+                          source=source) as sp:
+            with _promote_stage("load"):
+                cols, valid = loader()
+            added = self._put_and_admit(sp, portion_id, rows, cols, valid)
+            if added:
+                g = _counters()
+                g.counter("promotions").inc()
+                g.counter("promote_bytes").inc(added)
+                if committed_at is not None:
+                    lag = time.perf_counter() - committed_at
+                    g.histogram("resident_lag_seconds").observe(lag)
+                    sp.set(lag_s=round(lag, 6))
+        return added > 0
+
+    def _put_and_admit(self, sp, portion_id: int, rows: int, cols: dict,
+                       valid: "dict | None") -> int:
+        """The bytes admitted (0: spilled, or a concurrent promotion
+        landed every column first), under the promotion's span ``sp``."""
         budget = self.budget()
         dev = self._slice_device
         entries = {}
         total = 0
         valid = valid or {}
-        with memsan.seam("resident"):
+        with _promote_stage("put") as put_sp, memsan.seam("resident"):
             for n, a in cols.items():
                 v = valid.get(n)
                 if v is None:
@@ -356,13 +403,16 @@ class ResidentStore:
                     e = _Entry(jnp.asarray(a), jnp.asarray(v))
                 entries[n] = e
                 total += e.nbytes
+            put_sp.set(bytes=total, device=self._slice_slot)
         if total > budget:
             # a single portion larger than the whole valve can never be
             # resident: spill — the host path keeps serving it
             with self._lock:
                 self.spills += 1
-            return False
-        with self._lock:
+            _counters().counter("spills").inc()
+            sp.set(spilled=1)
+            return 0
+        with _promote_stage("admit") as admit_sp, self._lock:
             info = self._info.get(portion_id)
             if info is None:
                 info = {"rows": rows, "nbytes": 0, "cols": set(),
@@ -384,12 +434,12 @@ class ResidentStore:
                     info.setdefault("tickets", []).append(
                         memsan.charge(added, "resident",
                                       owner=portion_id))
+            held = self._nbytes
             evicted = self._evict_to_budget_locked(budget,
                                                    keep=portion_id)
-        if _P_PROMOTE and added:
-            _P_PROMOTE.fire(store=self.name, portion=portion_id,
-                            nbytes=added, evicted=evicted)
-        return added > 0
+            admit_sp.set(evicted_bytes=held - self._nbytes)
+        sp.set(bytes=added, evicted=evicted)
+        return added
 
     def _evict_to_budget_locked(self, budget: int, keep=None) -> int:
         """Drop whole portions until the ledger fits the budget. Victim
@@ -409,9 +459,6 @@ class ResidentStore:
             self._drop_locked(victim)
             self.evictions += 1
             evicted += 1
-        if evicted and _P_EVICT:
-            _P_EVICT.fire(store=self.name, portions=evicted,
-                          nbytes=self._nbytes)
         return evicted
 
     def _drop_locked(self, portion_id: int) -> None:
@@ -425,35 +472,43 @@ class ResidentStore:
         for t in info.get("tickets", ()):
             memsan.release(t, evicted=True)
 
-    def promote_async(self, portion_id: int, rows: int, loader) -> bool:
+    def promote_async(self, portion_id: int, rows: int, loader,
+                      committed_at: "float | None" = None) -> bool:
         """Queue a promotion on the shared conveyor. ``loader()`` runs
         on a worker and returns (cols, valid) host dicts — either the
-        in-memory arrays of a fresh portion write (eager path) or a
-        blob-store read (heat path). Single-flight per portion id;
-        bounded in-flight so queued promotions never crowd out scan
-        prefetch admission."""
+        in-memory arrays of a fresh portion write (eager path, which
+        hands ``committed_at``: see ``_promote``) or a blob-store read
+        (heat path). Single-flight per portion id; bounded in-flight so
+        queued promotions never crowd out scan prefetch admission. A
+        promotion not queued is counted by its reason
+        (``promote_declined{reason=disabled|in_flight|inflight_full}``)
+        and named on the caller's active span: declined here, a portion
+        reaches HBM only once PROMOTE_HEAT scans have missed it."""
         if not self.enabled():
-            return False
+            return self._declined("disabled")
         with self._lock:
-            if portion_id in self._inflight or \
-                    len(self._inflight) >= MAX_INFLIGHT:
-                return False
+            if portion_id in self._inflight:
+                return self._declined("in_flight")
+            if len(self._inflight) >= MAX_INFLIGHT:
+                return self._declined("inflight_full")
             self._inflight.add(portion_id)
             fh = leaksan.track("resident.flight",
                                f"{self.name}:{portion_id}")
             # compact finished handles while here (drain bookkeeping)
             self._pending = [h for h in self._pending
                              if not h.done.is_set()]
+        source = "blob" if committed_at is None else "memory"
 
         def task():
             try:
-                cols, valid = loader()
-                self.promote(portion_id, rows, cols, valid)
+                self._promote(portion_id, rows, loader, source,
+                              committed_at)
             except Exception:
                 # best-effort: a GC'd blob or a shrunk budget mid-task
                 # is not a scan error — the host path still serves
                 with self._lock:
                     self.errors += 1
+                _counters().counter("errors").inc()
             finally:
                 with self._lock:
                     self._inflight.discard(portion_id)
@@ -478,12 +533,16 @@ class ResidentStore:
             self._pending.append(h)
         return True
 
+    @staticmethod
+    def _declined(reason: str) -> bool:
+        _counters().group(reason=reason).counter("promote_declined").inc()
+        tracing.annotate(promote_declined=reason)
+        return False
+
     def drain(self, timeout: float = 30.0) -> None:
         """Wait for every queued promotion (tests/bench determinism).
         Bounded: a wedged conveyor stops the wait at ``timeout``, it
         never wedges the caller."""
-        import time
-
         deadline = time.monotonic() + timeout
         while True:
             with self._lock:

@@ -37,6 +37,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import threading
+import time
 from collections import OrderedDict
 
 import numpy as np
@@ -55,6 +56,8 @@ from ydb_tpu.engine.portion import (
     write_portion_blob,
 )
 from ydb_tpu.engine.scan import ColumnSource, ScanExecutor
+from ydb_tpu.obs import tracing
+from ydb_tpu.obs.counters import root_counters
 from ydb_tpu.obs.probes import probe
 from ydb_tpu.ssa.program import Program
 
@@ -62,7 +65,6 @@ _P_COMMIT = probe("columnshard.commit")
 _P_SCAN = probe("columnshard.scan")
 _P_SCAN_STAGES = probe("columnshard.scan.stages")
 _P_SCAN_PRUNING = probe("columnshard.scan.pruning")
-_P_COMPACT = probe("columnshard.compact")
 
 
 @dataclasses.dataclass
@@ -140,6 +142,9 @@ class ColumnShard:
         # take their snapshots from the global plan-step clock so local
         # bumps never collide with coordinator-assigned steps
         self.snap_source = None  # Optional[Callable[[], int]]
+        # the owning table's Tracer (tx/sharded.py): a compaction outside
+        # any trace opens its ``compact`` span as a root there
+        self.tracer = None
 
         # schema evolution state (set by the owning table on ALTER):
         # current version + the version at which each column was added
@@ -316,6 +321,13 @@ class ColumnShard:
         if not batches:
             self._log({"op": "noop", "snap": snap})
             return snap
+        with tracing.span("write.portion", shard=self.shard_id) as sp:
+            with tracing.leaf("write.concat", batches=len(batches)):
+                cols, validity = self._concat_batches(batches)
+            self._write_portion(sp, cols, validity, snap)
+        return snap
+
+    def _concat_batches(self, batches) -> tuple[dict, dict]:
         cols = {
             f.name: np.concatenate([b["columns"][f.name] for b in batches])
             for f in self.schema.fields
@@ -334,75 +346,104 @@ class ColumnShard:
                 parts.append(v)
             if any_mask:
                 validity[f.name] = np.concatenate(parts)
-        self._add_portion(cols, validity, snap)
-        return snap
+        return cols, validity
 
     def _add_portion(self, cols, validity, snap, removed=None,
                      staged=False) -> PortionMeta:
+        with tracing.span("write.portion", shard=self.shard_id) as sp:
+            return self._write_portion(sp, cols, validity, snap, removed,
+                                       staged)
+
+    def _write_portion(self, sp, cols, validity, snap, removed=None,
+                       staged=False) -> PortionMeta:
+        """Rows to one immutable portion, under the caller's
+        ``write.portion`` span ``sp`` (a commit's, with its ``write.concat``
+        before this; a compaction's or a TTL rewrite's): one leaf a stage,
+        ``write.sort`` / ``write.blob`` / ``write.index`` / ``write.log`` /
+        ``write.promote.enqueue``, and the process's ``component=write``
+        counters ``portions``, ``blob_bytes``, ``rows_deduped``."""
         # portions are PK-sorted on disk (the reference sorts at
         # indexation) so scans can K-way merge them without re-sorting;
         # under upsert, equal keys within one commit collapse last-wins
+        deduped = 0
         if self.pk_column and self.pk_column in cols and \
                 len(cols[self.pk_column]):
-            if self.upsert:
-                # stable sort on the whole key, last of each equal key
-                keys = [np.asarray(cols[k]) for k in self.pk_columns]
-                order = np.lexsort(keys[::-1])
-                order = order[last_of_equal_keys(
-                    [k[order] for k in keys])]
-            else:
-                order = np.argsort(cols[self.pk_column], kind="stable")
-            cols = {n: a[order] for n, a in cols.items()}
-            validity = {n: a[order] for n, a in (validity or {}).items()}
+            with tracing.leaf("write.sort",
+                              key_columns=len(self.pk_columns)):
+                if self.upsert:
+                    # stable sort on the whole key, last of each equal key
+                    keys = [np.asarray(cols[k]) for k in self.pk_columns]
+                    order = np.lexsort(keys[::-1])
+                    order = order[last_of_equal_keys(
+                        [k[order] for k in keys])]
+                    deduped = len(keys[0]) - len(order)
+                else:
+                    order = np.argsort(cols[self.pk_column], kind="stable")
+                cols = {n: a[order] for n, a in cols.items()}
+                validity = {n: a[order]
+                            for n, a in (validity or {}).items()}
         with self._meta_lock:
             pid = self.next_portion_id
             self.next_portion_id += 1
         blob_id = f"{self.shard_id}/portion/{pid}"
-        write_portion_blob(self.store, blob_id, cols, validity,
-                           chunk_rows=self.config.portion_chunk_rows,
-                           pk_column=self.pk_column)
-        meta = PortionMeta(
-            portion_id=pid,
-            blob_id=blob_id,
-            num_rows=len(next(iter(cols.values()))) if cols else 0,
-            commit_snap=snap,
-            schema_version=self.schema_version,
-        )
-        # portion-level zone maps for ALL columns (vectorized one-pass
-        # min/max/null-count per column): planning prunes portions and
-        # plans dense group tiers without touching blob storage
-        from ydb_tpu.stats.zonemap import column_zones
+        with tracing.leaf("write.blob") as blob_sp:
+            blob_bytes, chunks = write_portion_blob(
+                self.store, blob_id, cols, validity,
+                chunk_rows=self.config.portion_chunk_rows,
+                pk_column=self.pk_column)
+            blob_sp.set(blob_bytes=blob_bytes, chunks=chunks)
+        with tracing.leaf("write.index"):
+            meta = PortionMeta(
+                portion_id=pid,
+                blob_id=blob_id,
+                num_rows=len(next(iter(cols.values()))) if cols else 0,
+                commit_snap=snap,
+                schema_version=self.schema_version,
+            )
+            # portion-level zone maps for ALL columns (vectorized one-pass
+            # min/max/null-count per column): planning prunes portions and
+            # plans dense group tiers without touching blob storage
+            from ydb_tpu.stats.zonemap import column_zones
 
-        if cols:
-            meta.zones = column_zones(cols, validity)
-        if self.pk_column and self.pk_column in cols:
-            meta.pk_min, meta.pk_max = column_stats(cols[self.pk_column])
-            rest = [cols[k] for k in self.pk_columns[1:]]
-            if self.upsert and rest and meta.num_rows and all(
-                    np.issubdtype(a.dtype, np.integer) for a in rest):
-                meta.key_min_rest = [int(a[0]) for a in rest]
-                meta.key_max_rest = [int(a[-1]) for a in rest]
-        if self.ttl_column and self.ttl_column in cols:
-            meta.ttl_min, meta.ttl_max = column_stats(cols[self.ttl_column])
-        with self._meta_lock:
-            self.portions[pid] = meta
-            rec = {"op": "add_portion", "meta": meta.to_json(),
-                   "snap": snap, "removed": removed or [],
-                   "dict_delta": self._dict_delta()}
-            if staged:
-                rec["staged"] = True
-            self._log(rec)
+            if cols:
+                meta.zones = column_zones(cols, validity)
+            if self.pk_column and self.pk_column in cols:
+                meta.pk_min, meta.pk_max = column_stats(
+                    cols[self.pk_column])
+                rest = [cols[k] for k in self.pk_columns[1:]]
+                if self.upsert and rest and meta.num_rows and all(
+                        np.issubdtype(a.dtype, np.integer) for a in rest):
+                    meta.key_min_rest = [int(a[0]) for a in rest]
+                    meta.key_max_rest = [int(a[-1]) for a in rest]
+            if self.ttl_column and self.ttl_column in cols:
+                meta.ttl_min, meta.ttl_max = column_stats(
+                    cols[self.ttl_column])
+        with tracing.leaf("write.log") as log_sp:
+            with self._meta_lock:
+                self.portions[pid] = meta
+                rec = {"op": "add_portion", "meta": meta.to_json(),
+                       "snap": snap, "removed": removed or [],
+                       "dict_delta": self._dict_delta()}
+                if staged:
+                    rec["staged"] = True
+                log_sp.set(log_bytes=self._log(rec))
+        sp.set(portion=pid, rows=meta.num_rows, rows_deduped=deduped)
+        g = root_counters().group(component="write")
+        g.counter("portions").inc()
+        g.counter("blob_bytes").inc(blob_bytes)
+        g.counter("rows_deduped").inc(deduped)
         # eager resident promotion (write path AND compaction output):
         # the decoded columns are already in memory — pin them on the
         # device asynchronously so the FIRST scan is already warm.
         # Budget pressure evicts cold portions; a full valve spills.
-        if self.resident.enabled() and meta.num_rows:
-            pcols, pvalid = cols, validity
-
-            def from_memory():
-                return pcols, pvalid
-
-            self.resident.promote_async(pid, meta.num_rows, from_memory)
+        # ``span``, not ``leaf``: the conveyor hands the submitter's
+        # active span to the worker, whose ``resident.promote`` hangs
+        # under this one. The store counts a promotion it declines.
+        if meta.num_rows:
+            with tracing.span("write.promote.enqueue") as enq_sp:
+                enq_sp.set(queued=int(self.resident.promote_async(
+                    pid, meta.num_rows, lambda: (cols, validity),
+                    committed_at=time.perf_counter())))
         return meta
 
     def _dict_delta(self) -> dict:
@@ -816,7 +857,7 @@ class ColumnShard:
             self._compact_locked()
 
     def _compact_locked(self) -> None:
-        from ydb_tpu.engine.reader import PortionStreamSource, plan_clusters
+        from ydb_tpu.engine.reader import plan_clusters
 
         metas = self.visible_portions()
         if len(metas) <= 1:
@@ -843,14 +884,28 @@ class ColumnShard:
         ]
         if not clusters:
             return  # every portion already compact and bounded
-        from ydb_tpu.engine.reader import rechunk
+        with tracing.entry(self.tracer, "compact") as sp:
+            rows_in, rows_out = self._compact_clusters(clusters)
+            if sp.annotated:
+                sp.set(shard=self.shard_id, portions_in=len(metas),
+                       rows_in=rows_in, rows_out=rows_out)
+        if sp.recording:
+            g = root_counters().group(component="compact")
+            g.counter("runs").inc()
+            g.counter("rows_in").inc(rows_in)
+            g.counter("rows_out").inc(rows_out)
+            g.counter("seconds").inc(sp.seconds)
+        if self._records_since_checkpoint >= self.config.checkpoint_interval:
+            self.checkpoint()
 
+    def _compact_clusters(self, clusters) -> tuple[int, int]:
+        """Rewrite each cluster; returns the rows read and written."""
+        from ydb_tpu.engine.reader import PortionStreamSource, rechunk
+
+        cap = self.config.max_portion_rows
+        rows_in = rows_out = 0
         self._in_compaction = True
         snap = self._advance_snap()
-        if _P_COMPACT:
-            _P_COMPACT.fire(shard=self.shard_id, snap=snap,
-                            clusters=len(clusters),
-                            portions=len(metas))
         # output portions are WAL-staged and only activate at the
         # cluster's compact_commit record, which also carries the removal
         # tombstones: a crash anywhere mid-stream replays to the exact
@@ -880,20 +935,21 @@ class ColumnShard:
                         valid = {n: a[order] for n, a in valid.items()}
                     payloads = iter([(cols, valid)])
                 added = [
-                    self._add_portion(chunk_c, chunk_v, snap,
-                                      staged=True).portion_id
+                    self._add_portion(chunk_c, chunk_v, snap, staged=True)
                     for chunk_c, chunk_v in rechunk(payloads, names, cap)
                 ]
+                rows_in += sum(m.num_rows for m in cluster)
+                rows_out += sum(m.num_rows for m in added)
                 removed = [m.portion_id for m in cluster]
                 with self._meta_lock:
                     for m in cluster:
                         m.removed_snap = snap
                     self._log({"op": "compact_commit", "snap": snap,
-                               "adds": added, "removed": removed})
+                               "adds": [m.portion_id for m in added],
+                               "removed": removed})
         finally:
             self._in_compaction = False
-        if self._records_since_checkpoint >= self.config.checkpoint_interval:
-            self.checkpoint()
+        return rows_in, rows_out
 
     def evict_ttl(self, cutoff: int) -> int:
         """Drop rows whose TTL column < cutoff. Returns rows evicted."""
@@ -983,14 +1039,14 @@ class ColumnShard:
 
     # ---------------- durability: WAL + checkpoint + boot ----------------
 
-    def _log(self, record: dict) -> None:
+    def _log(self, record: dict) -> int:
+        """Append ``record`` to the WAL; returns the bytes it took."""
         with self._meta_lock:
             self._wal_seq += 1
             record["seq"] = self._wal_seq
+            data = json.dumps(record).encode()
             self.store.put(
-                f"{self.shard_id}/wal/{self._wal_seq:012d}",
-                json.dumps(record).encode(),
-            )
+                f"{self.shard_id}/wal/{self._wal_seq:012d}", data)
             self._records_since_checkpoint += 1
             if self._records_since_checkpoint >= \
                     self.config.checkpoint_interval and \
@@ -998,6 +1054,7 @@ class ColumnShard:
                 # a checkpoint between a staged add and its compact_commit
                 # would persist half a compaction; defer until commit
                 self.checkpoint()
+            return len(data)
 
     def checkpoint(self) -> None:
         with self._meta_lock:

@@ -421,7 +421,7 @@ class Cluster:
                 pk_columns=tuple(desc.primary_key),
                 ttl_column=desc.ttl_column, dicts=self.dicts, boot=boot,
                 config=shard_config, upsert=desc.upsert,
-                gen=desc.shard_gen,
+                gen=desc.shard_gen, tracer=self.tracer,
             )
         t.alter_schema(desc.schema, desc.schema_version, desc.column_added)
         # dict ids must be durable BEFORE any shard WAL references them:

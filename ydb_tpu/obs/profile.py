@@ -41,6 +41,24 @@ SPAN_STAGE = {
     "device.wait": "device_wait", "device.get": "device_wait",
     "fetch": "fetch",
 }
+#: the write path's spans beneath ``write`` (``tx/sharded.py``
+#: ``ShardedTable.insert``) -> the stage whose seconds their self time
+#: counts into (``stage_seconds{stage=...}`` of the process's
+#: ``component=write`` counters): a portion's own self time and its
+#: promotion's enqueue count as the commit's
+WRITE_SPAN_STAGE = {
+    "write.encode": "encode", "write.route": "route",
+    "write.buffer": "buffer", "write.commit": "commit",
+    "write.portion": "commit", "write.promote.enqueue": "commit",
+    "write.concat": "concat", "write.sort": "sort",
+    "write.blob": "blob", "write.index": "index", "write.log": "log",
+}
+#: and one more statement key, named after the ``write`` span and only
+#: of a statement that has such a span (an INSERT / UPSERT): the self
+#: time of the ``write*`` spans on the statement's thread, so a
+#: statement that writes is not all ``unattributed``
+WRITE_KEY = "write"
+SPAN_STAGE.update(dict.fromkeys(("write", *WRITE_SPAN_STAGE), WRITE_KEY))
 STATEMENT_KEYS = ("plan", "pull", "dispatch", "device_wait", "fetch",
                   "unattributed")
 #: a seventh statement key, named after the ``mesh`` span and only of
@@ -466,7 +484,8 @@ def format_plan_analyzed(plan, profile: QueryProfile) -> str:
     lines.append("stages: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in STAGE_KEYS))
     keys = STATEMENT_KEYS + tuple(
-        k for k in (*MESH_SPAN_KEYS.values(), *WALK_SPAN_KEYS.values())
+        k for k in (*MESH_SPAN_KEYS.values(), *WALK_SPAN_KEYS.values(),
+                    WRITE_KEY)
         if k in st)
     lines.append("statement: " + " ".join(
         f"{k}={st.get(k, 0.0):.6f}" for k in keys))

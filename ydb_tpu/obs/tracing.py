@@ -142,6 +142,7 @@ class _NullSpan:
     instrumentation sites need no ``if`` around their annotations."""
 
     recording = False
+    annotated = False
     trace_id = 0
     span_id = 0
     parent_id = None
@@ -223,6 +224,28 @@ def leaf(name: str, **attrs):
             _tls.span = s if s.recording else None
 
 
+@contextlib.contextmanager
+def entry(tracer: "Tracer | None", name: str):
+    """The span of a piece of work that may start inside a trace or
+    outside any (``ShardedTable.insert``: under a session's statement,
+    or called on the table; a compaction on a background thread): a
+    child of the active span where there is one, else a root on
+    ``tracer``, and the shared no-op span where there is neither.
+    Activated only while profiling is on or a trace is already active,
+    so ``YDB_TPU_PROFILE=0`` leaves the one span and nothing beneath
+    it, unannotated."""
+    parent = current_span()
+    if parent is not None:
+        sp = parent.child(name)
+    elif tracer is not None:
+        sp = tracer.trace(name, annotated=profiling_enabled())
+    else:
+        yield NULL_SPAN
+        return
+    with sp, (activate(sp) if sp.annotated else contextlib.nullcontext()):
+        yield sp
+
+
 def annotate(**attrs) -> None:
     """Attach attributes to the active span, if any."""
     sp = current_span()
@@ -302,13 +325,15 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 _compile_lock = threading.Lock()
-_compile_counts = {"built": 0, "fetched": 0, "seconds": 0.0}
+_compile_counts = {"built": 0, "fetched": 0, "seconds": 0.0,
+                   "built_seconds": 0.0, "fetched_seconds": 0.0}
 _compile_subscribers: list = []
 
 
 def compile_counts() -> dict:
     """Programs this process built with XLA / fetched from the
-    persistent cache, and the seconds both took."""
+    persistent cache, and the seconds both took (``seconds``, and each
+    kind's own: ``built_seconds``, ``fetched_seconds``)."""
     with _compile_lock:
         return dict(_compile_counts)
 
@@ -334,6 +359,7 @@ def _on_compile(event, seconds, **_kw) -> None:
     with _compile_lock:
         _compile_counts[kind] += 1
         _compile_counts["seconds"] += seconds
+        _compile_counts[kind + "_seconds"] += seconds
         subscribers = list(_compile_subscribers)
     sp = current_span()
     if sp is not None:

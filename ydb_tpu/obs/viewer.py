@@ -37,6 +37,7 @@ from urllib.parse import parse_qs, urlparse
 import numpy as np
 
 from ydb_tpu.obs import sysview
+from ydb_tpu.obs.counters import root_counters
 
 
 def _source_rows(src) -> list[dict]:
@@ -123,8 +124,11 @@ class Viewer:
 
     def render(self, path: str, query: dict) -> tuple[bytes, str]:
         if path == "/counters/prometheus":
+            # the cluster's own group, then the process root (the write
+            # path's: component=write | resident | compact)
             with self.lock:
-                text = self.cluster.counters.encode_prometheus()
+                text = self.cluster.counters.encode_prometheus() \
+                    + root_counters().encode_prometheus()
             return text.encode(), "text/plain; version=0.0.4"
         if path in ("/viewer", "/monitoring"):
             from ydb_tpu.obs.viewer_html import PAGE

@@ -33,6 +33,8 @@ from __future__ import annotations
 import dataclasses
 import threading
 
+from ydb_tpu.obs import tracing
+
 
 @dataclasses.dataclass
 class TxResult:
@@ -163,7 +165,18 @@ class Coordinator:
         stake, so the decision collapses to one prepare+apply and the
         read barrier advances immediately — the common single-shard
         write skips the 2PC decision bookkeeping.
+
+        Under a trace the whole of it is one ``write.commit`` span (the
+        wait for ``_commit_lock`` included): each participant's portion
+        is a ``write.portion`` beneath it, one after another.
         """
+        with tracing.span("write.commit") as sp:
+            res = self._commit(participants, prepare_args)
+            sp.set(participants=len(participants), step=res.step,
+                   volatile=int(len(participants) == 1))
+            return res
+
+    def _commit(self, participants: list, prepare_args: list) -> TxResult:
         if len(participants) == 1:
             with self._commit_lock:
                 txid, step = self._plan_locked(register=True)

@@ -28,6 +28,9 @@ from ydb_tpu.engine.blobs import BlobStore
 from ydb_tpu.engine.oracle import OracleTable
 from ydb_tpu.engine.scan import ColumnSource, ScanExecutor
 from ydb_tpu.engine.shard import ColumnShard, ShardConfig
+from ydb_tpu.obs import tracing
+from ydb_tpu.obs.counters import root_counters
+from ydb_tpu.obs.profile import WRITE_SPAN_STAGE, self_seconds, subtree
 from ydb_tpu.ssa.program import Program
 from ydb_tpu.tx.coordinator import Coordinator, TxResult
 
@@ -39,6 +42,37 @@ def _fnv_route(keys: np.ndarray, n_shards: int) -> np.ndarray:
     h *= np.uint64(0xFF51AFD7ED558CCD)
     h ^= h >> 33
     return (h % np.uint64(n_shards)).astype(np.int64)
+
+
+def _count_write(sp, rows: int, bytes_in: int, res: TxResult) -> None:
+    """One finished ``write`` span into the process's ``component=write``
+    counters. The span is the one source of time: ``seconds`` is its
+    duration, ``stage_seconds`` the self times of what is beneath it on
+    its thread (``WRITE_SPAN_STAGE``; none with profiling off, when the
+    split stands still and the totals still count), ``visible_seconds``
+    the duration of a write that came back committed: from ``insert``
+    called to the rows readable at the result's step."""
+    g = root_counters().group(component="write")
+    g.counter("inserts").inc()
+    g.counter("rows").inc(rows)
+    g.counter("bytes_in").inc(bytes_in)
+    g.counter("seconds").inc(sp.seconds)
+    if res.committed:
+        g.histogram("visible_seconds").observe(sp.seconds)
+    else:
+        g.counter("failed").inc()
+    if not sp.annotated:
+        return
+    spans = [s for s in subtree(sp.tracer.spans_for(sp.trace_id),
+                                sp.span_id) if s.thread == sp.thread]
+    selfs = self_seconds(spans)
+    stages: dict = {}
+    for s in spans:
+        stage = WRITE_SPAN_STAGE.get(s.name)
+        if stage is not None:
+            stages[stage] = stages.get(stage, 0.0) + selfs[s.span_id]
+    for stage, seconds in stages.items():
+        g.group(stage=stage).counter("stage_seconds").inc(seconds)
 
 
 class ShardedTable:
@@ -57,11 +91,17 @@ class ShardedTable:
         upsert: bool = False,
         gen: int = 0,
         pk_columns: tuple[str, ...] | None = None,
+        tracer: tracing.Tracer | None = None,
     ):
         self.name = name
         self.schema = schema
         self.store = store
         self.coordinator = coordinator
+        # the cluster's one Tracer: a write called on the table outside
+        # any statement opens its ``write`` span as a root there (and a
+        # shard's compaction its ``compact``); None = such work leaves
+        # no span and counts no seconds
+        self.tracer = tracer
         self.pk_column = pk_column or schema.names[0]
         # rows route on the first key column, so all versions of one
         # key share a shard; upsert dedup compares the whole key
@@ -103,6 +143,7 @@ class ShardedTable:
             ]
         for s in self.shards:
             s.snap_source = coordinator.background_plan
+            s.tracer = tracer
         # called after string encode but before any shard write: the
         # cluster journals dictionary growth here so no durable shard
         # state ever references a dict id that is not itself durable
@@ -177,6 +218,7 @@ class ShardedTable:
         self.gen = new_gen
         for s in new_shards:
             s.snap_source = self.coordinator.background_plan
+            s.tracer = self.tracer
         return new_gen
 
     def drop_generation_storage(self, gen: int, n_shards: int) -> None:
@@ -221,29 +263,59 @@ class ShardedTable:
         columns: dict[str, np.ndarray | list],
         validity: dict[str, np.ndarray] | None = None,
     ) -> TxResult:
-        """Route rows by PK hash, write every shard, commit at one step."""
-        enc = self.shards[0].encode_strings(columns)
-        if self.pre_commit is not None:
-            self.pre_commit()
-        n = len(next(iter(enc.values())))
-        route = _fnv_route(
-            np.asarray(enc[self.pk_column], dtype=np.int64),
-            len(self.shards),
-        )
-        participants, prepare_args = [], []
-        for i, shard in enumerate(self.shards):
-            mask = route == i
-            if not mask.any():
-                continue
-            cols_i = {k: np.asarray(v)[mask] for k, v in enc.items()}
-            val_i = (
-                {k: np.asarray(v)[mask] for k, v in validity.items()}
-                if validity else None
-            )
-            wid = shard.write(cols_i, val_i)
-            participants.append(shard)
-            prepare_args.append([wid])
-        return self.coordinator.commit(participants, prepare_args)
+        """Route rows by PK hash, write every shard, commit at one step.
+
+        One ``write`` span a call (``tracing.entry``: under the
+        statement's where a session runs it, else a root on the
+        cluster's tracer) with a leaf a stage beneath it, one a batch a
+        shard and never one a column (``ydb_tpu/obs/README.md``, "The
+        span tree of a write"); finished, it is counted into the
+        process's ``component=write`` counters."""
+        with tracing.entry(self.tracer, "write") as sp:
+            # the journal of dictionary growth is the encode's durable
+            # half: no shard state may name an id that is not durable
+            with tracing.leaf("write.encode") as enc_sp:
+                held = self._dict_values() if enc_sp.recording else 0
+                enc = self.shards[0].encode_strings(columns)
+                if self.pre_commit is not None:
+                    self.pre_commit()
+                if enc_sp.recording:
+                    enc_sp.set(dict_growth=self._dict_values() - held)
+            rows = len(next(iter(enc.values())))
+            bytes_in = sum(a.nbytes for a in enc.values())
+            with tracing.leaf("write.route"):
+                route = _fnv_route(
+                    np.asarray(enc[self.pk_column], dtype=np.int64),
+                    len(self.shards),
+                )
+            participants, prepare_args = [], []
+            for i, shard in enumerate(self.shards):
+                with tracing.leaf("write.route", shard=shard.shard_id):
+                    mask = route == i
+                    if not mask.any():
+                        continue
+                    cols_i = {k: np.asarray(v)[mask]
+                              for k, v in enc.items()}
+                    val_i = (
+                        {k: np.asarray(v)[mask]
+                         for k, v in validity.items()}
+                        if validity else None
+                    )
+                with tracing.leaf("write.buffer", shard=shard.shard_id):
+                    wid = shard.write(cols_i, val_i)
+                participants.append(shard)
+                prepare_args.append([wid])
+            res = self.coordinator.commit(participants, prepare_args)
+            if sp.annotated:
+                sp.set(table=self.name, rows=rows, bytes_in=bytes_in,
+                       shards=len(self.shards),
+                       shards_hit=len(participants))
+        if sp.recording:
+            _count_write(sp, rows, bytes_in, res)
+        return res
+
+    def _dict_values(self) -> int:
+        return sum(len(self.dicts[c]) for c in self.dicts.columns())
 
     # ---------------- reads ----------------
 
