@@ -20,7 +20,12 @@ import pytest
 from ydb_tpu.config import AppConfig
 from ydb_tpu.engine import resident as resident_mod
 from ydb_tpu.kqp.session import Cluster
+from ydb_tpu.obs import profile as profile_mod
 from ydb_tpu.obs.profile import STATEMENT_KEYS, WALK_SPAN_KEYS
+from ydb_tpu.plan import execute_plan
+from ydb_tpu.plan import executor as plan_executor
+from ydb_tpu.sql.parser import parse
+from ydb_tpu.sql.planner import plan_select_full
 from ydb_tpu.ssa import compiler, plan_fuse
 
 BENCH = pathlib.Path(__file__).resolve().parents[1] / "bench"
@@ -132,6 +137,8 @@ def test_the_walk_answers_exactly_and_says_where_the_time_went(
     assert attrs["group_layout"] == "sorted"
     assert attrs["key_words"] == KEY_WORDS[sid]
     assert attrs["reduce_tier"] == "scatter"
+    # the output's keys are the sorted keys at the segment heads (PR 40)
+    assert attrs["key_tier"] == "segment"
     # the top-10 selects its rows: nothing orders the capacity (PR 38)
     assert attrs["sort_tier"] == "select" and attrs["sort_limit"] == 10
     assert attrs["compile_cache"] == "hit"
@@ -203,6 +210,27 @@ def test_a_statement_whose_aggregate_pushes_down_has_neither_key(
     assert "pushdown_declined" not in by_name(p, "scan")[0]["attrs"]
     assert sum(p.stages[k] for k in STATEMENT_KEYS) == pytest.approx(
         p.seconds, abs=max(0.01 * p.seconds, 2e-4))
+
+
+@pytest.mark.parametrize("sql,key_tier", (
+    ("select count(*) as n from hits where AdvEngineID <> 0", None),
+    ("select MobilePhoneModel, count(*) as c from hits "
+     "group by MobilePhoneModel", "dense")), ids=("keyless", "dense"))
+def test_the_controls_transforms_say_their_own_key_tier(
+        deployment, sql, key_tier):
+    """The two controls push down and have no ``transform`` span; run
+    the way the walk answered them before the pushdown (the scan's
+    output, then the Transform over it), the span says where a dense
+    layout's keys come from, and a keyless aggregate has none."""
+    _, cluster = deployment
+    plan = plan_select_full(parse(sql), cluster.catalog(), None).plan
+    db = cluster.snapshot_db()
+    with profile_mod.profiled() as held:
+        plan_executor._transform_node(
+            plan, execute_plan(plan.input, db), db)
+    (transform,) = by_name(held.profile, "transform")
+    assert transform["attrs"].get("key_tier") == key_tier
+    assert transform["attrs"]["group_layout"] == (key_tier or "keyless")
 
 
 @contextlib.contextmanager

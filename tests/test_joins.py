@@ -340,14 +340,17 @@ def test_stable_lexsort_keeps_the_comparator_sort_for_a_float_key():
 
 @pytest.mark.parametrize("program", ("compact", "sorted_build",
                                      "repartition", "group_ids_sorted",
-                                     "sort_perm"))
+                                     "sort_perm", "group_keys"))
 def test_no_program_sorts_a_64_bit_or_a_two_key_operand(program):
     """What XLA's TPU sort costs to compile follows its operands: an
     (int64, bool) pair with its row numbers took 208-239 s for a
     described v5e at 917,504 rows, a ``uint32`` alone 26 s (PERF.md
     section 6, PR 35). The join's and the exchange's programs hold
     only sorts of one 32-bit key, alone or with its row numbers, and
-    ``compact`` holds no sort, gather or scatter at all (PR 36)."""
+    ``compact`` holds no sort, gather or scatter at all (PR 36); nor
+    does a whole group-by whose output keys are its sorted keys
+    compacted at the segment heads add a sort to ``group_ids_sorted``'s
+    (PR 40)."""
     import re
 
     import jax
@@ -366,15 +369,29 @@ def test_no_program_sorts_a_64_bit_or_a_two_key_operand(program):
     elif program == "sorted_build":
         text = jax.jit(jk._sorted_build).lower(
             jnp.zeros(rows, jnp.int64), jnp.zeros(rows, bool)).as_text()
-    elif program in ("group_ids_sorted", "sort_perm"):
+    elif program in ("group_ids_sorted", "sort_perm", "group_keys"):
         # Q3's group-by and its ORDER BY: int64 and int32 keys, their
         # validities, the live flag
         cols = [blk.columns["k"], blk.columns["v"]]
-        fn = ((lambda: kernels.group_ids_sorted(cols, blk.row_mask(), rows))
-              if program == "group_ids_sorted" else
-              (lambda: kernels.sort_perm(cols, [True, False],
-                                         blk.row_mask())))
+        fn = ((lambda: kernels.sort_perm(cols, [True, False],
+                                         blk.row_mask()))
+              if program == "sort_perm" else
+              (lambda: kernels.group_ids_sorted(cols, blk.row_mask(), rows)))
         text = jax.jit(fn).lower().as_text()
+        if program == "group_keys":
+            # the lowered group-by above the one-hot tier, keys and all:
+            # as many sorts as its group ids alone
+            from ydb_tpu.ssa import AggSpec, GroupByStep, Program, \
+                compile_program
+            from ydb_tpu.ssa.ops import Agg
+
+            ids_alone = text.count('"stablehlo.sort"')
+            cp = compile_program(Program((GroupByStep(
+                keys=("k", "v"),
+                aggs=(AggSpec(Agg.COUNT_ALL, None, "n"),)),)), blk.schema)
+            text = jax.jit(cp.run).lower(blk, {}).as_text()
+            assert cp.notes["key_tier"] == "segment"
+            assert text.count('"stablehlo.sort"') == ids_alone
     else:
         mesh = make_mesh(1, devices=jax.devices()[:1])
         text = jax.jit(shard_map(

@@ -350,3 +350,48 @@ def test_a_top_10_moves_ten_rows_of_a_hits_sized_block(
             if re.search(r"\b(sort|gather|scatter)\(", ln)
             and str(rows) in ln.split(" gather(")[0].split(" scatter(")[0]]
     assert not long, long[:3]
+
+
+def test_a_hits_sized_group_by_takes_its_keys_from_the_segment_heads(
+        one_chip, no_persistent_cache, capsys):
+    """ClickBench Q16's ``group by UserID, SearchPhrase`` with a count
+    over 12.58M slots: the output's keys are the sorted keys compacted
+    at the segment heads (PR 40). No ``ydb.scatter_first`` is left of
+    the 12.58M-row scatters a key column; what scatters is
+    ``group_ids_sorted``'s inverse permutation and the reduce's add, and
+    the program's temporaries fit beside the resident table."""
+    import time
+
+    from ydb_tpu import dtypes
+    from ydb_tpu.blocks.block import Column, TableBlock
+    from ydb_tpu.ssa import AggSpec, GroupByStep, Program, compile_program
+    from ydb_tpu.ssa.ops import Agg
+
+    rows = HITS_CAPACITY
+    schema = dtypes.schema(("UserID", dtypes.INT64),
+                           ("SearchPhrase", dtypes.INT32))
+    block = TableBlock(
+        {f.name: Column(_shape((rows,), f.type.physical, one_chip),
+                        _shape((rows,), "bool", one_chip))
+         for f in schema.fields},
+        _shape((), "int32", one_chip), schema)
+    cp = compile_program(Program((GroupByStep(
+        keys=schema.names, aggs=(AggSpec(Agg.COUNT_ALL, None, "c"),)),)),
+        schema)
+    t0 = time.perf_counter()
+    compiled = jax.jit(cp.run).lower(block, {}).compile()
+    with capsys.disabled():
+        print(f"\na group-by of two keys over {rows} slots compiled for a "
+              f"described v5e in {time.perf_counter() - t0:.1f} s")
+    assert cp.notes["key_tier"] == "segment"
+    assert cp.notes["groups"] == rows and cp.notes["key_words"] == 3
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES / 8, temp
+    text = compiled.as_text()
+    assert "ydb.scatter_first" not in text and "ydb.compact" in text
+    scatters = [ln for ln in text.splitlines()
+                if re.search(r"\bscatter\(", ln)]
+    scopes = sorted({m for ln in scatters
+                     for m in re.findall(r"ydb\.\w+", ln)})
+    assert scopes == ["ydb.fused_group_reduce", "ydb.group_ids_sorted"], (
+        [ln.strip()[:160] for ln in scatters])

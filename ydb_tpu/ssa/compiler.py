@@ -69,7 +69,11 @@ class CompiledProgram:
     #: its sort, known only once the block's capacity is: ``groups``
     #: (the slots the states are sized to), ``key_words`` (32-bit words
     #: of the key a sort-derived layout sorts by), ``reduce_tier``
-    #: (kernels.reduce_tier of each accumulator bank), ``sort_tier``
+    #: (kernels.reduce_tier of each accumulator bank), ``key_tier``
+    #: (where the output's key columns come from: ``dense`` = decoded
+    #: from the slot number, ``onehot`` = gathered at each group's first
+    #: row, ``segment`` = a sort-derived layout's sorted keys compacted
+    #: at their segment heads; a keyless aggregate has none), ``sort_tier``
     #: (kernels.sort_tier of its SortStep) and ``sort_limit`` (that
     #: step's LIMIT, where it has one); the executor's ``transform``
     #: span carries them
@@ -1094,13 +1098,15 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
         and jnp.issubdtype(jnp.dtype(t.physical), jnp.integer)
     }
 
-    def trace_fused(env, aux, live, gid, ng, kcols, capacity):
+    def trace_fused(env, aux, live, gid, ng, kcols, capacity,
+                    sorted_keys):
         """ONE shared hit expansion per GroupByStep.
 
         All linear aggregates (COUNT/SUM/AVG/VAR/STDDEV states) stack
         into per-accumulator-dtype banks, each reduced by one
         kernels.fused_group_reduce; MIN/MAX and the key columns reuse
-        the same bool hit matrix.
+        the same bool hit matrix. ``sorted_keys`` is what a sort-derived
+        layout's ``group_ids_sorted`` left of the keys (else None).
         """
         onehot = ng <= kernels.ONEHOT_GROUP_LIMIT
         # counts share the f64 bank of the AVG/VAR sums in the one-hot
@@ -1190,6 +1196,7 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
         hits = kernels.group_hits(gid, ng) if onehot else None
         new_env: dict[str, Column] = {}
         if key_names and use_dense:
+            ctx.notes["key_tier"] = "dense"
             # dense slot ids ARE the keys: decode each key value from
             # the slot index arithmetically (enc = value + 1, 0 = NULL,
             # group_ids_dense's mixed-radix encoding) — zero row passes
@@ -1207,6 +1214,7 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
                 kv = (enc > 0) & group_live
                 new_env[k] = Column(kd, kv)
         elif key_names and onehot:
+            ctx.notes["key_tier"] = "onehot"
             # one first-row expansion shared by EVERY key column
             first, found = kernels.first_live_index(hits)
             for k, c in zip(key_names, kcols):
@@ -1214,11 +1222,23 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
                                jnp.zeros_like(c.data[first]))
                 kv = c.validity[first] & found
                 new_env[k] = Column(kd, kv & group_live)
-        else:
-            for k, c in zip(key_names, kcols):
-                kd = kernels.scatter_first(c.data, live, gid, ng)
-                kv = kernels.scatter_first(c.validity, live, gid, ng)
-                new_env[k] = Column(kd, kv & group_live)
+        elif key_names:
+            # a sort-derived layout above the one-hot tier: group g's
+            # key IS the sorted key columns at the g-th segment head, so
+            # one compact by the boundary flags puts it in slot g (ids
+            # are given in sorted key order); groups past an explicit
+            # cap fall off the slice as they fall off the reduce
+            ctx.notes["key_tier"] = "segment"
+            heads = kernels.compact(
+                TableBlock(dict(zip(key_names, sorted_keys.columns)),
+                           jnp.int32(capacity),
+                           dtypes.schema(*((k, out_types[k])
+                                           for k in key_names))),
+                sorted_keys.boundary)
+            for k in key_names:
+                c = heads.columns[k]
+                new_env[k] = Column(c.data[:ng],
+                                    c.validity[:ng] & group_live)
 
         for spec, t in specs:
             if spec.func is Agg.COUNT_ALL:
@@ -1301,7 +1321,7 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
     def lower(env, aux, live):
         kcols = [env[k] for k in key_names]
         capacity = next(iter(env.values())).data.shape[0]
-        ng_scalar = None
+        ng_scalar = sorted_keys = None
         if key_names:
             if use_dense:
                 gid, ng = kernels.group_ids_dense(kcols, list(b_tuple), live)
@@ -1318,7 +1338,8 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
                 if group_bound is not None:
                     caps.append(group_bound)
                 ng = max(1, min(caps))
-                gid, ng_scalar = kernels.group_ids_sorted(kcols, live, ng)
+                gid, ng_scalar, sorted_keys = kernels.group_ids_sorted(
+                    kcols, live, ng)
                 ng_scalar = jnp.minimum(ng_scalar, jnp.int32(ng))
         else:
             # global aggregate: one group
@@ -1326,7 +1347,7 @@ def _resolve_group_by(ctx: _Lowering, step: GroupByStep, cur_types,
             ng = 1
 
         new_env, group_live = trace_fused(
-            env, aux, live, gid, ng, kcols, capacity)
+            env, aux, live, gid, ng, kcols, capacity, sorted_keys)
 
         if key_names and keep_slots:
             # mesh-mergeable layout: every slot stays in place; dead slots

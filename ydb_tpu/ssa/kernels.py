@@ -26,6 +26,7 @@ traced int32 scalar, never a shape.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -306,17 +307,33 @@ def group_ids_dense(
     return gid, num_groups
 
 
+class SortedKeys(NamedTuple):
+    """What ``group_ids_sorted``'s one sort leaves of the keys: each key
+    column in sorted order (the data zero under a NULL) and
+    ``boundary``, the first row of each live group. Group ``g``'s key is
+    the columns at the ``g``-th boundary: a ``compact`` by ``boundary``
+    puts it in slot ``g``."""
+
+    columns: tuple[Column, ...]
+    boundary: jax.Array     # bool[capacity]
+
+
 @jax.named_scope("ydb.group_ids_sorted")
 def group_ids_sorted(
     keys: list[Column], live: jax.Array, max_groups: int
-) -> tuple[jax.Array, jax.Array]:
+) -> tuple[jax.Array, jax.Array, SortedKeys]:
     """Generic exact group ids via lexicographic sort (no device hash table).
 
     Returns (gid[capacity] int32 with dead rows = max_groups, n_groups
-    scalar). Group ids are assigned in sorted key order, so downstream
-    per-group outputs come out key-ordered.
+    scalar, the sorted keys and their segment heads). Group ids are
+    assigned in sorted key order, so downstream per-group outputs come
+    out key-ordered.
     """
-    # sort dead rows last; NULLs first within a key (stable choice)
+    # what lies under a NULL is made alike before the sort, so that all
+    # NULLs of a key form ONE run whatever keys follow it
+    keys = [Column(jnp.where(k.validity, k.data, jnp.zeros_like(k.data)),
+                   k.validity) for k in keys]
+    # sort dead rows last; a key's NULLs after its values
     sort_keys = []
     for k in reversed(keys):
         sort_keys.append(k.data)
@@ -329,15 +346,11 @@ def group_ids_sorted(
     )
 
     live_s = live[perm]
-
-    def sorted_col(k: Column):
-        return k.data[perm], k.validity[perm]
-
     changed = jnp.zeros(live.shape, dtype=bool)
+    sorted_keys = []
     for k in keys:
-        d, v = sorted_col(k)
-        # normalize garbage under NULL slots so all NULLs form one group
-        d = jnp.where(v, d, jnp.zeros_like(d))
+        d, v = k.data[perm], k.validity[perm]
+        sorted_keys.append(Column(d, v))
         prev_d = jnp.roll(d, 1)
         prev_v = jnp.roll(v, 1)
         diff = (d != prev_d) | (v != prev_v)
@@ -349,7 +362,8 @@ def group_ids_sorted(
     n_groups = jnp.maximum(jnp.max(jnp.where(live_s, seg_sorted, -1)) + 1, 0)
     seg_sorted = jnp.where(live_s, seg_sorted, max_groups)
     gid = seg_sorted[inv]
-    return gid, n_groups.astype(jnp.int32)
+    return (gid, n_groups.astype(jnp.int32),
+            SortedKeys(tuple(sorted_keys), boundary))
 
 
 #: Below this many groups the one-hot masked reduction beats any scatter:
