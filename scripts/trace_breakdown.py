@@ -46,6 +46,19 @@ WAITING = {"ydb.dispatch", "ydb.device.wait", "ydb.device.get"}
 #: join's capacities and attempts; a host concatenation's blocks, rows
 #: and bytes; a Transform's capacity, group layout, key words and tier)
 REPORTED_SPANS = ("mesh.shuffle", "mesh.join", "host.concat", "transform")
+
+
+def reported_span(sp: dict) -> bool:
+    """One of ``REPORTED_SPANS``, or a DQ stage's dispatch that says
+    something: a join stage's rows and kind, a group-by's or a sort's
+    tiers."""
+    return sp["name"] in REPORTED_SPANS or (
+        sp["name"] == "dispatch" and sp["attrs"].get("program") == "dq_stage"
+        and len(set(sp["attrs"]) - {"program", "compile_built",
+                                     "compile_fetched",
+                                     "compile_seconds"}) > 0)
+
+
 #: a device operation that crosses devices, by its HLO name
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter")
@@ -345,7 +358,7 @@ def main(argv=None) -> int:
             {"sql": p.sql, "seconds": p.seconds, "stages": dict(p.stages),
              "spans": [dict(sp["attrs"], name=sp["name"],
                             seconds=sp["seconds"])
-                       for sp in p.spans if sp["name"] in REPORTED_SPANS]}
+                       for sp in p.spans if reported_span(sp)]}
             for p in by_sql.values()]
         reported = [r for r in reported if r["spans"]]
         if reported:

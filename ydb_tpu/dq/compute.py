@@ -40,8 +40,9 @@ from ydb_tpu.dq.spilling import Spiller
 from ydb_tpu.engine.oracle import OracleTable
 from ydb_tpu.engine.scan import ColumnSource, merge_blocks_device
 from ydb_tpu.obs import tracing
+from ydb_tpu.obs.counters import root_counters
 from ydb_tpu.runtime.actors import Actor, ActorId
-from ydb_tpu.ssa.compiler import compile_program
+from ydb_tpu.ssa.compiler import LAYOUT_NAMES, compile_program
 
 DEFAULT_WINDOW = 4  # unacked blocks per channel before spilling
 
@@ -145,6 +146,20 @@ def _hash_rows(payload: dict, schema, keys) -> np.ndarray:
     )
 
 
+def _payload_rows(payload: dict) -> int:
+    return len(next(iter(payload.values()))) if payload else 0
+
+
+def _span_notes(cp) -> dict:
+    """What a stage program's ``dispatch`` span says of its group-by and
+    its sort, as the walk's ``transform`` span does: the layout, and once
+    the first trace has filled them the tiers (``compiler.CompiledProgram
+    .notes``); nothing for a program with neither."""
+    if cp.group_layout[0] is not None:
+        cp.notes["group_layout"] = LAYOUT_NAMES[cp.group_layout[0]]
+    return cp.notes
+
+
 def _split_by_hash(payload: dict, h: np.ndarray, n: int) -> list[dict]:
     if n == 1:
         return [payload]
@@ -173,6 +188,7 @@ class _CompiledStage:
             self.out_schema = _join_out_schema(
                 spec.join, in_schemas[0], in_schemas[1])
             self.mid_schema = self.out_schema
+            self.block_notes = self.final_notes = {}
             return
         self.join = None
         if spec.program is not None:
@@ -183,9 +199,11 @@ class _CompiledStage:
             mid = self.per_block.out_schema
             self._pb_jit = jax.jit(self.per_block.run)
             self._pb_aux = device_aux(self.per_block.aux)
+            self.block_notes = _span_notes(self.per_block)
         else:
             self.per_block = None
             mid = in_schema
+            self.block_notes = {}
         self.mid_schema = mid
         if spec.final_program is not None:
             from ydb_tpu.ssa import twophase
@@ -198,6 +216,7 @@ class _CompiledStage:
                 dict_aliases=aliases,
             )
             self._f_aux = device_aux(self.final.aux)
+            self.final_notes = _span_notes(self.final)
             self.out_schema = self.final.out_schema
             final_run = self.final.run
 
@@ -212,6 +231,7 @@ class _CompiledStage:
             self._finalize_jit = _finalize
         else:
             self.final = None
+            self.final_notes = {}
             self.out_schema = mid
             self._f_aux = {}
             self._finalize_jit = jax.jit(
@@ -526,14 +546,17 @@ class ComputeActor(Actor):
         self._ingest(blk)
         self.send(self.self_id, _PumpSource())
 
-    def _timed(self, fn, *args):
+    def _timed(self, fn, *args, notes=None):
         """Charge a stage-program dispatch to the task's profile span
-        (pass-through when no trace is active)."""
+        (pass-through when no trace is active); the span says what the
+        program's ``notes`` say of its group-by and its sort."""
         if self._span is None:
             return fn(*args)
         t0 = time.perf_counter()
-        with tracing.span("dispatch", program="dq_stage"):
+        with tracing.span("dispatch", program="dq_stage") as sp:
             out = fn(*args)
+            if notes:
+                sp.set(**notes)
         self._compute_s += time.perf_counter() - t0
         return out
 
@@ -544,20 +567,17 @@ class ComputeActor(Actor):
             # spiller (blocks beyond the quota go to blobs)
             self._acc_ids.append(self.spiller.put(
                 block_to_payload(
-                    self._timed(self.compiled.run_block, block))))
+                    self._timed(self.compiled.run_block, block,
+                                notes=self.compiled.block_notes))))
         else:
-            out = self._timed(self.compiled.run_block, block)
+            out = self._timed(self.compiled.run_block, block,
+                              notes=self.compiled.block_notes)
             self._emit(out)
 
     def _finish_input(self):
         spec = self.task.stage_spec
         if self.compiled.join is not None:
-            probe = _assemble(self._join_acc[0],
-                              self.compiled.in_schemas[0])
-            build = _assemble(self._join_acc[1],
-                              self.compiled.in_schemas[1])
-            self._join_acc = {0: [], 1: []}
-            self._emit(self._timed(self.compiled.run_join, probe, build))
+            self._join_bucket()
             self._finish_output()
             return
         if spec.final_program is not None:
@@ -567,25 +587,58 @@ class ComputeActor(Actor):
                                      self.compiled.mid_schema)
                     for sid in self._acc_ids
                 ]
-                self._emit(self._timed(self.compiled.run_final, blocks))
+                self._emit(self._timed(self.compiled.run_final, blocks,
+                                       notes=self.compiled.final_notes))
             else:
                 # empty input still finalizes (COUNT over nothing etc.)
                 empty = _empty_block(self.compiled.mid_schema)
-                self._emit(self._timed(self.compiled.run_final, [empty]))
+                self._emit(self._timed(self.compiled.run_final, [empty],
+                                       notes=self.compiled.final_notes))
             self._acc_ids = []
         self._finish_output()
 
+    def _join_bucket(self):
+        """The join stage's one dispatch: the bucket's two sides
+        concatenated and staged on the device, joined there, the output
+        copied out and routed, all under the stage's ``dispatch`` span,
+        which says the join (``join=lookup|expand``, ``kind``) and the
+        rows of its sides and of its output. The process counts the
+        join's rows (``component=join``) whether or not a trace is
+        active."""
+        j = self.compiled.join
+        probe_rows, build_rows = (sum(map(_payload_rows, self._join_acc[i]))
+                                  for i in (0, 1))
+        with tracing.span("dispatch", program="dq_stage") as sp:
+            probe = _assemble(self._join_acc[0], self.compiled.in_schemas[0])
+            build = _assemble(self._join_acc[1], self.compiled.in_schemas[1])
+            # the bucket's host payloads go once staged: only the device
+            # blocks live through the join and the output's copy out
+            self._join_acc = {0: [], 1: []}
+            t0 = time.perf_counter()
+            out = self.compiled.run_join(probe, build)
+            self._compute_s += time.perf_counter() - t0
+            sp.set(join="expand" if j.expand else "lookup", kind=j.kind,
+                   probe_rows=probe_rows, build_rows=build_rows,
+                   out_rows=self._emit(out))
+        g = root_counters().group(component="join")
+        g.counter("joins").inc()
+        g.counter("probe_rows").inc(probe_rows)
+        g.counter("build_rows").inc(build_rows)
+
     # ---- output side ----
 
-    def _emit(self, block: TableBlock):
+    def _emit(self, block: TableBlock) -> int:
+        """Copy a stage's output out of the device and send it on;
+        returns its rows."""
         if int(block.capacity) == 0:
-            return
+            return 0
         payload = block_to_payload(block)
         out = self.task.stage_spec.output
         if isinstance(out, ResultOutput):
             self.send(self.result_target, ResultData(payload, False))
-            return
-        self._route(payload, out)
+        else:
+            self._route(payload, out)
+        return _payload_rows(payload)
 
     @tracing.span("dq.exchange")
     def _route(self, payload: dict, out):

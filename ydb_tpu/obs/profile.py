@@ -94,6 +94,15 @@ MESH_SPAN_KEYS = {"mesh": MESH_KEY, "mesh.shuffle": "mesh_shuffle",
 #: ``unattributed``; a mesh statement's ``host.concat`` spans stay
 #: where they were (``mesh_join_scan_ms`` reads them there).
 WALK_SPAN_KEYS = {"host.concat": "concat", "transform": "transform"}
+#: and one key that is a view of ``seconds``, not a part of it, as the
+#: four ``STAGE_KEYS`` are: the self time on the statement's thread of
+#: each DQ join stage's span (``dispatch program=dq_stage`` with a
+#: ``join`` attr: dq/compute.py ``_join_bucket``) and of every span
+#: beneath it (the bucket's sides concatenated and staged, the join's
+#: programs enqueued and waited for, the output copied out), which
+#: ``dispatch`` and ``device_wait`` already count; only of a statement
+#: with such a span
+DQ_JOIN_KEY = "dq_join"
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
@@ -245,6 +254,27 @@ def statement_stages(spans, seconds: float) -> dict:
     return out
 
 
+def join_stage_seconds(spans) -> float | None:
+    """``DQ_JOIN_KEY``'s seconds, or None without a DQ join stage."""
+    stages = {s.span_id for s in spans
+              if s.name == "dispatch" and "join" in s.attrs}
+    if not stages:
+        return None
+    by_id = {s.span_id: s for s in spans}
+    thread = next(s for s in spans if s.parent_id not in by_id).thread
+    selfs = self_seconds(spans)
+    total = 0.0
+    for s in spans:
+        if s.thread != thread or not s.annotated:
+            continue
+        up = s
+        while up is not None and up.span_id not in stages:
+            up = by_id.get(up.parent_id)
+        if up is not None:
+            total += selfs[s.span_id]
+    return total
+
+
 def _walk_key(span, by_id: dict) -> str | None:
     """``concat`` or ``transform`` for a ``host.concat`` or
     ``transform`` span and what is beneath one, by the nearest above."""
@@ -364,6 +394,9 @@ def build_profile(spans, sql: str = "", kind: str = "",
             for k in PRUNING_KEYS:
                 p.pruning[k] += int(a.get(k, 0))
     p.stages.update(statement_stages(spans, p.seconds))
+    joins = join_stage_seconds(spans)
+    if joins is not None:
+        p.stages[DQ_JOIN_KEY] = joins
     p.stages = {k: round(v, 6) for k, v in p.stages.items()}
     p.rows = rows if rows is not None else rows_out
     p.execute_seconds = max(0.0, p.seconds - p.compile_seconds)

@@ -40,7 +40,7 @@ from ydb_tpu.obs import tracing
 from ydb_tpu.obs.probes import probe as _probe
 from ydb_tpu.ssa import join as join_kernels
 from ydb_tpu.ssa import kernels, twophase
-from ydb_tpu.ssa.compiler import compile_program
+from ydb_tpu.ssa.compiler import LAYOUT_NAMES, compile_program
 from ydb_tpu.ssa.program import (
     AssignStep,
     FilterStep,
@@ -460,7 +460,7 @@ def _scan_aggregated(plan: Transform, db: Database, shared: set,
     ex, fresh = _scan_executor(pushed, db, plan.dict_aliases)
     if not ex.folds_partials:
         if declined is not None:
-            declined[id(plan.input)] = "layout=" + _LAYOUT_NAMES.get(
+            declined[id(plan.input)] = "layout=" + LAYOUT_NAMES.get(
                 ex.partial.group_layout[0], "none")
         return None
     with tracing.span("scan") as sp:
@@ -745,14 +745,9 @@ def _compiled_transform(plan: Transform, schema, db: Database):
     # ``notes``: the group-by's layout, and (filled by the first trace
     # of ``run``) its key words and reduce tier, for the span
     notes = cp.notes
-    notes["group_layout"] = _LAYOUT_NAMES.get(cp.group_layout[0], "none")
+    notes["group_layout"] = LAYOUT_NAMES.get(cp.group_layout[0], "none")
     return jax.jit(cp.run), device_aux(cp.aux), notes
 
-
-#: compiler.CompiledProgram.group_layout -> the ``group_layout`` the
-#: spans say: a sort-derived layout's groups come out compacted
-_LAYOUT_NAMES = {"keyless": "keyless", "dense": "dense",
-                 "dense_slots": "dense", "compact": "sorted"}
 
 
 def _transform_node(plan: Transform, block: TableBlock,

@@ -410,7 +410,7 @@ class _Lower:
 
     # FuncCalls producing a (dictionary-encoded) string column
     _STRING_FUNCS = frozenset({
-        "substring", "upper", "lower", "trim", "ltrim", "rtrim",
+        "substring", "substr", "upper", "lower", "trim", "ltrim", "rtrim",
         "replace", "concat", "gethost", "cutwww",
     })
 
@@ -709,8 +709,8 @@ class _Lower:
             for arg in e.args[1:]:  # n-ary folds into binary chains
                 out = Call(op, out, self.lower(arg))
             return out
-        if e.name == "substring":
-            col = self._as_string_col(e.args[0], "substring")
+        if e.name in ("substring", "substr"):   # substr: TPC-DS's q19
+            col = self._as_string_col(e.args[0], e.name)
             if not (isinstance(e.args[1], ast.Literal)
                     and isinstance(e.args[2], ast.Literal)):
                 raise PlanError("substring bounds must be literals")
@@ -2313,6 +2313,10 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
                         " SELECT list")
                 if isinstance(rw, ast.Name) and rw.parts[-1] in out_names:
                     keys.append(rw.parts[-1])
+                elif isinstance(rw, ast.Name) and rw.parts[-1] in key_out:
+                    # a group key the SELECT list projects under an alias
+                    # (TPC-DS q19: i_brand AS brand, ORDER BY i_brand)
+                    keys.append(key_out[rw.parts[-1]])
                 else:
                     name = f"__ord{i}"
                     lowered = post_low.lower(rw)

@@ -46,6 +46,11 @@ from ydb_tpu.ssa.program import (
     infer_type,
 )
 
+#: CompiledProgram.group_layout -> the ``group_layout`` a span says: a
+#: sort-derived layout's groups come out compacted
+LAYOUT_NAMES = {"keyless": "keyless", "dense": "dense",
+                "dense_slots": "dense", "compact": "sorted"}
+
 
 @dataclasses.dataclass
 class CompiledProgram:
