@@ -44,8 +44,10 @@ WAITING = {"ydb.dispatch", "ydb.device.wait", "ydb.device.get"}
 #: spans of the newest statement's profile reported with their attrs
 #: (an exchange's bucket sizes, worst count, attempts and bytes; a local
 #: join's capacities and attempts; a host concatenation's blocks, rows
-#: and bytes; a Transform's capacity, group layout, key words and tier)
-REPORTED_SPANS = ("mesh.shuffle", "mesh.join", "host.concat", "transform")
+#: and bytes; a Transform's capacity, group layout, key words and tier;
+#: a DQ graph's channel rows by path)
+REPORTED_SPANS = ("mesh.shuffle", "mesh.join", "host.concat", "transform",
+                  "dq")
 
 
 def reported_span(sp: dict) -> bool:
@@ -364,6 +366,17 @@ def main(argv=None) -> int:
         if reported:
             found["newest_statement"] = reported[-1]
             found["newest_statements"] = reported
+        # the process's DQ channel and join counters (set-up included),
+        # and the HBM a graph's channel blocks may hold, where the
+        # program has that budget (``engine/hbm.channel_budget``)
+        from ydb_tpu.engine import hbm
+        from ydb_tpu.obs.counters import root_counters
+
+        found["counters"] = {
+            c: root_counters().group(component=c).snapshot()
+            for c in ("dq", "join")}
+        found["channel_budget"] = getattr(hbm, "channel_budget",
+                                          lambda: None)()
         return totals(cluster)
 
     run.deploy.resident_totals = totals_and_mesh_report

@@ -92,17 +92,21 @@ def test_bloom_filter_native_matches_numpy(both_paths):
 
 
 def test_hash_rows_used_by_shuffle_routing():
-    from ydb_tpu.dq.compute import _hash_rows
+    """A DQ channel routes a row to consumer ``hash_rows % n``, the hash
+    computed on the device bit for bit as here."""
+    from ydb_tpu import dtypes
+    from ydb_tpu.blocks.block import TableBlock
+    from ydb_tpu.dq.compute import _route_dest
 
-    payload = {
-        "k": np.arange(100, dtype=np.int64),
-        "__v_k": np.ones(100, dtype=bool),
-    }
-
-    class S:
-        names = ("k",)
-
-    h = _hash_rows(payload, S, ("k",))
+    k = np.arange(100, dtype=np.int64)
+    ok = np.arange(100) % 7 != 0
+    block = TableBlock.from_numpy(
+        {"k": k}, dtypes.schema(("k", dtypes.INT64)), {"k": ok})
+    dest, counts = _route_dest(block, ("k",), 3)
+    h = hash_rows([k], [ok])
     assert h.dtype == np.uint64 and len(h) == 100
-    np.testing.assert_array_equal(
-        h, hash_rows([payload["k"]], [payload["__v_k"]]))
+    want = (h % np.uint64(3)).astype(np.int64)
+    np.testing.assert_array_equal(np.asarray(dest)[:100], want)
+    assert (np.asarray(dest)[100:] == 3).all()
+    np.testing.assert_array_equal(np.asarray(counts),
+                                  np.bincount(want, minlength=3))

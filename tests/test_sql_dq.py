@@ -146,3 +146,59 @@ def test_stage_graph_shape(catalog):
             assert isinstance(up.output, HashPartition)
     assert isinstance(stages[-1].output, ResultOutput)
     assert stages[-1].tasks == 1
+
+
+@pytest.mark.parametrize("name", ["q3", "q5", "q12"])
+def test_device_channels_match_host_channels(name, data, catalog,
+                                             dq_sources):
+    """The same plan on one node with its channels on the chip and (a
+    budget of 0) through the host: the same rows in the same order,
+    every column and its validity."""
+    from ydb_tpu.engine.hbm import ChannelBudget
+    from ydb_tpu.obs.counters import root_counters
+    from ydb_tpu.runtime.actors import ActorSystem
+
+    def channel_rows():
+        g = root_counters().group(component="dq")
+        return {p: g.group(path=p).counter("channel_rows").value
+                for p in ("device", "host")}
+
+    plan = plan_select_full(parse(TPCH[name]), catalog).plan
+    runs = []
+    for budget in (ChannelBudget(None), ChannelBudget(0)):
+        before = channel_rows()
+        res = execute_plan_dq(plan, dq_sources, ActorSystem(),
+                              dicts=data.dicts, n_tasks=N_TASKS,
+                              block_rows=1 << 12, channel_budget=budget)
+        runs.append((res, {p: n - before[p]
+                           for p, n in channel_rows().items()}))
+    (dev, dev_rows), (host, host_rows) = runs
+    assert dev.num_rows == host.num_rows > 0
+    assert dev.schema.names == host.schema.names
+    for c in host.schema.names:
+        for i in (0, 1):
+            np.testing.assert_array_equal(
+                np.asarray(dev.cols[c][i]), np.asarray(host.cols[c][i]),
+                err_msg=c)
+    assert dev_rows["device"] > 0
+    assert dev_rows["host"] == dev.num_rows  # the result's alone
+    assert host_rows["device"] == 0
+
+
+def test_the_dq_span_counts_channel_rows_by_path(catalog, single_db):
+    """The session path's one-process graph: the ``dq`` span says how
+    many channel rows rode the chip and how many the host carried, the
+    result's alone."""
+    from ydb_tpu.obs import tracing
+
+    plan = plan_select_full(parse(TPCH["q3"]), catalog).plan
+    tracer = tracing.Tracer()
+    root = tracer.trace("query")
+    with tracing.activate(root):
+        out = to_host(execute_plan(plan, single_db))
+    root.finish()
+    dq = [s.attrs for s in tracer.spans_for(root.trace_id)
+          if s.name == "dq"]
+    assert len(dq) == 1
+    assert dq[0]["device_channel_rows"] > 0
+    assert dq[0]["host_channel_rows"] == out.num_rows

@@ -30,6 +30,12 @@ class Counter:
         with self._lock:
             self.value = value
 
+    def set_max(self, value):
+        """Raise to ``value`` if it is higher (a peak)."""
+        with self._lock:
+            if value > self.value:
+                self.value = value
+
 
 class Histogram:
     """Fixed-bucket histogram (exponential bounds by default).

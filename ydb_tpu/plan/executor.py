@@ -333,6 +333,9 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
             handle.start()
             with tracing.span("dq.pump"):
                 rt.run()
+            rows = handle.channel_rows()
+            sp.set(device_channel_rows=rows["device"],
+                   host_channel_rows=rows["host"])
             if timer is not None:
                 pruning = collections.Counter()
                 for src, b in zip(timed, before):
