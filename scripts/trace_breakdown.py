@@ -374,7 +374,7 @@ def main(argv=None) -> int:
 
         found["counters"] = {
             c: root_counters().group(component=c).snapshot()
-            for c in ("dq", "join")}
+            for c in ("dq", "join", "rollup", "window")}
         found["channel_budget"] = getattr(hbm, "channel_budget",
                                           lambda: None)()
         return totals(cluster)

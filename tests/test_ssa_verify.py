@@ -12,7 +12,6 @@ from ydb_tpu.analysis import (
     check_program,
     verify_program,
 )
-from ydb_tpu.analysis.diagnostics import PlanError
 from ydb_tpu.blocks import TableBlock
 from ydb_tpu.ssa import (
     Agg,
@@ -100,16 +99,44 @@ def test_dead_projection():
 
 
 def test_nullable_window_key_rejected_as_plan_error():
+    """A window over a key that may be NULL was refused (V005: the
+    lowering sorted raw physical values, so a NULL ranked by the stale
+    bits under it). It now verifies and runs: the NULL partition key is
+    one partition whatever lies under its NULLs, NULL order keys come
+    last and are peers, as the numpy oracle ranks them."""
+    import jax
+
     prog = Program((
         WindowStep("rank", ("b",), ("a",), (False,), "rnk"),
+        WindowStep("rank", ("a",), ("b",), (True,), "by_b"),
     ))
-    d = _only(verify_program(prog, SCH), "V005")
-    assert d.step == 0
-    assert "NULL" in d.message
-    # the targeted rejection is a PlanError: the SQL surface reports it
-    # like any other plan-time failure
-    with pytest.raises(PlanError, match="window.*NULL|NULL.*window"):
-        check_program(prog, SCH)
+    assert verify_program(prog, SCH) == []
+    check_program(prog, SCH)     # does not raise
+    a = np.array([5, 3, 5, 1, 3, 2, 7, 5], dtype=np.int64)
+    b_ok = np.array([1, 0, 1, 1, 0, 1, 0, 1], dtype=bool)
+    # stale bits under the NULLs: distinct values a raw sort would split
+    b = np.where(b_ok, np.array([9, 0, 9, 4, 0, 4, 0, 9]),
+                 np.array([0, 77, 0, 0, -5, 0, 123, 0])).astype(np.int64)
+    block = TableBlock.from_numpy(
+        {"a": a, "b": b, "s": np.zeros(8, np.int32)}, SCH,
+        {"a": np.ones(8, bool), "b": b_ok, "s": np.ones(8, bool)})
+    out = jax.jit(compile_program(prog, SCH).run)(
+        block, {}).to_numpy()
+
+    def ranks(part, order, desc):
+        """rank() by its definition: 1 + the rows of the partition
+        that come strictly before in the order, NULLs last."""
+        key = [(0, -o if desc else o) if o is not None else (1, 0)
+               for o in order]
+        return [1 + sum(1 for j in range(8)
+                        if part[j] == part[i] and key[j] < key[i])
+                for i in range(8)]
+
+    b_vals = [int(v) if ok else None for v, ok in zip(b, b_ok)]
+    assert out["rnk"].tolist() == ranks(b_vals, a.tolist(), False)
+    assert out["by_b"].tolist() == ranks(a.tolist(), b_vals, True)
+    # the NULL partition (rows 1, 4, 6) ranks 3 < 3 == 3 < 7 inside itself
+    assert out["rnk"][[1, 4, 6]].tolist() == [1, 1, 3]
 
 
 def test_non_nullable_window_key_accepted():
@@ -219,8 +246,9 @@ def test_scan_executor_verifies_original_program():
 
 def test_nullability_threads_into_out_schema():
     """The verifier's nullability inference types the compiled output
-    schema: keyed aggregates over non-null inputs stay non-null, so a
-    downstream window over the aggregate passes the V005 check."""
+    schema: keyed aggregates over non-null inputs stay non-null, and a
+    downstream window keeps each column's flag and adds a non-null
+    rank, over a nullable order key as over a non-null one."""
     prog = Program((
         GroupByStep(("a",), (
             AggSpec(Agg.SUM, "a", "total"),
@@ -237,14 +265,13 @@ def test_nullability_threads_into_out_schema():
     assert by_name["maybe"].nullable       # input column is nullable
     assert by_name["sd"].nullable          # NULL for singleton groups
 
-    downstream = Program((
-        WindowStep("rank", (), ("total",), (True,), "rnk"),
-    ))
-    assert verify_program(downstream, cp.out_schema) == []
-    bad = Program((
-        WindowStep("rank", (), ("maybe",), (True,), "rnk"),
-    ))
-    assert _only(verify_program(bad, cp.out_schema), "V005")
+    for key in ("total", "maybe"):
+        downstream = compile_program(Program((
+            WindowStep("rank", (), (key,), (True,), "rnk"),
+        )), cp.out_schema)
+        flags = {f.name: f.nullable for f in downstream.out_schema.fields}
+        assert flags == {"a": False, "total": False, "n": False,
+                         "maybe": True, "sd": True, "rnk": False}
 
 
 def test_keyless_aggregate_is_nullable():
@@ -254,20 +281,21 @@ def test_keyless_aggregate_is_nullable():
 
 
 def test_division_is_nullable_unless_nonzero_literal_divisor():
-    """a / b NULLs rows where b == 0, whatever the operands declare —
-    so windowing over a division is a V005 rejection, closing the
-    zero-divisor bypass of the nullable-window-key guard."""
+    """a / b NULLs rows where b == 0, whatever the operands declare: the
+    division's column is typed nullable in the output schema, through a
+    window over it too."""
     by_col = Program((AssignStep("r", Call(Op.DIV, Col("a"), Col("a"))),))
     assert analyze_program(by_col, SCH).out_nullable["r"]
     by_lit = Program((AssignStep("r", Call(Op.DIV, Col("a"), lit(2))),))
     assert not analyze_program(by_lit, SCH).out_nullable["r"]
     by_zero = Program((AssignStep("r", Call(Op.DIV, Col("a"), lit(0))),))
     assert analyze_program(by_zero, SCH).out_nullable["r"]
-    windowed = Program((
+    windowed = compile_program(Program((
         AssignStep("r", Call(Op.DIV, Col("a"), Col("a"))),
         WindowStep("rank", (), ("r",), (False,), "rnk"),
-    ))
-    assert _only(verify_program(windowed, SCH), "V005")
+    )), SCH)
+    assert windowed.out_schema.field("r").nullable
+    assert not windowed.out_schema.field("rnk").nullable
 
 
 def test_scan_result_schema_keeps_original_agg_nullability():

@@ -16,26 +16,24 @@ the kernel layer unverified ("a typed plan checker in front of the
 tensor compiler keeps the kernel layer simple" — the Tensor Query
 Processor argument, PAPERS.md).
 
-Beyond types, the verifier infers *nullability* through the program
-(the compiler uses the result to type its output schema), and rejects
-ranking-window partition/order keys that may be NULL: the window
-lowering sorts raw physical values, so a NULL key would rank by the
-stale bits under the null — silently wrong results rather than an
-error (ADVICE round 5, ssa/compiler.py:321).
+Beyond types, the verifier infers *nullability* through the program;
+the compiler uses the result to type its output schema. A ranking
+window's keys may be NULL: ``kernels.window_rank`` makes the data under
+a NULL alike before its sort, so a NULL partition key is one partition
+and NULL order keys come last, as ``sort_block`` puts them.
 
 Division/modulo results are typed nullable unless the divisor is a
-provably nonzero literal (a zero divisor NULLs the row at runtime),
-so V005 also catches window keys derived from divisions. The scan
-executor types its RESULT schema from the original program's analysis
-— keyed AVG over a non-null input stays non-null even though the
-two-phase rewrite computes it via a division fixup.
+provably nonzero literal (a zero divisor NULLs the row at runtime).
+The scan executor types its RESULT schema from the original program's
+analysis — keyed AVG over a non-null input stays non-null even though
+the two-phase rewrite computes it via a division fixup. A ROLLUP's keys
+and its SUM / MIN / MAX are nullable on every level.
 
 Error codes (see ydb_tpu/analysis/README.md):
   V001 unknown-column          expression references a column not in scope
   V002 filter-not-boolean      FilterStep predicate is not BOOL
   V003 agg-input-mismatch      AggSpec input column/dtype unusable
   V004 dead-projection         ProjectStep names a column not in scope
-  V005 window-key-nullable     window partition/order key may be NULL
   V006 group-capacity          GroupByStep.max_groups is not positive
   V007 expr-type               expression cannot be typed (bad operands)
   V008 sort-desc-arity         descending flags do not match sort keys
@@ -64,6 +62,7 @@ from ydb_tpu.ssa.program import (
     GroupByStep,
     Program,
     ProjectStep,
+    RollupStep,
     SortStep,
     UdfCall,
     WindowStep,
@@ -78,6 +77,9 @@ _EMPTY_SCHEMA = dtypes.Schema(())
 _NUMERIC_AGGS = (Agg.SUM, Agg.AVG, Agg.VAR_SAMP, Agg.STDDEV_SAMP)
 
 _WINDOW_FUNCS = ("rank", "dense_rank", "row_number")
+
+#: the aggregates a RollupStep rolls up (an AVG as its SUM and COUNT)
+_ROLLUP_FUNCS = (Agg.SUM, Agg.COUNT, Agg.COUNT_ALL, Agg.MIN, Agg.MAX)
 
 #: Ops whose runtime validity collapses to "all args valid" — plus the
 #: documented zero-divisor approximation for DIV/MOD/DIV_INT.
@@ -248,6 +250,8 @@ class _Verifier:
                          " on its raw value")
         elif isinstance(s, GroupByStep):
             self._group_by(i, s)
+        elif isinstance(s, RollupStep):
+            self._rollup(i, s)
         elif isinstance(s, ProjectStep):
             kept: list = []
             for j, n in enumerate(s.names):
@@ -362,6 +366,29 @@ class _Verifier:
         self.types = out_types
         self.nullable = out_nullable
 
+    def _rollup(self, i: int, s: RollupStep) -> None:
+        names = list(s.keys)
+        for j, k in enumerate(s.keys):
+            self.expr(Col(k), i, f"steps[{i}].keys[{j}]")
+        for j, spec in enumerate(s.aggs):
+            path = f"steps[{i}].aggs[{j}]"
+            self.expr(Col(spec.out_name), i, f"{path}.out_name")
+            if spec.func not in _ROLLUP_FUNCS:
+                self.diag(
+                    "V003", "agg-input-mismatch",
+                    f"{spec.func.name} does not roll up level by level"
+                    f" (ROLLUP takes {', '.join(f.name for f in _ROLLUP_FUNCS)})",
+                    i, path, hint="an AVG rolls up as its SUM and COUNT")
+            names.append(spec.out_name)
+        self.names = names
+        self.types = {n: self.types.get(n, dtypes.INT64) for n in names}
+        # a rolled-up key is NULL on its level; a SUM, MIN or MAX is
+        # NULL on the grand total of no rows; a COUNT never is
+        self.nullable = {n: True for n in names}
+        for spec in s.aggs:
+            if spec.func in (Agg.COUNT, Agg.COUNT_ALL):
+                self.nullable[spec.out_name] = False
+
     def _window(self, i: int, s: WindowStep) -> None:
         if s.func not in _WINDOW_FUNCS:
             self.diag(
@@ -378,19 +405,7 @@ class _Verifier:
         for role, keys in (("partition", s.partition),
                            ("order", s.order_keys)):
             for j, k in enumerate(keys):
-                path = f"steps[{i}].{role}[{j}]"
-                t, null = self.expr(Col(k), i, path)
-                if t is None:
-                    continue
-                if null:
-                    self.diag(
-                        "V005", "window-key-nullable",
-                        f"window {role} key {k!r} may be NULL; the"
-                        " ranking lowering sorts raw physical values,"
-                        " so NULL keys would rank by stale bits"
-                        " instead of grouping as NULL", i, path,
-                        hint="COALESCE the key or filter NULLs ahead"
-                             " of the window")
+                self.expr(Col(k), i, f"steps[{i}].{role}[{j}]")
         self.types[s.out_name] = dtypes.INT64
         self.nullable[s.out_name] = False
         if s.out_name not in self.names:

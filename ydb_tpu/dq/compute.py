@@ -55,8 +55,13 @@ from ydb_tpu.obs.counters import root_counters
 from ydb_tpu.parallel.shuffle import hash_rows
 from ydb_tpu.runtime.actors import Actor, ActorId
 from ydb_tpu.ssa import kernels
-from ydb_tpu.ssa.compiler import LAYOUT_NAMES, compile_program
+from ydb_tpu.ssa.compiler import (
+    LAYOUT_NAMES,
+    compile_program,
+    resolve_whole_input_step,
+)
 from ydb_tpu.ssa.plan_fuse import shape_class
+from ydb_tpu.ssa.program import Program, RollupStep, WindowStep
 
 DEFAULT_WINDOW = 4  # unacked blocks per channel before spilling
 
@@ -367,6 +372,138 @@ def _as_input(block: TableBlock, schema: dtypes.Schema) -> TableBlock:
                       block.length, schema)
 
 
+def _split_whole_input(program: Program):
+    """A final program as (the steps before its first RollupStep or
+    WindowStep, that step, the steps after it), each a Program or None;
+    (the program, None, None) without such a step."""
+    at = next((i for i, s in enumerate(program.steps)
+               if isinstance(s, (RollupStep, WindowStep))), None)
+    if at is None:
+        return program, None, None
+    steps = program.steps
+    return (Program(steps[:at]) if at else None, steps[at],
+            Program(steps[at + 1:]) if at + 1 < len(steps) else None)
+
+
+class _WholeInput:
+    """A stage's ROLLUP or ranking window, run once over the stage's
+    whole input apart from the programs around it, so that its span and
+    statement key hold its own work. A rollup's levels are sized by
+    their rows (``rollup_counts``, read back in one small fetch) at
+    shape classes, where a program traced blind would give each level
+    the capacity of the finest; a window reads back nothing it does not
+    need (its partitions only for a trace). ``post`` is the program
+    after the step, compiled over its output."""
+
+    def __init__(self, step, post, in_schema: dtypes.Schema, ordered,
+                 dicts, key_spaces, aliases):
+        self.step = step
+        self.lowering, aux = resolve_whole_input_step(
+            step, in_schema, dicts, key_spaces, aliases)
+        self.aux = device_aux(aux)
+        if isinstance(step, RollupStep):
+            self.kind = "rollup"
+            self.ordered = tuple(ordered) == tuple(step.keys)
+            counts_of = self.lowering.counts_of
+            self.schema = dtypes.Schema(tuple(
+                dtypes.Field(n, in_schema.field(n).type, n not in counts_of)
+                for n in self.lowering.names))
+        else:
+            self.kind = "window"
+            self.schema = dtypes.Schema(tuple(
+                f for f in in_schema.fields if f.name != step.out_name)
+                + (dtypes.Field(step.out_name, dtypes.INT64, False),))
+        self.post = None
+        self.out_schema = self.schema
+        if post is not None:
+            self.post = compile_program(post, self.schema, dicts,
+                                        key_spaces, dict_aliases=aliases)
+            self._post_jit = jax.jit(self.post.run)
+            self._post_aux = device_aux(self.post.aux)
+            self.out_schema = self.post.out_schema
+
+    @functools.cached_property
+    def _counts(self):
+        return jax.jit(lambda parts: self.lowering.counts(
+            merge_blocks_device(list(parts)), self.ordered))
+
+    @functools.cached_property
+    def _levels(self):
+        def levels(parts, aux, caps, out_cap):
+            block, _ = self.lowering.apply(
+                merge_blocks_device(list(parts)), aux, caps, out_cap,
+                self.ordered)
+            return TableBlock(block.columns, block.length, self.schema)
+
+        return jax.jit(levels, static_argnums=(2, 3))
+
+    @functools.cached_property
+    def _window(self):
+        def window(parts, aux):
+            block = merge_blocks_device(list(parts))
+            col, partitions = self.lowering.apply(
+                block.columns, aux, block.row_mask())
+            cols = {n: (col if n == self.step.out_name
+                        else block.columns[n]) for n in self.schema.names}
+            return TableBlock(cols, block.length, self.schema), partitions
+
+        return jax.jit(window)
+
+    @host_ok("a rollup's level rows, one small fetch that sizes its"
+             " levels; a window's partitions, read for a trace's span")
+    def run(self, blocks: list, rows_in: int | None,
+            traced: bool) -> tuple[TableBlock, dict]:
+        """The step over the stage's input blocks (``rows_in`` live rows
+        where the caller knows them), then the program after it: (its
+        output, what its span says)."""
+        blocks = tuple(blocks)
+        if self.kind == "rollup":
+            with tracing.span("device.wait"):
+                counts = jax.device_get(self._counts(blocks)).tolist()
+            caps, room = [], sum(b.capacity for b in blocks)
+            for rows in reversed(counts[:-1]):   # the finest level first
+                room = min(room, shape_class(rows))
+                caps.append(room)
+            caps[-1] = 1
+            out = self._levels(blocks, self.aux, tuple(reversed(caps)),
+                               shape_class(sum(counts)))
+            notes = {"rollup_levels": len(counts), "rows_in": counts[-1],
+                     "groups": counts[::-1]}
+        else:
+            out, partitions = self._window(blocks, self.aux)
+            if rows_in is None:
+                rows_in = sum(b.live_rows() for b in blocks)
+            notes = {"window": self.step.func, "rows_in": rows_in,
+                     "key_words": self.key_words}
+            if traced:
+                with tracing.span("device.wait"):
+                    notes["partitions"] = int(partitions)
+        if self.post is not None:
+            out = self._post_jit(out, self._post_aux)
+        return out, notes
+
+    @property
+    def key_words(self) -> int:
+        return sum(-(-self.schema.field(k).type.physical.itemsize // 4)
+                   for k in self.step.partition + self.step.order_keys)
+
+    def least_bytes(self, notes: dict) -> int:
+        """The bytes the step cannot move less of: a rollup reads each
+        column of its finest level once and writes every level's rows
+        once; a window reads its keys once and writes its column once
+        (data and a validity byte each)."""
+        def width(names):
+            return sum(self.schema.field(n).type.physical.itemsize + 1
+                       for n in names)
+
+        if self.kind == "rollup":
+            return (notes["rows_in"] + sum(notes["groups"])) * width(
+                self.schema.names)
+        return notes["rows_in"] * width(
+            self.step.partition + self.step.order_keys
+            + (self.step.out_name,))
+
+
 class _CompiledStage:
     """Per-stage compiled programs + schemas (shared by its tasks).
 
@@ -401,16 +538,23 @@ class _CompiledStage:
             mid = in_schema
             self.block_notes = {}
         self.mid_schema = mid
-        if spec.final_program is not None:
-            from ydb_tpu.ssa import twophase
+        self.final = self.whole = None
+        self.final_notes = {}
+        self._f_aux = {}
+        self.out_schema = mid
+        self._finalize_jit = jax.jit(
+            lambda parts, aux: merge_blocks_device(list(parts)))
+        if spec.final_program is None:
+            return
+        from ydb_tpu.ssa import twophase
 
-            aliases = dict(spec.dict_aliases)
-            if spec.program is not None:
-                aliases.update(twophase.dict_aliases(spec.program))
-            self.final = compile_program(
-                spec.final_program, mid, dicts, key_spaces,
-                dict_aliases=aliases,
-            )
+        aliases = dict(spec.dict_aliases)
+        if spec.program is not None:
+            aliases.update(twophase.dict_aliases(spec.program))
+        pre, step, post = _split_whole_input(spec.final_program)
+        if pre is not None:
+            self.final = compile_program(pre, mid, dicts, key_spaces,
+                                         dict_aliases=aliases)
             self._f_aux = device_aux(self.final.aux)
             self.final_notes = _span_notes(self.final)
             self.out_schema = self.final.out_schema
@@ -425,13 +569,12 @@ class _CompiledStage:
                 return final_run(merge_blocks_device(list(parts)), aux)
 
             self._finalize_jit = _finalize
-        else:
-            self.final = None
-            self.final_notes = {}
-            self.out_schema = mid
-            self._f_aux = {}
-            self._finalize_jit = jax.jit(
-                lambda parts, aux: merge_blocks_device(list(parts)))
+        if step is not None:
+            self.whole = _WholeInput(
+                step, post, self.out_schema,
+                self.final.ordered if self.final is not None else (),
+                dicts, key_spaces, aliases)
+            self.out_schema = self.whole.out_schema
 
     def run_block(self, block: TableBlock) -> TableBlock:
         if self.per_block is None:
@@ -804,11 +947,56 @@ class ComputeActor(Actor):
                 else payload_to_block(self.spiller.get(p), mid)
                 for p in self._acc
             ] or [_empty_block(mid)]
-            self._emit(self._timed(self.compiled.run_final, blocks,
-                                   notes=self.compiled.final_notes))
+            if self.compiled.whole is None:
+                self._emit(self._timed(self.compiled.run_final, blocks,
+                                       notes=self.compiled.final_notes))
+            else:
+                self._whole_input(blocks)
             _release(self._acc)
             self._acc = []
         self._finish_output()
+
+    def _whole_input(self, blocks: list):
+        """A stage whose final program holds a ROLLUP or a ranking
+        window: the program before it (the merged group-by a rollup
+        rolls up) in one ``dispatch`` span, which, traced, waits for its
+        rows; then the step, the program after it and the output's
+        routing in a second, which says what the step did
+        (``rollup_levels``, ``rows_in``, ``groups`` a level, the finest
+        first; ``window``, ``rows_in``, ``key_words`` and, traced,
+        ``partitions``). The process counts
+        it (``component=rollup | window``) whether or not a trace is
+        active; untraced, nothing is read back that the step does not
+        need: a window's rows are its input blocks', already known,
+        where no program runs before it."""
+        whole = self.compiled.whole
+        traced = self._span is not None
+        rows_in = sum(map(_item_rows, self._acc))
+        if self.compiled.final is not None:
+            def pre(parts):
+                out = self.compiled.run_final(parts)
+                if traced:      # the span holds the program's own time
+                    out.live_rows()
+                return out
+
+            blocks = [self._timed(pre, blocks,
+                                  notes=self.compiled.final_notes)]
+            rows_in = None
+        with tracing.span("dispatch", program="dq_stage") as sp:
+            t0 = time.perf_counter()
+            out, notes = whole.run(blocks, rows_in, traced)
+            self._compute_s += time.perf_counter() - t0
+            sp.set(**notes)
+            self._emit(out)
+        g = root_counters().group(component=whole.kind)
+        if whole.kind == "rollup":
+            g.counter("rollups").inc()
+            g.counter("levels").inc(notes["rollup_levels"])
+            g.counter("groups").inc(sum(notes["groups"]))
+        else:
+            g.counter("windows").inc()
+        g.counter("rows_in").inc(notes["rows_in"])
+        g.counter("bytes_least").inc(whole.least_bytes(notes))
 
     def _join_bucket(self):
         """The join stage's one dispatch: the bucket's two sides packed

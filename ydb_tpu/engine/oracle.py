@@ -113,21 +113,32 @@ def run_oracle(
                 mask = np.ones(n, dtype=bool)
         elif isinstance(step, WindowStep):
             # deliberately DIFFERENT algorithm from the device plane:
-            # python sort + per-partition scan (vs lexsort + segment
-            # cummax), so the cross-check is independent
+            # python sort + per-partition scan (vs stable passes +
+            # segment scans), so the cross-check is independent. A NULL
+            # partition key is one partition; NULL order keys come last
+            # in either direction and are peers
             live_idx = np.flatnonzero(mask)
 
             def keyval(col, i):
+                if not cols[col][1][i]:
+                    return None
                 v = cols[col][0][i]
                 t = types[col]
                 if t.is_string:
                     return int(dicts[col].sort_rank()[int(v)])
                 return v
 
+            def order_val(col, i, dsc):
+                v = keyval(col, i)
+                if v is None:
+                    return (1, 0)
+                return (0, -v if dsc else v)
+
             def sort_key(i):
-                parts = [keyval(k, i) for k in step.partition]
+                parts = [(0, keyval(k, i)) if keyval(k, i) is not None
+                         else (1, 0) for k in step.partition]
                 orders = [
-                    -keyval(k, i) if dsc else keyval(k, i)
+                    order_val(k, i, dsc)
                     for k, dsc in zip(
                         step.order_keys,
                         step.descending

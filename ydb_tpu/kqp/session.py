@@ -348,8 +348,8 @@ class Cluster:
     def stop(self, timeout: float = 30.0) -> None:
         """Orderly node teardown (the driver_lib shutdown analog): stop
         the statistics cadence thread, wait for queued background work
-        (promotions, prefetch, compaction tasks) to drain off the
-        shared conveyor, then — under YDB_TPU_LEAKSAN — prove every
+        (promotions, prefetch, compaction tasks, morsel reads) to drain
+        off the shared and stream conveyors, then — under YDB_TPU_LEAKSAN — prove every
         tracked resource handle in the process drained to zero
         (:class:`~ydb_tpu.analysis.leaksan.LeakError` names survivors).
         Added for lifecycle rule R005: the cluster held the stoppable
@@ -357,9 +357,12 @@ class Cluster:
         The drain check is process-global, so call it with no other
         cluster mid-statement (tests; single-node serving)."""
         self.stats.stop()
-        from ydb_tpu.runtime.conveyor import shared_conveyor
+        from ydb_tpu.runtime.conveyor import shared_conveyor, stream_conveyor
 
         shared_conveyor().wait_idle(timeout=timeout)
+        # a cancelled scan's morsel tasks stay queued on the stream pool
+        # until a worker takes them up as no-ops
+        stream_conveyor().wait_idle(timeout=timeout)
         _leaksan.assert_drained(where="Cluster.stop")
 
     # ---- dict durability (cluster-wide journal) ----

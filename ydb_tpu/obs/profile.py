@@ -103,6 +103,17 @@ WALK_SPAN_KEYS = {"host.concat": "concat", "transform": "transform"}
 #: ``dispatch`` and ``device_wait`` already count; only of a statement
 #: with such a span
 DQ_JOIN_KEY = "dq_join"
+#: two more views by the same rule, of the DQ stage that rolls a
+#: group-by up (the ``dispatch program=dq_stage`` span with a
+#: ``rollup_levels`` attr: dq/compute.py ``_whole_input``: the levels'
+#: rows read back, the levels, the program after them, the output's
+#: routing) and of the stage that ranks (a ``window`` attr), each only of
+#: a statement with such a span
+DQ_ROLLUP_KEY = "dq_rollup"
+DQ_WINDOW_KEY = "dq_window"
+#: each DQ stage view: its key -> the attr its stages' spans carry
+DQ_STAGE_VIEWS = {DQ_JOIN_KEY: "join", DQ_ROLLUP_KEY: "rollup_levels",
+                  DQ_WINDOW_KEY: "window"}
 #: span attrs summed into the per-query pruning/row accounting
 PRUNING_KEYS = ("portions_total", "portions_skipped", "chunks_read",
                 "chunks_skipped", "resident_portions", "resident_rows")
@@ -254,10 +265,11 @@ def statement_stages(spans, seconds: float) -> dict:
     return out
 
 
-def join_stage_seconds(spans) -> float | None:
-    """``DQ_JOIN_KEY``'s seconds, or None without a DQ join stage."""
+def dq_stage_seconds(spans, attr: str) -> float | None:
+    """A ``DQ_STAGE_VIEWS`` key's seconds, by the attr its stages' spans
+    carry, or None without such a stage."""
     stages = {s.span_id for s in spans
-              if s.name == "dispatch" and "join" in s.attrs}
+              if s.name == "dispatch" and attr in s.attrs}
     if not stages:
         return None
     by_id = {s.span_id: s for s in spans}
@@ -394,9 +406,10 @@ def build_profile(spans, sql: str = "", kind: str = "",
             for k in PRUNING_KEYS:
                 p.pruning[k] += int(a.get(k, 0))
     p.stages.update(statement_stages(spans, p.seconds))
-    joins = join_stage_seconds(spans)
-    if joins is not None:
-        p.stages[DQ_JOIN_KEY] = joins
+    for key, attr in DQ_STAGE_VIEWS.items():
+        seconds = dq_stage_seconds(spans, attr)
+        if seconds is not None:
+            p.stages[key] = seconds
     p.stages = {k: round(v, 6) for k, v in p.stages.items()}
     p.rows = rows if rows is not None else rows_out
     p.execute_seconds = max(0.0, p.seconds - p.compile_seconds)

@@ -62,7 +62,7 @@ from ydb_tpu.plan.nodes import ExpandJoin, LookupJoin, TableScan, Transform
 from ydb_tpu.ssa import join as join_kernels
 from ydb_tpu.ssa import kernels
 from ydb_tpu.ssa.plan_fuse import shape_class
-from ydb_tpu.ssa.program import SortStep, WindowStep
+from ydb_tpu.ssa.program import RollupStep, SortStep, WindowStep
 
 
 #: the device-local joins' operations outside ssa/join.py's own scopes
@@ -561,10 +561,11 @@ class MeshPlanExecutor:
                                 table=pushed.table, fresh=fresh)
 
     def _transform(self, plan: Transform, memo, root: bool):
-        if any(isinstance(s, WindowStep) for s in plan.program.steps):
-            # ranking windows need every row at once; a per-shard
-            # elementwise run would rank within shards. Fall back to
-            # the single-chip/DQ path.
+        if any(isinstance(s, (RollupStep, WindowStep))
+               for s in plan.program.steps):
+            # ranking windows and rollups need every row at once; a
+            # per-shard elementwise run would rank within shards. Fall
+            # back to the single-chip/DQ path.
             raise NotImplementedError("window function on the mesh")
         if root:
             out = self._scan_aggregated(plan, memo.shared)

@@ -71,7 +71,7 @@ from ydb_tpu.ssa.plan_fuse import (
     lookup_schema,
     shape_class,
 )
-from ydb_tpu.ssa.program import SortStep, WindowStep
+from ydb_tpu.ssa.program import RollupStep, SortStep, WindowStep
 
 #: in-process override (tests pick the executor with it); None defers
 #: to the env
@@ -101,7 +101,7 @@ def _walk(plan: PlanNode):
 def _aggregating(program) -> bool:
     return (program is not None
             and (program.group_by is not None
-                 or any(isinstance(s, (SortStep, WindowStep))
+                 or any(isinstance(s, (SortStep, WindowStep, RollupStep))
                         for s in program.steps)))
 
 
@@ -136,7 +136,8 @@ def mesh_signature(plan: PlanNode, db, ndev: int) -> PlanSignature | None:
         return None
     if plan.program.group_by is None:
         return None
-    if any(isinstance(s, WindowStep) for s in plan.program.steps):
+    if any(isinstance(s, (RollupStep, WindowStep))
+           for s in plan.program.steps):
         return None
     fsources: dict = {}
     for node in _walk(plan):
@@ -258,7 +259,7 @@ class MeshLowering(PlanLowering):
 
     def lower_transform(self, node: Transform):
         prog = node.program
-        if any(isinstance(s, WindowStep) for s in prog.steps):
+        if any(isinstance(s, (RollupStep, WindowStep)) for s in prog.steps):
             raise Unfusible("window function on the mesh")
         if self.ndev == 1 or not _aggregating(prog):
             # 1-device mesh: the base (single-chip) lowering IS the

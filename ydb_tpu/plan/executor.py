@@ -47,6 +47,7 @@ from ydb_tpu.ssa.program import (
     GroupByStep,
     Program,
     ProjectStep,
+    RollupStep,
 )
 from ydb_tpu.plan.nodes import (
     Concat,
@@ -362,9 +363,10 @@ def _execute_plan_dq(plan: PlanNode, db: Database) -> TableBlock | None:
 def execute_plan(plan: PlanNode, db: Database,
                  _memo: _Memo | None = None,
                  use_dq: bool | None = None) -> TableBlock:
-    """Execute a logical plan: join-bearing plans route through the DQ
-    stage graph (the production executer path); single-stage plans and
-    non-lowerable shapes use the bottom-up walk. ``_memo`` dedupes
+    """Execute a logical plan: plans that join or hold a GROUP BY ROLLUP
+    route through the DQ stage graph (the production executer path, and
+    the one that sizes a rollup's levels by their rows); single-stage
+    plans and non-lowerable shapes use the bottom-up walk. ``_memo`` dedupes
     shared subtrees (a CTE referenced from several places executes once
     per statement)."""
     if _memo is None:
@@ -374,6 +376,8 @@ def execute_plan(plan: PlanNode, db: Database,
                 return out
         if (use_dq if use_dq is not None else _DQ_ON) and any(
                 isinstance(n, (LookupJoin, ExpandJoin))
+                or (isinstance(n, Transform) and any(
+                    isinstance(s, RollupStep) for s in n.program.steps))
                 for n in _plan_nodes(plan)):
             out = _execute_plan_dq(plan, db)
             if out is not None:

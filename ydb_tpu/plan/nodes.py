@@ -87,7 +87,8 @@ def format_plan(plan: PlanNode, indent: int = 0) -> str:
         if program is None:
             return ""
         from ydb_tpu.ssa.program import (
-            AssignStep, FilterStep, GroupByStep, ProjectStep, SortStep,
+            AssignStep, FilterStep, GroupByStep, ProjectStep, RollupStep,
+            SortStep, WindowStep,
         )
 
         bits = []
@@ -104,6 +105,11 @@ def format_plan(plan: PlanNode, indent: int = 0) -> str:
                 bits.append(
                     f"group_by[keys={list(s.keys)}, "
                     f"aggs={len(s.aggs)}]")
+            elif isinstance(s, RollupStep):
+                bits.append(f"rollup[levels={len(s.keys) + 1}]")
+            elif isinstance(s, WindowStep):
+                bits.append(f"window[{s.func}, partition="
+                            f"{list(s.partition)}, order={list(s.order_keys)}]")
             elif isinstance(s, SortStep) and (s.keys or s.limit):
                 lim = f", limit={s.limit}" if s.limit is not None else ""
                 bits.append(f"sort[{list(s.keys)}{lim}]")

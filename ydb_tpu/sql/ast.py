@@ -174,6 +174,9 @@ class Select:
     distinct: bool = False
     # WITH name AS (select), ...: CTEs usable as FROM sources downstream
     ctes: tuple[tuple[str, "Select"], ...] = ()
+    # GROUP BY ROLLUP(group_by): every prefix of the keys is a grouping
+    # set too, down to the grand total
+    rollup: bool = False
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2,8 +2,8 @@
 
 Dialect: the YQL/PostgreSQL-flavored subset the engine executes — SELECT
 with expressions/aggregates, multi-way JOIN ... ON, WHERE with
-AND/OR/NOT/BETWEEN/IN/LIKE/IS NULL/CASE, GROUP BY, HAVING, ORDER BY ...
-[ASC|DESC], LIMIT; INSERT INTO ... VALUES; CREATE TABLE with PRIMARY KEY.
+AND/OR/NOT/BETWEEN/IN/LIKE/IS NULL/CASE, GROUP BY [ROLLUP(...)], HAVING,
+ORDER BY ... [ASC|DESC], LIMIT; INSERT INTO ... VALUES; CREATE TABLE with PRIMARY KEY.
 Grammar is layered by precedence (or > and > not > cmp > add > mul >
 unary > primary), one function per layer — the shape of the reference's
 SQL grammar without the generated-parser machinery (yql/sql/v1).
@@ -213,11 +213,22 @@ class Parser:
             from_ = self.parse_from()
         where = self.parse_expr() if self.kw("where") else None
         group_by: tuple = ()
+        rollup = False
         if self.kw("group"):
             self.expect("kw", "by")
+            # GROUP BY ROLLUP(k1, ..., kn): ``rollup`` is no keyword, so
+            # a column of that name still parses
+            after = self.toks[self.i + 1]
+            if (self.peek().kind == "name"
+                    and self.peek().value.lower() == "rollup"
+                    and after.kind == "op" and after.value == "("):
+                self.i += 2
+                rollup = True
             gb = [self.parse_expr()]
             while self.accept("op", ","):
                 gb.append(self.parse_expr())
+            if rollup:
+                self.expect("op", ")")
             group_by = tuple(gb)
         having = self.parse_expr() if self.kw("having") else None
         order_by: tuple = ()
@@ -231,7 +242,7 @@ class Parser:
         if self.kw("limit"):
             limit = int(self.expect("number").value)
         return ast.Select(tuple(items), from_, where, group_by, having,
-                          order_by, limit, distinct, tuple(ctes))
+                          order_by, limit, distinct, tuple(ctes), rollup)
 
     def parse_select_item(self) -> ast.SelectItem:
         if self.peek().kind == "op" and self.peek().value == "*":

@@ -41,6 +41,7 @@ from ydb_tpu.ssa.program import (
     GroupByStep,
     Program,
     ProjectStep,
+    RollupStep,
     SortStep,
     WindowStep,
     infer_type,
@@ -1332,6 +1333,10 @@ class _SelectPlanner:
                 ast.Name((n,)) for n in sorted(used)
                 if ast.Name((n,)) not in sel.group_by
             )
+            if extra and sel.rollup:
+                raise PlanError(
+                    "a scalar subquery's value cannot join the keys of"
+                    " GROUP BY ROLLUP")
             if extra:
                 sel = dataclasses.replace(
                     sel, group_by=tuple(sel.group_by) + extra)
@@ -2032,7 +2037,7 @@ def _rewrite_mixed_distinct(sel: ast.Select, planner):
     plain = [a for a in aggs if not a.distinct]
     d_cols = {a.args[0].column for a in distinct
               if a.args and isinstance(a.args[0], ast.Name)}
-    if not distinct or not (plain or len(d_cols) > 1):
+    if not distinct or not (plain or len(d_cols) > 1) or sel.rollup:
         return None
     if any(a.name != "count" or not a.args
            or not isinstance(a.args[0], ast.Name) for a in distinct):
@@ -2121,8 +2126,16 @@ def _rewrite_mixed_distinct(sel: ast.Select, planner):
     return dataclasses.replace(sel, items=new_items, having=new_having)
 
 
+#: the aggregates GROUP BY ROLLUP rolls up level by level (RollupStep);
+#: AVG rolls up as its SUM and its COUNT
+_ROLLUP_AGGS = (Agg.SUM, Agg.COUNT, Agg.COUNT_ALL, Agg.MIN, Agg.MAX)
+
+
 def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
-    """Lower GROUP BY + aggregates + HAVING into SSA steps.
+    """Lower GROUP BY [ROLLUP] + aggregates + HAVING into SSA steps. A
+    ROLLUP's finest grouping is the GroupByStep, its coarser levels a
+    RollupStep after it (each rolled-up key NULL on its level); an AVG
+    there is its SUM and its COUNT, divided once the levels exist.
 
     Returns (steps, out_names, out_types, group_key_out_names)."""
     # group keys may be select aliases of computed exprs (q7's l_year
@@ -2170,6 +2183,8 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
     agg_specs: list[AggSpec] = []
     agg_map: dict = {}
     distinct_cols: list[str] = []
+    #: (avg name, its SUM's name, its COUNT's name) under a ROLLUP
+    rolled_avgs: list[tuple[str, str, str]] = []
 
     def register_agg(fc: ast.FuncCall) -> str:
         key = repr(fc)
@@ -2192,8 +2207,24 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
                 if fc.name != "count":
                     raise PlanError(
                         "DISTINCT is supported for COUNT only")
+                if sel.rollup:
+                    raise PlanError(
+                        "COUNT(DISTINCT) does not roll up: GROUP BY"
+                        " ROLLUP takes SUM, COUNT, MIN, MAX and AVG")
                 distinct_cols.append(col)
-            agg_specs.append(AggSpec(func, col, name))
+            if sel.rollup and func is Agg.AVG:
+                name = f"__avg{len(rolled_avgs)}"
+                s_name, c_name = (f"__agg{len(agg_specs)}",
+                                  f"__agg{len(agg_specs) + 1}")
+                agg_specs.append(AggSpec(Agg.SUM, col, s_name))
+                agg_specs.append(AggSpec(Agg.COUNT, col, c_name))
+                rolled_avgs.append((name, s_name, c_name))
+            elif sel.rollup and func not in _ROLLUP_AGGS:
+                raise PlanError(
+                    f"{fc.name}() does not roll up: GROUP BY ROLLUP"
+                    " takes SUM, COUNT, MIN, MAX and AVG")
+            else:
+                agg_specs.append(AggSpec(func, col, name))
         agg_map[key] = name
         return name
 
@@ -2260,6 +2291,12 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
         steps.append(GroupByStep(
             tuple(key_names) + tuple(dict.fromkeys(distinct_cols)), ()))
     steps.append(GroupByStep(tuple(key_names), tuple(agg_specs)))
+    if sel.rollup:
+        steps.append(RollupStep(tuple(key_names), tuple(
+            AggSpec(s.func, s.out_name, s.out_name) for s in agg_specs)))
+        for name, s_name, c_name in rolled_avgs:
+            steps.append(AssignStep(name, Call(
+                Op.DIV, Call(Op.CAST_DOUBLE, Col(s_name)), Col(c_name))))
 
     from ydb_tpu.ssa.program import agg_result_type
 
@@ -2267,6 +2304,8 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
     post_dict_src = dict(low.dict_src)
     for spec in agg_specs:
         post_types[spec.out_name] = agg_result_type(spec, None, low.types)
+    for name, _, _ in rolled_avgs:
+        post_types[name] = dtypes.DOUBLE
     post_low = _Lower(post_types, low.dicts, post_dict_src,
                       udfs=low.udfs)
     for spec in agg_specs:
@@ -2333,6 +2372,7 @@ def _plan_aggregate(sel: ast.Select, low: _Lower, steps: list, having):
     out_types = {n: post_low.types[n] for n in out_names}
     # propagate dictionary renames for downstream consumers
     low.dict_src.update(post_low.dict_src)
-    # the output names the group keys survive under (None if projected out)
-    key_outs = [key_out.get(k) for k in key_names]
+    # the output names the group keys survive under (None if projected
+    # out); a ROLLUP's rows are not unique on them
+    key_outs = [] if sel.rollup else [key_out.get(k) for k in key_names]
     return steps, out_names, out_types, key_outs

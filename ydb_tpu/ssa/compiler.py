@@ -39,6 +39,7 @@ from ydb_tpu.ssa.program import (
     GroupByStep,
     ProjectStep,
     Program,
+    RollupStep,
     SortStep,
     UdfCall,
     WindowStep,
@@ -83,6 +84,10 @@ class CompiledProgram:
     #: step's LIMIT, where it has one); the executor's ``transform``
     #: span carries them
     notes: dict = dataclasses.field(default_factory=dict, compare=False)
+    #: the keys whose equal prefixes the output holds in contiguous runs
+    #: (a group-by's output and what keeps its order): a RollupStep over
+    #: it takes the rows as they come
+    ordered: tuple = ()
     # aux staged to the device once, on first dispatch — restaging the
     # whole dict per call cost an H2D transfer per statement. Staleness
     # is impossible: the compile caches key on the dict contents and
@@ -207,6 +212,10 @@ def _compile_program(
     # the fused group-by collapses per-column valid counts and input
     # masking for columns that provably carry no NULLs
     cur_nullable = {f.name: f.nullable for f in schema.fields}
+    # the keys whose equal prefixes run contiguous in the rows, the live
+    # rows a prefix (a group-by's output), where a RollupStep may take
+    # the rows as they come
+    ordered: tuple = ()
 
     def resolve_expr(expr: Expr):
         """Return (lower_fn(env, aux) -> Column, LogicalType)."""
@@ -265,11 +274,14 @@ def _compile_program(
                 step.expr, cur_nullable)
             if step.name not in cur_names:
                 cur_names.append(step.name)
+            if step.name in ordered:
+                ordered = ()
             plan.append(("assign", (step.name, fn)))
         elif isinstance(step, FilterStep):
             fn, t = resolve_expr(step.expr)
             if t.kind != dtypes.Kind.BOOL:
                 raise TypeError(f"filter predicate must be bool, got {t}")
+            ordered = ()
             plan.append(("filter", fn))
         elif isinstance(step, GroupByStep):
             lowered = _resolve_group_by(ctx, step, cur_types,
@@ -280,13 +292,23 @@ def _compile_program(
             # aggregate outputs may be NULL for empty/dead groups;
             # conservative for any later step
             cur_nullable = {n: True for n in cur_names}
+            ordered = group_order(step, ctx.group_layout)
+        elif isinstance(step, RollupStep):
+            # a program traced blind would give every level the finest
+            # level's capacity: the DQ stage reads the levels' rows
+            # first and sizes them (dq/compute.py, plan/executor.py)
+            raise NotImplementedError(
+                "GROUP BY ROLLUP runs on the DQ executor")
         elif isinstance(step, ProjectStep):
             missing = [n for n in step.names if n not in cur_types]
             if missing:
                 raise KeyError(f"projection of unknown columns {missing}")
             cur_names = list(step.names)
+            if not set(ordered) <= set(step.names):
+                ordered = ()
             plan.append(("project", tuple(step.names)))
         elif isinstance(step, SortStep):
+            ordered = ()
             desc = step.descending or (False,) * len(step.keys)
             # string keys order by dictionary *rank*, not id: ship a
             # plan-time rank table per string key (ydb_tpu.blocks.dictionary)
@@ -306,33 +328,13 @@ def _compile_program(
                 ("sort", (tuple(step.keys), tuple(desc), step.limit,
                           tuple(ranks))))
         elif isinstance(step, WindowStep):
-            if step.func not in ("rank", "dense_rank", "row_number"):
-                raise NotImplementedError(
-                    f"window function {step.func}")
-            # string keys compare by dictionary RANK (partition needs
-            # only equality, but ranks are equality-preserving too, so
-            # one treatment covers both roles)
-            wranks = []
-            for k in step.partition + step.order_keys:
-                t = cur_types[k]
-                if t.is_string:
-                    d = ctx.dictionary(k)
-                    if d is None:
-                        raise ValueError(
-                            f"window key on string column {k} needs"
-                            " its dictionary")
-                    wranks.append(
-                        ctx.add_aux(f"wrank.{k}", d.sort_rank()))
-                else:
-                    wranks.append(None)
-            desc = step.descending or (False,) * len(step.order_keys)
+            lowered = WindowLowering.resolve(ctx, step, cur_types)
             cur_types[step.out_name] = dtypes.INT64
             if step.out_name not in cur_names:
                 cur_names.append(step.out_name)
-            plan.append(("window", (
-                step.func, tuple(step.partition),
-                tuple(step.order_keys), tuple(desc), tuple(wranks),
-                step.out_name)))
+            if step.out_name in ordered:
+                ordered = ()
+            plan.append(("window", lowered))
         else:
             raise NotImplementedError(f"step {step}")
 
@@ -402,63 +404,158 @@ def _compile_program(
                 length = blk.length
                 mask = blk.row_mask()
             elif kind == "window":
-                func, pkeys, okeys, desc, wranks, out_name = payload
+                win = payload
                 cap = next(iter(env.values())).data.shape[0]
                 live = mask & (jnp.arange(cap, dtype=jnp.int32)
                                < length)
-                vals = []
-                for k, rk in zip(pkeys + okeys, wranks):
-                    c = env[k]
-                    if rk is not None:
-                        c = kernels.dict_gather(aux[rk], c)
-                    d_ = c.data
-                    if d_.dtype == jnp.bool_:
-                        d_ = d_.astype(jnp.int32)
-                    vals.append(d_)
-                pvals = vals[:len(pkeys)]
-                ovals = []
-                for d_, dsc in zip(vals[len(pkeys):], desc):
-                    ovals.append(-d_ if dsc else d_)
-                # lexsort: LAST key is primary — liveness first, then
-                # partition, then order keys
-                perm = jnp.lexsort(tuple(
-                    reversed([(~live).astype(jnp.int32)]
-                             + pvals + ovals)))
-                idx = jnp.arange(cap, dtype=jnp.int32)
-
-                def changed(cols_sorted):
-                    ch = idx == 0
-                    for c in cols_sorted:
-                        ch = ch | (c != jnp.roll(c, 1))
-                    return ch
-
-                sp = [c[perm] for c in pvals]
-                so = [c[perm] for c in ovals]
-                new_part = changed(sp)
-                new_order = new_part | changed(so)
-                seg_start = jax.lax.cummax(
-                    jnp.where(new_part, idx, 0))
-                if func == "row_number":
-                    out_sorted = idx - seg_start + 1
-                elif func == "rank":
-                    peer_start = jax.lax.cummax(
-                        jnp.where(new_order, idx, 0))
-                    out_sorted = peer_start - seg_start + 1
-                else:  # dense_rank
-                    dense = jnp.cumsum(new_order.astype(jnp.int64))
-                    out_sorted = dense - dense[seg_start] + 1
-                out = jnp.zeros(cap, dtype=jnp.int64).at[perm].set(
-                    out_sorted.astype(jnp.int64))
-                env[out_name] = Column(out, live)
-                if out_name not in names:
-                    names.append(out_name)
+                env[win.step.out_name], _ = win.apply(env, aux, live)
+                ctx.notes["window"] = win.step.func
+                if win.step.out_name not in names:
+                    names.append(win.step.out_name)
         out_cols = {n: env[n] for n in out_schema.names}
         blk = TableBlock(out_cols, length, out_schema)
         return kernels.compact(blk, mask)
 
     return CompiledProgram(run=run, aux=ctx.aux, out_schema=out_schema,
                            in_schema=schema, group_layout=ctx.group_layout,
-                           notes=ctx.notes)
+                           notes=ctx.notes, ordered=ordered)
+
+
+# ---------------- whole-input steps: ROLLUP, ranking windows ----------------
+
+
+def group_order(step: GroupByStep, layout: tuple) -> tuple:
+    """The keys whose equal prefixes a group-by's output holds in
+    contiguous runs, its live rows a prefix: a sort-derived layout's
+    groups come in sorted key order, a dense one's in slot order (the
+    mixed radix of the keys, the first most significant); a layout
+    that keeps dead slots in place (mesh partials) holds none."""
+    if step.keys and layout[0] in ("compact", "dense"):
+        return tuple(step.keys)
+    return ()
+
+
+#: RollupStep aggregate -> what ``kernels.rollup`` does with it a level
+_ROLL_KIND = {Agg.SUM: "sum", Agg.COUNT: "count", Agg.COUNT_ALL: "count",
+              Agg.MIN: "min", Agg.MAX: "max"}
+
+
+@dataclasses.dataclass(frozen=True)
+class RollupLowering:
+    """A RollupStep resolved against its input's types: its keys, each
+    aggregate's roll kind, and for a MIN / MAX over a string the rank
+    table it orders by (the value packed as ``rank << 32 | id``, as the
+    group-by packs it). Only the DQ executor runs it: it reads each
+    level's rows first (``counts``) and sizes the levels to them."""
+
+    keys: tuple[str, ...]
+    rolls: tuple[tuple[str, str], ...]
+    packed: tuple[tuple[str, str], ...]   # (column, rank aux key)
+
+    @property
+    def names(self) -> tuple[str, ...]:
+        return self.keys + tuple(n for n, _ in self.rolls)
+
+    @property
+    def counts_of(self) -> tuple[str, ...]:
+        return tuple(n for n, kind in self.rolls if kind == "count")
+
+    @classmethod
+    def resolve(cls, ctx: "_Lowering", step: RollupStep, cur_types):
+        for n in step.keys + tuple(a.out_name for a in step.aggs):
+            if n not in cur_types:
+                raise KeyError(f"rollup column {n} not in scope")
+        rolls, packed = [], []
+        for a in step.aggs:
+            if a.func not in _ROLL_KIND:
+                raise NotImplementedError(f"{a.func} does not roll up")
+            rolls.append((a.out_name, _ROLL_KIND[a.func]))
+            if a.func in (Agg.MIN, Agg.MAX) and \
+                    cur_types[a.out_name].is_string:
+                d = ctx.dictionary(a.out_name)
+                if d is None:
+                    raise ValueError(f"MIN/MAX over string column"
+                                     f" {a.out_name} needs its dictionary")
+                packed.append((a.out_name, ctx.add_aux(
+                    f"rank.{a.out_name}", d.sort_rank())))
+        return cls(tuple(step.keys), tuple(rolls), tuple(packed))
+
+    def _pack(self, block: TableBlock, aux, unpack: bool) -> TableBlock:
+        cols = dict(block.columns)
+        for name, rank in self.packed:
+            c = cols[name]
+            if unpack:
+                data = (c.data & 0xFFFFFFFF).astype(jnp.int32)
+            else:
+                data = (kernels.dict_gather(aux[rank], c).data.astype(
+                    jnp.int64) << 32) | c.data.astype(jnp.int64)
+            cols[name] = Column(data, c.validity)
+        return TableBlock(cols, block.length, block.schema)
+
+    def counts(self, block: TableBlock, ordered: bool) -> jax.Array:
+        return kernels.rollup_counts(block, self.keys, ordered)
+
+    def apply(self, block: TableBlock, aux, caps, out_cap: int,
+              ordered: bool) -> tuple[TableBlock, jax.Array]:
+        """Every level of the rollup of ``block`` (its columns
+        ``names``), ``kernels.rollup`` in ``out_cap`` slots, and each
+        level's rows."""
+        out, counts = kernels.rollup(self._pack(block, aux, False),
+                                     self.keys, self.rolls, caps,
+                                     out_cap, ordered)
+        return self._pack(out, aux, True), counts
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowLowering:
+    """A WindowStep resolved against its input's types: a string key
+    compares by its dictionary's sort rank (a partition needs only
+    equality, which ranks keep too)."""
+
+    step: WindowStep
+    ranks: tuple    # aux key per partition + order key, or None
+
+    @classmethod
+    def resolve(cls, ctx: "_Lowering", step: WindowStep, cur_types):
+        if step.func not in ("rank", "dense_rank", "row_number"):
+            raise NotImplementedError(f"window function {step.func}")
+        ranks = []
+        for k in step.partition + step.order_keys:
+            if cur_types[k].is_string:
+                d = ctx.dictionary(k)
+                if d is None:
+                    raise ValueError(f"window key on string column {k}"
+                                     " needs its dictionary")
+                ranks.append(ctx.add_aux(f"wrank.{k}", d.sort_rank()))
+            else:
+                ranks.append(None)
+        return cls(step, tuple(ranks))
+
+    def apply(self, env: dict, aux, live) -> tuple[Column, jax.Array]:
+        """The window's column over the ``live`` rows of ``env``, and
+        the number of partitions."""
+        s = self.step
+        cols = []
+        for k, rk in zip(s.partition + s.order_keys, self.ranks):
+            cols.append(env[k] if rk is None
+                        else kernels.dict_gather(aux[rk], env[k]))
+        p = len(s.partition)
+        values, partitions = kernels.window_rank(
+            s.func, cols[:p], cols[p:],
+            s.descending or (False,) * len(s.order_keys), live)
+        return Column(values, live), partitions
+
+
+def resolve_whole_input_step(step, schema: dtypes.Schema, dicts=None,
+                             key_spaces=None, dict_aliases=None):
+    """A RollupStep or WindowStep resolved over ``schema`` for an
+    executor that runs it apart from a program (the DQ stage that sizes
+    a rollup's levels by their rows): (its lowering, the plan-time
+    tables it reads)."""
+    ctx = _Lowering(schema, dicts, key_spaces, dict_aliases=dict_aliases)
+    types = {f.name: f.type for f in schema.fields}
+    cls = RollupLowering if isinstance(step, RollupStep) else WindowLowering
+    return cls.resolve(ctx, step, types), ctx.aux
 
 
 # ---------------- expression lowering helpers ----------------

@@ -150,6 +150,27 @@ class GroupByStep:
 
 
 @dataclasses.dataclass(frozen=True)
+class RollupStep:
+    """GROUP BY ROLLUP(keys): from the finest grouping, which the
+    GroupByStep before it computes (one row per distinct tuple of
+    ``keys``), every coarser grouping set of the rollup: the keys'
+    prefixes, down to the grand total. A rolled-up key is NULL on its
+    level; a row whose key was NULL in the data stays a row of the
+    finest level. ``aggs`` names the group-by's outputs that roll up
+    (``column`` = ``out_name``) by their function: SUM, COUNT /
+    COUNT_ALL (the sum of the counts, never NULL), MIN, MAX; an AVG
+    rolls up as its SUM and its COUNT (the planner divides after).
+
+    Whole-input semantics, as WindowStep's: it lowers to
+    ``kernels.rollup``, each level from the one above it by runs of
+    equal key prefixes, and only the DQ executor runs it, once over
+    the merged group-by, its levels sized by their rows."""
+
+    keys: tuple[str, ...]
+    aggs: tuple[AggSpec, ...]
+
+
+@dataclasses.dataclass(frozen=True)
 class ProjectStep:
     names: tuple[str, ...]
 
@@ -177,7 +198,10 @@ class WindowStep:
     once, so it may only appear in programs executed over a
     materialized block (the planner keeps it out of scan pushdown, and
     the DQ lowering splits it into the merged final phase). Lowers to
-    one device lexsort + segment scans + inverse-permutation scatter.
+    ``kernels.window_rank``: one stable sort a 32-bit word of the keys,
+    segment scans, the values sorted back. A NULL partition key is
+    one partition; NULL order keys come last in either direction, as
+    ``sort_block`` puts them.
     """
 
     func: str  # rank | dense_rank | row_number
@@ -187,8 +211,8 @@ class WindowStep:
     out_name: str
 
 
-Step = Union[AssignStep, FilterStep, GroupByStep, ProjectStep, SortStep,
-             WindowStep]
+Step = Union[AssignStep, FilterStep, GroupByStep, RollupStep, ProjectStep,
+             SortStep, WindowStep]
 
 
 @dataclasses.dataclass(frozen=True)

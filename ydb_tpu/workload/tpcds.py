@@ -22,8 +22,14 @@ q65, q67, q68, q69, q70, q71, q73, q74, q79, q81, q82, q86, q88, q89,
 q91, q92, q93, q94, q96, q98, q99). q10/q35 run EXISTS plus an OR of
 EXISTS (counting decorrelation). q44/q67/q70 run REAL ranking window functions
 (rank / row_number over partitions). q17/q39
-exercise the stddev_samp aggregate; ROLLUPs (q18/q27) restate flat at
-their finest grouping; q9 picks buckets by CASE over scalar
+exercise the stddev_samp aggregate. Every ROLLUP template here is a flat
+restatement at its finest grouping, a different answer under the
+template's name: q18, q22, q27, q36, q67, q70 and q86 (their own
+comments say so). TPC-DS q67 as the specification publishes it, GROUP BY
+ROLLUP over eight keys and rank() over the subtotals, is
+``bench/statements/tpcds_q67.sql``: the engine runs it natively (the
+benchmark's cell ``tpcds-store.rollup``), held to
+``bench/refs/tpcds_q67.py``. q9 picks buckets by CASE over scalar
 subqueries; q74/q11/q4 restate the official UNION ALL year_total CTE
 as one CTE per channel; q38's INTERSECT restates as a 1:1 join of
 distinct triples; q89 restates AVG() OVER as a per-partition average
@@ -2483,7 +2489,8 @@ order by i_manager_id, avg_monthly_sales, sum_sales, d_moy
 limit 100""",
     # q67: top-ranked item/month/store revenue cells per category
     # (ROLLUP restated flat at the finest grouping; i_product_name
-    # adapted to i_item_id; full tiebreakers added to the sort)
+    # adapted to i_item_id; full tiebreakers added to the sort). The
+    # published text, ROLLUP and all: bench/statements/tpcds_q67.sql
     "q67": """
 select i_category, i_class, i_brand, i_item_id, d_year, d_qoy,
        d_moy, s_store_id, sumsales, rk
