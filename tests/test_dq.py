@@ -309,17 +309,18 @@ BUILD = dtypes.schema(("a", dtypes.INT64), ("b", dtypes.INT64),
                       ("w", dtypes.INT64))
 
 
-def _join_tables(probe_rows=300, build_rows=40, seed=11):
+def _join_tables(probe_rows=300, build_rows=40, seed=11, spread=1):
     """Probe keys with NULLs in both key columns; build ``a`` unique (a
-    lookup join's side), ``b`` repeated with NULLs (an expanding one's)."""
+    lookup join's side), ``b`` repeated with NULLs (an expanding one's);
+    ``a`` on both sides ``spread`` apart."""
     rng = np.random.default_rng(seed)
-    probe = {"a": rng.integers(0, 60, probe_rows),
+    probe = {"a": rng.integers(0, 60, probe_rows) * spread,
              "b": rng.integers(0, 5, probe_rows),
              "v": rng.integers(0, 1000, probe_rows)}
     pval = {"a": rng.random(probe_rows) > 0.1,
             "b": rng.random(probe_rows) > 0.1,
             "v": np.ones(probe_rows, bool)}
-    build = {"a": np.arange(build_rows, dtype=np.int64),
+    build = {"a": np.arange(build_rows, dtype=np.int64) * spread,
              "b": np.arange(build_rows, dtype=np.int64) % 5,
              "w": rng.integers(0, 1000, build_rows)}
     bval = {"a": np.ones(build_rows, bool),
@@ -474,6 +475,40 @@ def test_local_and_remote_producers_feed_one_consumer():
     got, _, moved = _run_join(stages, tables, runtime=SimRuntime(3))
     _assert_same(got, want)
     assert moved["device"] > 0 and moved["remote"] > 0
+
+
+def _lookups() -> dict:
+    g = root_counters().group(component="join")
+    return {p: g.group(path=p).counter("lookups").value
+            for p in ("direct", "search")}
+
+
+@pytest.mark.parametrize("spread, path", [(1, "direct"), (1000, "search")])
+def test_a_lookup_join_stage_counts_and_says_its_path(spread, path):
+    """Dense build keys (0..39) take the direct table, the same keys
+    1,000 apart (a span past the table's 4,096 slots) the search: each
+    join task counts ``component=join`` ``lookups{path}``, untraced as
+    traced, and says ``lookup=`` on its ``dispatch`` span; the answers
+    are the same rows."""
+    from ydb_tpu.obs import tracing
+
+    stages = _join_stages(JOINS["lookup_inner"])
+    before = _lookups()
+    untraced, _, _ = _run_join(stages, _join_tables(spread=spread))
+    assert untraced.num_rows > 0
+    tracer = tracing.Tracer()
+    root = tracer.trace("query")
+    with tracing.activate(root):
+        traced, _, _ = _run_join(stages, _join_tables(spread=spread))
+    root.finish()
+    _assert_same(traced, untraced)
+    after = _lookups()
+    assert {p: after[p] - before[p] for p in after} == {
+        "direct": 4 * (path == "direct"), "search": 4 * (path == "search")}
+    joins = [sp.attrs for sp in tracer.spans_for(root.trace_id)
+             if sp.name == "dispatch" and "join" in sp.attrs]
+    assert [a["lookup"] for a in joins] == [path, path]
+    assert {a["program"] for a in joins} == {"dq_stage"}
 
 
 @pytest.mark.parametrize("native_lib", [True, False])

@@ -35,7 +35,7 @@ def test_lookup_join_inner_left_semi_anti():
         bk=([2, 3, 4], dtypes.INT64),
         bv=([200, 300, 400], dtypes.INT64),
     )
-    joined, found = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
+    joined, found, _ = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
     inner = kernels.compact(joined, found)
     res = TableBlock.to_numpy(inner)
     np.testing.assert_array_equal(res["k"], [2, 3, 2])
@@ -54,7 +54,7 @@ def test_lookup_join_inner_left_semi_anti():
 def test_lookup_join_null_keys_never_match():
     probe = _block(k=([1, 1], dtypes.INT64, [True, False]))
     build = _block(bk=([1], dtypes.INT64), bv=([5], dtypes.INT64))
-    _, found = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
+    _, found, _ = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
     assert np.asarray(found)[:2].tolist() == [True, False]
 
 
@@ -68,7 +68,8 @@ def test_two_column_key_packing():
         y=([7, 7], dtypes.INT64),
         v=([100, 200], dtypes.INT64),
     )
-    _, found = jk.lookup_join(probe, build, ["a", "b"], ["x", "y"], ["v"])
+    _, found, _ = jk.lookup_join(probe, build, ["a", "b"], ["x", "y"],
+                                 ["v"])
     assert np.asarray(found)[:3].tolist() == [True, False, True]
 
 
@@ -214,7 +215,7 @@ def test_lookup_join_int64_max_key_matches():
     big = np.iinfo(np.int64).max
     probe = _block(k=([big, 5], dtypes.INT64))
     build = _block(bk=([big], dtypes.INT64), bv=([1], dtypes.INT64))
-    _, found = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
+    _, found, _ = jk.lookup_join(probe, build, ["k"], ["bk"], ["bv"])
     assert np.asarray(found)[:2].tolist() == [True, False]
     out, total = jk.expand_join(
         probe, build, ["k"], ["bk"], ["k"], ["bv"], out_capacity=8
@@ -412,3 +413,201 @@ def test_no_program_sorts_a_64_bit_or_a_two_key_operand(program):
     for args in sorts:
         kinds = re.findall(r"tensor<(\w+)>", args)[::2]
         assert len(kinds) <= 2 and kinds[0] in ("ui32", "i32"), args
+
+
+# ---- the lookup's two paths: a direct-addressed table, a binary search ----
+
+I64 = np.iinfo(np.int64)
+KINDS = ("inner", "left", "semi", "anti")
+PROBE_CAP, BUILD_CAP = 2048, 1024
+#: the direct table of a 2,048-slot probe into a 1,024-slot build:
+#: shape_class(max(4 * 1,024, min(2^21, 2,048)))
+SLOTS = 4096
+
+
+def _side(key: str, live, dead=(), nulls=(), capacity=BUILD_CAP,
+          seed=0) -> TableBlock:
+    """A join side of ``capacity`` slots: ``live`` keys first (the rows
+    at ``nulls`` with a NULL key), then ``dead`` keys past its length,
+    valid and real-looking as a compacted block's padding can be, and a
+    payload column of distinct values."""
+    keys = np.zeros(capacity, np.int64)
+    n, d = len(live), len(dead)
+    keys[:n] = live
+    keys[n:n + d] = dead
+    valid = np.zeros(capacity, bool)
+    valid[:n + d] = True
+    valid[list(nulls)] = False
+    pay = np.random.default_rng(seed).permutation(capacity) + 1
+    sch = dtypes.schema((key, dtypes.INT64), (key + "v", dtypes.INT64))
+    full = TableBlock.from_numpy({key: keys, key + "v": pay}, sch,
+                                 {key: valid, key + "v": valid}, capacity)
+    return TableBlock(full.columns, np.int32(n), sch)
+
+
+def _lookup_cases():
+    rng = np.random.default_rng(45)
+    far = 10 ** 15
+    dense = np.arange(1, 501)
+    return {
+        # probe misses below and above the build's keys
+        "dense": (dense, (), (), rng.integers(0, 600, 1500), (), (), True),
+        # the first live row of a repeated key wins, on both paths
+        "duplicates": (rng.integers(1, 60, 400), (), (),
+                       rng.integers(0, 70, 1500), (), (), True),
+        # NULL keys on both sides; dead build rows whose keys the probe
+        # asks for and one far away (neither matches nor widens the
+        # span), dead probe rows whose keys the build has
+        "nulls_and_dead": (np.arange(1, 301), (301, 302, far), (0, 5, 77),
+                           rng.integers(0, 320, 1500), (410, 303, 302),
+                           (3, 4, 5, 7), True),
+        "empty_build": ((), (1, 2, 3), (), rng.integers(0, 5, 1500), (),
+                        (), False),
+        "negative": (np.arange(-700, -200), (), (),
+                     rng.integers(-800, 0, 1500), (), (), True),
+        "int64_max_end": (np.arange(I64.max - 9, I64.max, dtype=np.int64)
+                          .tolist() + [I64.max], (), (),
+                          [I64.min, I64.max, I64.max - 20, I64.max - 3, 0],
+                          (), (), True),
+        "int64_min_end": (np.arange(I64.min, I64.min + 10, dtype=np.int64),
+                          (), (), [I64.max, I64.min, I64.min + 9, -1, 0],
+                          (), (), True),
+        "int64_both_ends": ([I64.min, I64.min + 1, 0, I64.max], (), (),
+                            [I64.max, I64.min, 0, 1, I64.min + 1], (), (),
+                            False),
+        "span_slots_less_one": ([0, SLOTS - 1, 17, 4000], (), (),
+                                [0, SLOTS - 1, SLOTS, 17, -1, 4000], (), (),
+                                True),
+        "span_slots": ([0, SLOTS, 17, 4000], (), (),
+                       [0, SLOTS, SLOTS - 1, 17, -1, 4000], (), (), False),
+    }
+
+
+LOOKUP_CASES = _lookup_cases()
+
+
+def _sides(case: str):
+    live, dead, nulls, plive, pdead, pnulls, _ = LOOKUP_CASES[case]
+    probe = _side("k", plive, pdead, pnulls, PROBE_CAP, seed=1)
+    build = _side("bk", live, dead, nulls, BUILD_CAP, seed=2)
+    return probe, build
+
+
+def _every_kind_program(search_only: bool):
+    """Every kind's output and path flag in one program: as the rule
+    picks, or (``search_only``) compiled with the search alone."""
+    import unittest.mock
+
+    import jax
+
+    @jax.jit
+    def run(probe, build):
+        with unittest.mock.patch.object(
+                jk, "_direct_slots",
+                (lambda *a: None) if search_only else jk._direct_slots):
+            return {kind: jk._lookup_join_of_kind.__wrapped__(
+                probe, build, ("k",), ("bk",), ("bkv",), "", kind)
+                for kind in KINDS}
+
+    return run
+
+
+#: one program a path for every case: their shapes are one pair of
+#: shape classes
+_EVERY_KIND = {False: _every_kind_program(False),
+               True: _every_kind_program(True)}
+
+
+@pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+def test_the_direct_path_is_the_search_bit_for_bit(case):
+    """Each kind's output on the path the input picks against the same
+    join compiled with the search alone: the rows, their order, every
+    column's data and validity (a left join's payload data under its
+    NULL excepted: unspecified on both paths), and the inner join's
+    matches against a dict of the live build rows, the first of a
+    repeated key winning."""
+    probe, build = _sides(case)
+    got = _EVERY_KIND[False](probe, build)
+    want = _EVERY_KIND[True](probe, build)
+    assert {bool(got[k][1]) for k in KINDS} == {LOOKUP_CASES[case][-1]}
+    assert not any(bool(want[k][1]) for k in KINDS)
+    for kind in KINDS:
+        g, w = got[kind][0], want[kind][0]
+        n = int(w.length)
+        assert int(g.length) == n, kind
+        for name, wc in w.columns.items():
+            gc = g.columns[name]
+            ok = np.asarray(wc.validity)[:n]
+            np.testing.assert_array_equal(
+                np.asarray(gc.validity)[:n], ok, err_msg=(kind, name))
+            gd, wd = np.asarray(gc.data)[:n], np.asarray(wc.data)[:n]
+            if kind == "left" and name == "bkv":
+                gd, wd = gd[ok], wd[ok]
+            np.testing.assert_array_equal(gd, wd, err_msg=(kind, name))
+
+    bk, bv = (np.asarray(build.columns[c].data) for c in ("bk", "bkv"))
+    bok = np.asarray(build.columns["bk"].validity)
+    first = {}
+    for i in range(int(build.length)):
+        if bok[i]:
+            first.setdefault(int(bk[i]), int(bv[i]))
+    pk = np.asarray(probe.columns["k"].data)
+    pok = np.asarray(probe.columns["k"].validity)
+    want_inner = [(int(k), first[int(k)])
+                  for k, ok in zip(pk[:int(probe.length)],
+                                   pok[:int(probe.length)])
+                  if ok and int(k) in first]
+    inner = got["inner"][0]
+    n = int(inner.length)
+    assert list(zip(np.asarray(inner.columns["k"].data)[:n].tolist(),
+                    np.asarray(inner.columns["bkv"].data)[:n].tolist())
+                ) == want_inner
+
+
+@pytest.mark.parametrize("shape", ("two_keys", "small_probe", "one_key"))
+def test_only_one_integer_key_into_a_smaller_build_compiles_the_table(shape):
+    """The static rule: a two-column key and a probe smaller than its
+    build compile today's program alone, no conditional and no scatter
+    into a table; one integer key into a build no larger than the
+    probe compiles both branches."""
+    import jax
+
+    two = dtypes.schema(("a", dtypes.INT64), ("b", dtypes.INT64),
+                        ("v", dtypes.INT64))
+    cols = {n: np.arange(1000) for n in ("a", "b", "v")}
+    blk = lambda cap: TableBlock.from_numpy(cols, two, None, cap)
+    probe, build = (blk(PROBE_CAP), blk(BUILD_CAP))
+    keys = ("a",)
+    if shape == "two_keys":
+        keys = ("a", "b")
+    elif shape == "small_probe":
+        probe, build = build, probe
+    slots = jk._direct_slots([build.columns[k] for k in keys],
+                             probe.capacity, build.capacity)
+    text = jax.jit(
+        lambda p, b: jk._lookup_join_of_kind.__wrapped__(
+            p, b, keys, keys, (), "", "semi")).lower(probe, build).as_text()
+    branches = text.count("stablehlo.case") + text.count("stablehlo.if")
+    if shape == "one_key":
+        assert slots == SLOTS and branches == 1 and "scatter" in text
+    else:
+        assert slots is None and branches == 0 and "scatter" not in text
+
+
+def test_dense_and_sparse_builds_share_one_program():
+    """A dense build takes the table, a sparse one of the same shapes
+    the search, in the program the first built: nothing compiles for
+    the second."""
+    dense = _side("bk", np.arange(1, 700))
+    sparse = _side("bk", np.arange(1, 700) * 1000)
+    probe = _side("k", np.arange(0, 1500) * 3, capacity=PROBE_CAP, seed=1)
+    run = lambda build: jk.run_equi_join_path(
+        probe, build, ["k"], ["bk"], kind="left", payload=["bkv"])
+    _, direct = run(dense)
+    size = jk._lookup_join_of_kind._cache_size()
+    out, search = run(sparse)
+    assert bool(direct) and not bool(search)
+    assert jk._lookup_join_of_kind._cache_size() == size
+    found = np.asarray(out.columns["bkv"].validity)[:1500]
+    assert found.sum() == len(set(range(0, 4500, 3))
+                              & set(range(1000, 700000, 1000)))

@@ -305,6 +305,52 @@ def test_compact_moves_a_block_without_a_sort_or_a_gather(
     assert "ydb.compact" in text
 
 
+#: a task's probe side of TPC-DS q7's first lookup join (`promotion`,
+#: 1,000 rows) in `tpcds-store.star`: the shape class of 18.0M rows
+STAR_PROBE_ROWS = 20_971_520
+
+
+def test_a_star_lookup_compiles_its_direct_table(one_chip,
+                                                 no_persistent_cache,
+                                                 capsys):
+    """q7's first join as a task runs it: 20.97M fact slots of five
+    int64 columns probe 1,024 promotion slots on one key. Both branches
+    compile, the direct table (a scatter into 2^21 slots, one gather a
+    probe row) beside the search, and the temporaries fit beside a
+    resident table."""
+    import time
+
+    from ydb_tpu import dtypes
+    from ydb_tpu.blocks.block import Column, TableBlock
+    from ydb_tpu.ssa import join as jk
+
+    def side(rows, names):
+        return TableBlock(
+            {n: Column(_shape((rows,), "int64", one_chip),
+                       _shape((rows,), "bool", one_chip)) for n in names},
+            _shape((), "int32", one_chip),
+            dtypes.schema(*[(n, dtypes.INT64) for n in names]))
+
+    probe = side(STAR_PROBE_ROWS, ("ss_promo_sk", "ss_item_sk",
+                                   "ss_cdemo_sk", "ss_sold_date_sk",
+                                   "ss_quantity"))
+    build = side(1024, ("p_promo_sk", "p_channel_email"))
+    t0 = time.perf_counter()
+    compiled = jk._lookup_join_of_kind.lower(
+        probe, build, ("ss_promo_sk",), ("p_promo_sk",),
+        ("p_channel_email",), "", "inner").compile()
+    with capsys.disabled():
+        print(f"\na star lookup at {STAR_PROBE_ROWS} x 1024 slots compiled"
+              f" for a described v5e in {time.perf_counter() - t0:.1f} s")
+    assert jk._direct_slots([build.columns["p_promo_sk"]], STAR_PROBE_ROWS,
+                            1024) == jk.DIRECT_SLOTS_CAP
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_SHARE * V5E_HBM_BYTES, temp
+    text = compiled.as_text()
+    assert "conditional(" in text and "scatter(" in text
+    assert "ydb.direct_lookup" in text and "ydb.sorted_build" in text
+
+
 #: the Transform's capacity over ClickBench's `hits` on one chip: the
 #: shape class of 12.5M rows (PERF.md section 5)
 HITS_CAPACITY = 12_582_912

@@ -261,9 +261,10 @@ def _fit(block: TableBlock, rows: int) -> TableBlock:
 
 @host_ok("the split's one small fetch: the rows a consumer gets, which"
          " size its part and its bucket")
-def _split(block: TableBlock, keys: tuple, n: int) -> list:
+def _split(block: TableBlock, keys: tuple, n: int, rider=None):
     """The block as ``n`` parts by ``hash % n``, each in its rows' order
-    and cut to their shape class: ``(part or None, rows)`` a consumer.
+    and cut to their shape class: ``(part or None, rows)`` a consumer;
+    and ``rider``, a device scalar or None, read back in the same fetch.
 
     The hash and the counts are one program, read back in one small
     fetch; then one ``compact`` a consumer, a program keyed by its
@@ -273,11 +274,23 @@ def _split(block: TableBlock, keys: tuple, n: int) -> list:
     scatter, both slower on a TPU than a ``compact`` a consumer."""
     dest, counts = _route_dest(block, keys, n)
     with tracing.span("device.wait"):
-        counts = jax.device_get(counts).tolist()
+        counts, rider = jax.device_get((counts, rider))
+    counts = counts.tolist()
     head = min(shape_class(sum(counts)), block.capacity)
     return [(_take_part(block, dest, d, head, min(shape_class(rows), head))
              if rows else None, rows)
-            for d, rows in enumerate(counts)]
+            for d, rows in enumerate(counts)], rider
+
+
+@host_ok("the block's rows, the sync live_rows makes, with a rider")
+def _live_rows(block: TableBlock, rider=None):
+    """``block.live_rows()`` and ``rider``, a device scalar or None, read
+    back in the same fetch."""
+    if rider is None:
+        return block.live_rows(), None
+    with tracing.span("device.wait"):
+        rows, rider = jax.device_get((block.length, rider))
+    return int(rows), rider
 
 
 class _Part:
@@ -581,14 +594,16 @@ class _CompiledStage:
             return block
         return self._pb_jit(block, self._pb_aux)
 
-    def run_join(self, probe: TableBlock, build: TableBlock) -> TableBlock:
+    def run_join(self, probe: TableBlock, build: TableBlock):
         """Device-local join of this task's hash bucket (grace bucket
         join, mkql_grace_join_imp.cpp bucket processing). Shares the
-        exact dispatch with the single-chip executor (run_equi_join)."""
+        exact dispatch with the single-chip executor (run_equi_join);
+        returns the joined block and a lookup's path flag (None for an
+        expanding join)."""
         from ydb_tpu.ssa import join as join_kernels
 
         j = self.join
-        return join_kernels.run_equi_join(
+        return join_kernels.run_equi_join_path(
             probe, build, j.probe_keys, j.build_keys, kind=j.kind,
             suffix=j.suffix, expand=j.expand, payload=j.payload,
             probe_payload=j.probe_payload, build_payload=j.build_payload,
@@ -1002,9 +1017,11 @@ class ComputeActor(Actor):
         """The join stage's one dispatch: the bucket's two sides packed
         on the device, joined there, the output routed, all under the
         stage's ``dispatch`` span, which says the join
-        (``join=lookup|expand``, ``kind``) and the rows of its sides and
-        of its output. The process counts the join's rows
-        (``component=join``) whether or not a trace is active."""
+        (``join=lookup|expand``, ``kind``, a lookup's ``lookup=direct|
+        search``) and the rows of its sides and of its output. The
+        process counts the join's rows and a lookup's path
+        (``component=join``) whether or not a trace is active; the path
+        is read back in the fetch that routes the output."""
         j = self.compiled.join
         probe_rows, build_rows = (sum(map(_item_rows, self._join_acc[i]))
                                   for i in (0, 1))
@@ -1018,32 +1035,44 @@ class ComputeActor(Actor):
                 _release(side)
             self._join_acc = {0: [], 1: []}
             t0 = time.perf_counter()
-            out = self.compiled.run_join(probe, build)
+            out, direct = self.compiled.run_join(probe, build)
             self._compute_s += time.perf_counter() - t0
             del probe, build
+            out_rows, direct = self._emit(out, direct)
+            path = (None if direct is None
+                    else "direct" if bool(direct) else "search")
             sp.set(join="expand" if j.expand else "lookup", kind=j.kind,
                    probe_rows=probe_rows, build_rows=build_rows,
-                   out_rows=self._emit(out))
+                   out_rows=out_rows)
+            if path is not None:
+                sp.set(lookup=path)
         g = root_counters().group(component="join")
+        if path is not None:
+            g.group(path=path).counter("lookups").inc()
         g.counter("joins").inc()
         g.counter("probe_rows").inc(probe_rows)
         g.counter("build_rows").inc(build_rows)
 
     # ---- output side ----
 
-    def _emit(self, block: TableBlock) -> int:
+    def _emit(self, block: TableBlock, rider=None) -> tuple:
         """Send a stage's output on: the result leaves the device, the
-        other outputs are routed; returns its rows."""
+        other outputs are routed; returns its rows and ``rider``, a
+        device scalar or None, read back in the first fetch that sending
+        makes (a fetched array keeps its value, so the copy out that
+        follows waits for nothing again)."""
         if int(block.capacity) == 0:
-            return 0
+            return 0, rider
         out = self.task.stage_spec.output
         if not isinstance(out, ResultOutput):
-            return self._route(block, out)
+            return self._route(block, out, rider)
+        if rider is not None:
+            _, rider = _live_rows(block, rider)
         payload = block_to_payload(block)
         rows = _payload_rows(payload)
         self._count_channel("host", rows, "result")
         self.send(self.result_target, ResultData(payload, False))
-        return rows
+        return rows, rider
 
     def _host_reason(self, ch: int) -> str | None:
         """Why channel ``ch`` carries host payloads, or None where it
@@ -1056,11 +1085,11 @@ class ComputeActor(Actor):
         return None
 
     @tracing.span("dq.exchange")
-    def _route(self, block: TableBlock, out) -> int:
+    def _route(self, block: TableBlock, out, rider=None) -> tuple:
         """Each consumer edge gets the whole routed stream: a hash
         partition over several tasks split on the chip, skipping the
         consumers it gives no rows, anything else the whole block, empty
-        or not. Returns the block's rows."""
+        or not. Returns the block's rows and ``rider`` as ``_emit``."""
         rows = None
         splits: dict[int, list] = {}
         whole = None
@@ -1068,21 +1097,22 @@ class ComputeActor(Actor):
             n = len(chans)
             if isinstance(out, HashPartition) and n > 1:
                 if n not in splits:
-                    splits[n] = [
-                        _Part(part, r, split=True)
-                        for part, r in _split(block, tuple(out.keys), n)]
+                    split, rider = _split(block, tuple(out.keys), n, rider)
+                    splits[n] = [_Part(part, r, split=True)
+                                 for part, r in split]
                 parts = splits[n]
                 rows = sum(p.rows for p in parts)
             else:  # Broadcast/UnionAll, or a single-task hash consumer
                 if whole is None:
-                    whole = _Part(block, rows if rows is not None
-                                  else block.live_rows(), split=False)
+                    if rows is None:
+                        rows, rider = _live_rows(block, rider)
+                    whole = _Part(block, rows, split=False)
                 parts = [whole] * n
                 rows = whole.rows
             for ch, part in zip(chans, parts):
                 if part.rows or part is whole:
                     self._send_part(ch, part)
-        return rows
+        return rows, rider
 
     def _send_part(self, ch: int, part: _Part):
         """One consumer's part: a device block where the channel and the

@@ -1,3 +1,5 @@
+# ydb-devmem: device-module — join kernels: every body runs under the
+# compiled join program's trace (XLA temporaries, not HBM residents)
 """Device equi-join kernels.
 
 The reference joins rows with hash tables (GraceJoin partitioned hash join
@@ -6,10 +8,23 @@ tables don't exist on TPU; the TPU-native designs here are sort-based with
 static shapes:
 
   * ``lookup_join`` — N:1 join (probe side may repeat keys; build keys
-    unique, e.g. any FK -> PK join): sort build by key once, then
-    ``searchsorted`` + gather per probe row. Output shape == probe shape;
-    a found-mask drives inner/left/semi/anti variants. This covers every
-    TPC-H dimension join.
+    unique, e.g. any FK -> PK join). Output shape == probe shape; a
+    found-mask drives inner/left/semi/anti variants. This covers every
+    TPC-H and TPC-DS dimension join. Each probe row finds its build row
+    on one of two paths:
+
+    - direct: the build's row numbers scattered into a table indexed by
+      ``key - min key``, one gather per probe row. Dense surrogate keys
+      (``1..N`` a dimension, a date's day numbers) are what it is for.
+    - search: the build sorted by key once, then ``searchsorted`` (a
+      loop of ceil(log2(capacity + 1)) gathers) and a gather per row.
+
+    The rule that picks is the input's, never a setting: the direct
+    branch is compiled only for one integer key column and a probe at
+    least as large as its build (``_direct_slots``), and it runs, by a
+    ``lax.cond`` on the device, only where the live build keys span
+    fewer than its table's slots; otherwise the search runs, in the
+    same program.
   * ``expand_join`` — N:M join via prefix-sum expansion into a static
     output capacity: per-probe match counts -> cumulative offsets ->
     each output slot maps back to (probe row, k-th match) with two
@@ -88,6 +103,77 @@ def _join_keys_live(block: TableBlock, keys: list[str]) -> tuple:
     return _key_i64(cols), live
 
 
+#: the direct table's slots follow the probe up to here: 2^21 covers
+#: every TPC-DS dimension's key span (``customer``'s 2,000,000 is the
+#: widest), an int32 table of 8 MB
+DIRECT_SLOTS_CAP = 1 << 21
+
+
+def _direct_slots(key_cols: list[Column], probe_capacity: int,
+                  build_capacity: int) -> int | None:
+    """The static half of the lookup's rule, from shapes and types: the
+    direct table's slot count, or None where no direct branch is
+    compiled. Two packed columns are no dense key, and a probe smaller
+    than its build would spend more filling and scattering a table the
+    build's size than the search it saves. The slots are a shape class,
+    so one pair of shape classes still compiles one program."""
+    from ydb_tpu.ssa.plan_fuse import shape_class
+
+    if (len(key_cols) != 1
+            or not jnp.issubdtype(key_cols[0].data.dtype, jnp.integer)
+            or probe_capacity < build_capacity):
+        return None
+    return shape_class(max(4 * build_capacity,
+                           min(DIRECT_SLOTS_CAP, probe_capacity)))
+
+
+def _search_rows(pk, plive, bk, blive):
+    """Each probe row's build row by binary search into the sorted
+    build: (src, found)."""
+    order, bk_sorted, n_live = _sorted_build(bk, blive)
+    idx = jnp.searchsorted(bk_sorted, pk)
+    idx = jnp.clip(idx, 0, bk_sorted.shape[0] - 1)
+    found = (idx < n_live) & (bk_sorted[idx] == pk) & plive
+    return order[idx], found
+
+
+@jax.named_scope("ydb.direct_lookup")
+def _direct_rows(pk, plive, bk, blive, kmin, kmax, slots: int):
+    """Each probe row's build row from a table of ``slots`` build row
+    numbers indexed by ``key - kmin``: (src, found). Where live build
+    keys repeat, the scatter's min keeps the first row in build order,
+    the row the stable sort and a left ``searchsorted`` pick."""
+    cap = bk.shape[0]
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    at = jnp.where(blive, (bk - kmin).astype(jnp.int32), slots)
+    table = jnp.full(slots, cap, jnp.int32).at[at].min(rows, mode="drop")
+    inr = plive & (pk >= kmin) & (pk <= kmax)
+    src = table[jnp.where(inr, (pk - kmin).astype(jnp.int32), 0)]
+    found = inr & (src < cap)
+    return jnp.where(found, src, 0), found
+
+
+def _match_rows(pk, plive, bk, blive, slots: int | None):
+    """(src, found, direct): each probe row's build row on the path the
+    input picks, and whether it was the direct one (a device bool).
+    The data half of the rule: the live build keys exist and span fewer
+    than ``slots`` (the span taken in uint64, so keys at INT64_MIN and
+    INT64_MAX cannot overflow it)."""
+    if slots is None:
+        return (*_search_rows(pk, plive, bk, blive),
+                jnp.zeros((), jnp.bool_))
+    kmin = jnp.min(jnp.where(blive, bk, jnp.iinfo(jnp.int64).max))
+    kmax = jnp.max(jnp.where(blive, bk, jnp.iinfo(jnp.int64).min))
+    span = (jax.lax.bitcast_convert_type(kmax, jnp.uint64)
+            - jax.lax.bitcast_convert_type(kmin, jnp.uint64))
+    direct = jnp.any(blive) & (span < jnp.uint64(slots))
+    src, found = jax.lax.cond(
+        direct,
+        lambda: _direct_rows(pk, plive, bk, blive, kmin, kmax, slots),
+        lambda: _search_rows(pk, plive, bk, blive))
+    return src, found, direct
+
+
 @jax.named_scope("ydb.lookup_join")
 def lookup_join(
     probe: TableBlock,
@@ -97,21 +183,27 @@ def lookup_join(
     payload: list[str],
     suffix: str = "",
     null_extended: bool = False,
-) -> tuple[TableBlock, jax.Array]:
+) -> tuple[TableBlock, jax.Array, jax.Array]:
     """N:1 equi-join: gather ``payload`` columns from build into probe.
 
-    Returns (probe + payload columns, found_mask). Build keys must be
-    unique among live rows (duplicate keys: one match wins). Inner join =
-    compact by found; left join = keep all, payload validity = found.
+    Returns (probe + payload columns, found_mask, direct). Build keys
+    must be unique among live rows (duplicate keys: the first live row
+    in build order wins, on either path). Inner join = compact by found;
+    left join = keep all, payload validity = found, the payload's data
+    under that NULL unspecified.
+
+    ``direct`` (a device bool) says how the probe rows found their build
+    rows: in a direct-addressed table, one gather a row, where one
+    integer key column joins a probe at least the build's capacity
+    (``_direct_slots``, decided at trace time) and the live build keys
+    span fewer than the table's slots (decided on the device); else by
+    binary search into the sorted build.
     """
     pk, plive = _join_keys_live(probe, probe_keys)
     bk, blive = _join_keys_live(build, build_keys)
-
-    order, bk_sorted, n_live = _sorted_build(bk, blive)
-    idx = jnp.searchsorted(bk_sorted, pk)
-    idx = jnp.clip(idx, 0, bk_sorted.shape[0] - 1)
-    found = (idx < n_live) & (bk_sorted[idx] == pk) & plive
-    src = order[idx]
+    slots = _direct_slots([build.columns[k] for k in build_keys],
+                          probe.capacity, build.capacity)
+    src, found, direct = _match_rows(pk, plive, bk, blive, slots)
 
     if len(set(payload)) != len(payload):
         raise ValueError(f"duplicate payload columns {payload}")
@@ -137,10 +229,18 @@ def lookup_join(
         # nullable no matter what the build side declares
         sch = sch.with_field(
             dtypes.Field(out_name, f.type, f.nullable or null_extended))
-    return TableBlock(out_cols, probe.length, sch), found
+    return TableBlock(out_cols, probe.length, sch), found, direct
 
 
-def run_equi_join(
+def run_equi_join(probe: TableBlock, build: TableBlock, probe_keys,
+                  build_keys, **kw) -> TableBlock:
+    """``run_equi_join_path``'s joined block alone: the walk, the fused
+    plans and the mesh leave the lookup's path unread."""
+    return run_equi_join_path(probe, build, probe_keys, build_keys,
+                              **kw)[0]
+
+
+def run_equi_join_path(
     probe: TableBlock,
     build: TableBlock,
     probe_keys,
@@ -151,10 +251,13 @@ def run_equi_join(
     payload=(),
     probe_payload=(),
     build_payload=(),
-) -> TableBlock:
+) -> "tuple[TableBlock, jax.Array | None]":
     """One dispatch for every equi-join shape — the single-chip plan
     executor and the DQ grace-bucket join call THIS so their semantics
     cannot drift (test_sql_dq.py asserts bit parity between the paths).
+    Returns the joined block and, for a lookup join, whether it took the
+    direct path (a device bool, read back by whoever fetches anyway);
+    None for an expanding join.
 
     Lookup (N:1) joins support inner/left/semi/anti; expand (N:M) joins
     support inner/left.
@@ -197,7 +300,7 @@ def run_equi_join(
     with tracing.span("dispatch", program="join_expand"):
         return _expand_emit_jit(
             probe, build, match, tuple(probe_payload),
-            tuple(build_payload), shape_class(total), suffix, kind)[0]
+            tuple(build_payload), shape_class(total), suffix, kind)[0], None
 
 
 # The join kernels are some fifty jnp ops each. Called eagerly every op
@@ -207,18 +310,19 @@ def run_equi_join(
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _lookup_join_of_kind(probe, build, probe_keys, build_keys, payload,
                          suffix, kind):
+    """The lookup join of ``kind`` and whether it took the direct path."""
     from ydb_tpu.ssa import kernels
 
-    joined, found = lookup_join(
+    joined, found, direct = lookup_join(
         probe, build, list(probe_keys), list(build_keys),
         list(payload), suffix, null_extended=(kind == "left"))
     if kind == "inner":
-        return kernels.compact(joined, found)
+        return kernels.compact(joined, found), direct
     if kind == "left":
-        return joined
+        return joined, direct
     if kind == "semi":
-        return kernels.compact(probe, found)
-    return kernels.compact(probe, ~found & probe.row_mask())  # anti
+        return kernels.compact(probe, found), direct
+    return kernels.compact(probe, ~found & probe.row_mask()), direct  # anti
 
 
 @jax.named_scope("ydb.expand_match")
